@@ -1,10 +1,13 @@
-"""Pallas kernel bench: correctness digest (interpret mode) + TPU v5e
-roofline projections per kernel at production shapes.
+"""Pallas kernel bench: correctness digest + TPU v5e roofline projections
+per kernel at production shapes.
 
-No TPU wall-clock exists on this container, so the bench reports the terms a
-TPU run would be bounded by: FLOPs, HBM bytes, arithmetic intensity, and the
-projected roofline time max(flops/peak, bytes/bw) per call, plus the VMEM
-working set implied by the chosen BlockSpecs (must stay under ~16 MiB).
+The digest runs each kernel against its reference: compiled on an
+accelerator, through the Pallas interpreter only when the platform is the
+CPU.  The projection rows time nothing.  They report the terms a TPU v5e run
+would be bounded by: FLOPs, HBM bytes, arithmetic intensity, and the
+projected roofline time max(flops/peak, bytes/bw) per call from the chip's
+published peaks, plus the VMEM working set implied by the chosen BlockSpecs
+(must stay under ~16 MiB).  Each row says so in its ``projection`` field.
 """
 
 from __future__ import annotations
@@ -13,8 +16,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+#: published TPU v5e peaks (Google Cloud documentation, "TPU v5e"); the
+#: rows below are projections from them, not measurements
 PEAK_FLOPS = 197e12  # bf16 / chip
 HBM_BW = 819e9  # bytes/s
+PROJECTION = "TPU v5e published peaks (projection, not a measurement)"
+
+
+def _interpret() -> bool:
+    """Pallas interpreter only on the CPU; compiled kernels elsewhere."""
+    return jax.default_backend() == "cpu"
 
 
 def _roofline_row(name, flops, bytes_, vmem_bytes, correct):
@@ -30,6 +41,7 @@ def _roofline_row(name, flops, bytes_, vmem_bytes, correct):
         bound=bound,
         vmem_mib=vmem_bytes / 2**20,
         correct=correct,
+        projection=PROJECTION,
     )
 
 
@@ -41,7 +53,7 @@ def bench_flash() -> dict:
     q = jax.random.normal(key, (1, 256, 4, 64), jnp.float32)
     k = jax.random.normal(key, (1, 256, 2, 64), jnp.float32)
     v = jax.random.normal(key, (1, 256, 2, 64), jnp.float32)
-    out = flash_attention(q, k, v, interpret=True)
+    out = flash_attention(q, k, v, interpret=_interpret())
     err = float(jnp.max(jnp.abs(out - reference_attention(q, k, v))))
 
     # production shape: mixtral prefill_32k per-device slice
@@ -60,7 +72,9 @@ def bench_segment_sum() -> dict:
     rng = np.random.default_rng(0)
     ids = jnp.asarray(np.sort(rng.integers(0, 256, 2048)), jnp.int32)
     vals = jnp.asarray(rng.normal(size=(2048, 64)), jnp.float32)
-    out = sorted_segment_sum(ids, vals, 256, assume_sorted=True, interpret=True)
+    out = sorted_segment_sum(
+        ids, vals, 256, assume_sorted=True, interpret=_interpret()
+    )
     err = float(jnp.max(jnp.abs(out - reference_segment_sum(ids, vals, 256))))
 
     # production: ogb_products per-device slice E=242k edges (62M/256), D=128
@@ -101,14 +115,14 @@ def bench_bfs_relax() -> dict:
     closed = jax.make_jaxpr(
         functools.partial(
             relax_ops._bfs_relax_csr_jit,
-            n=n, block_n=bn, block_e=be, t_max=t_max, interpret=True,
+            n=n, block_n=bn, block_e=be, t_max=t_max, interpret=_interpret(),
         )
     )(dist[None], frontier[None], src_d, dst_d, w_d, start_d, cnt_d)
     findings = check_hot_path(closed, "bench/bfs_relax")
     findings += check_pallas_grids(closed, "bench/bfs_relax", expect_kernel=True)
     assert not findings, "\n".join(str(f) for f in findings)
 
-    out = bfs_relax_csr(dist, frontier, layout, interpret=True)
+    out = bfs_relax_csr(dist, frontier, layout, interpret=_interpret())
     ref = reference_bfs_relax(
         dist, frontier, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w)
     )
@@ -141,6 +155,8 @@ def bench_bfs_relax() -> dict:
 def run(verbose: bool = True) -> list[dict]:
     rows = [bench_flash(), bench_segment_sum(), bench_bfs_relax()]
     if verbose:
+        print(f"device: {jax.devices()[0].platform} {jax.devices()[0].device_kind}")
+        print(f"roofline rows: {PROJECTION}")
         print("name,gflops,gbytes,intensity,roofline_us,bound,vmem_mib,correct")
         for r in rows:
             print(

@@ -28,6 +28,9 @@ BENCHES = [
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     want = set(sys.argv[1:])
     summary = []
     for name, module in BENCHES:
@@ -39,9 +42,6 @@ def main() -> None:
             mod = importlib.import_module(module)
             mod.run()
             status = "ok"
-        except ModuleNotFoundError as e:
-            print(f"(skipped: {e})")
-            status = "skipped"
         except Exception:
             traceback.print_exc()
             status = "FAILED"

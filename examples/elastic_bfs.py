@@ -22,10 +22,12 @@ Knobs:
                the full remaining horizon via activity-decay extrapolation
                (repro.core.replan, one replan per divergence; the metagraph
                prediction doubles as the replanner's sketch prior)
-  --mesh N     force N host devices (must be set before jax initializes --
-               this flag is pre-parsed) and run the mesh-sharded engine:
-               partition axis on an N-device mesh, real all-to-all exchange,
-               and per-window *physical* shard migration.  Prints per-device
+  --mesh N     run the mesh-sharded engine on N devices.  Under
+               ``JAX_PLATFORMS=cpu`` the N devices are forced host devices
+               (set before jax initializes -- this flag is pre-parsed);
+               otherwise they are the real accelerators.  The engine shards
+               the partition axis over the mesh, with a real all-to-all
+               exchange and per-window *physical* shard migration.  Prints per-device
                shard residency at every window so the movement is visible.
   --relayout   (with --mesh) dynamic re-layout: at every window boundary the
                engine swaps its ``MeshEdgeLayout`` to the spliced placement
@@ -95,7 +97,8 @@ def _preparse_mesh() -> int:
 
 
 _MESH = _preparse_mesh()
-if _MESH > 1:
+_FORCE_HOST = _MESH > 1 and os.environ.get("JAX_PLATFORMS") == "cpu"
+if _FORCE_HOST:
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={_MESH}"
@@ -170,8 +173,8 @@ def main():
     )
     ap.add_argument(
         "--mesh", type=int, default=0, metavar="N",
-        help="force N host devices and run the mesh-sharded engine with "
-        "physical per-window shard migration",
+        help="run the mesh-sharded engine on N devices (forced host devices "
+        "under JAX_PLATFORMS=cpu) with physical per-window shard migration",
     )
     ap.add_argument(
         "--relayout", nargs="?", const=True, default=False,
@@ -211,8 +214,16 @@ def main():
     if args.mesh > 1:
         from repro.dist.sharding import partition_mesh
 
+        import jax
+
         mesh = partition_mesh(args.mesh)
-        print(f"mesh: {args.mesh} forced host devices, partition axis sharded")
+        kind = (
+            "forced host devices"
+            if _FORCE_HOST
+            else f"{jax.devices()[0].platform} devices "
+            f"(of {len(jax.devices())} present)"
+        )
+        print(f"mesh: {args.mesh} {kind}, partition axis sharded")
 
     for wl in paper_workloads(tuple(args.workloads)):
         print(f"\n=== {wl.name} [{args.algorithm}] " + "=" * 40)

@@ -20,7 +20,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
@@ -101,12 +100,12 @@ def _fx_zero_size_grid():
 def _spmd_jaxpr(body, n_outs_rep: int = 1):
     """Trace ``body`` under shard_map over an abstract parts mesh and return
     the mapped body's jaxpr (what ``check_window_collectives`` takes)."""
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
-        mesh=AbstractMesh(((PARTS, _D),)),
+        mesh=AbstractMesh((_D,), (PARTS,)),
         in_specs=(P(None, PARTS),),
         out_specs=(P(None, PARTS),) + (P(),) * n_outs_rep,
-        check_rep=False,
+        check_vma=False,
     )
     closed = jax.make_jaxpr(mapped)(jax.ShapeDtypeStruct((2, _D * 8), np.float32))
     (sm,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "shard_map"]
@@ -375,7 +374,7 @@ ALL_FIXTURES = (
         _fx_unsynced_loop,
     ),
     Fixture(
-        "host-callback", "JX01", "debug_callback",
+        "host-callback", "JX01", "debug_print",
         "jax.debug.print traced into the superstep hot path",
         _fx_host_callback,
     ),

@@ -70,11 +70,17 @@ HOST_INTEROP_PRIMS = frozenset({
     "device_put",
 })
 
-#: named-axis primitives the balance checker accounts for (rule JX02)
-COLLECTIVE_PRIMS = frozenset({
-    "psum", "pmax", "pmin", "all_to_all", "all_gather", "ppermute",
-    "psum_scatter", "pgather", "reduce_scatter",
-})
+#: named-axis primitives the balance checker accounts for (rule JX02): the
+#: name jax emits -> the name collective signatures count.  With replication
+#: checks on (``check_vma=True``), ``lax.psum`` inside ``shard_map`` traces
+#: as ``psum_invariant``; it is the same collective.
+COLLECTIVE_PRIMS = {
+    name: name
+    for name in (
+        "psum", "pmax", "pmin", "all_to_all", "all_gather", "ppermute",
+        "psum_scatter", "pgather", "reduce_scatter",
+    )
+} | {"psum_invariant": "psum"}
 
 
 # -- jaxpr walking -----------------------------------------------------------
@@ -126,7 +132,7 @@ def collectives_in(jaxpr) -> Counter:
     """Recursive Counter of collective primitive names in a (Closed)Jaxpr."""
     jaxpr = _as_jaxpr(jaxpr)
     return Counter(
-        e.primitive.name
+        COLLECTIVE_PRIMS[e.primitive.name]
         for e, _ in iter_eqns(jaxpr)
         if e.primitive.name in COLLECTIVE_PRIMS
     )
@@ -287,7 +293,7 @@ def check_window_collectives(
         if eqn is outer:
             continue
         if eqn.primitive.name in COLLECTIVE_PRIMS:
-            epi[eqn.primitive.name] += 1
+            epi[COLLECTIVE_PRIMS[eqn.primitive.name]] += 1
         else:
             for sub, _ in sub_jaxprs(eqn):
                 epi += collectives_in(sub)
@@ -318,11 +324,11 @@ def check_window_collectives(
             closure += collectives_in(eqn.params["body_jaxpr"])
             continue
         if eqn.primitive.name in COLLECTIVE_PRIMS:
-            boundary_seq.append(eqn.primitive.name)
+            boundary_seq.append(COLLECTIVE_PRIMS[eqn.primitive.name])
             continue
         for sub, _ in sub_jaxprs(eqn):
             boundary_seq.extend(
-                e.primitive.name
+                COLLECTIVE_PRIMS[e.primitive.name]
                 for e, _ in iter_eqns(sub)
                 if e.primitive.name in COLLECTIVE_PRIMS
             )

@@ -36,7 +36,7 @@ TRACED_FUNCTIONS = (
     TracedFn(
         "graph/traversal.py",
         "_window_impl",
-        ("dist", "frontier", "nst0"),
+        ("dist", "frontier", "nst0", "consts"),
         "jitted in TraversalEngine.__init__ (static_argnums=3)",
     ),
     TracedFn(

@@ -116,7 +116,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.dist.sharding import (
@@ -305,13 +304,13 @@ def abstract_window_jaxpr(
     body = window_body(pg, program, ml, m_max, backend=backend, statics=statics)
     state = traversal_state_spec()
     rep = P()
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
-        mesh=AbstractMesh(((PARTS, int(d_n)),)),
+        mesh=AbstractMesh((int(d_n),), (PARTS,)),
         in_specs=(state, state, rep)
         + tuple(per_device_spec(np.ndim(c)) for c in consts),
         out_specs=(state, state) + (rep,) * 9,
-        check_rep=False,
+        check_vma=False,
     )
     sds = jax.ShapeDtypeStruct
     args = (
@@ -544,12 +543,12 @@ class MeshTraversalProgram:
         )
         state = traversal_state_spec()
         rep = P()
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(state, state, rep) + self._const_specs,
             out_specs=(state, state, rep, rep, rep, rep, rep, rep, rep, rep, rep),
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(mapped)
 

@@ -29,7 +29,7 @@ from repro.graph.structs import (
     Graph,
     MeshEdgeLayout,
     PartitionedGraph,
-    block_ranges_for,
+    device_block_ranges,
     dst_sorted_layout,
     mesh_layout_key,
 )
@@ -615,15 +615,17 @@ def _build_mesh_layout(
         for key, (bstart, bcnt, _) in (base.__dict__.get("_block_maps") or {}).items():
             kind, bn, be = key
             if kind == "local":
-                aff, edge_rows, nseg = vert_aff, ldst, n_pad
+                aff, edge_rows, valid, nseg = vert_aff, ldst, lvalid, n_pad
             elif kind == "mirror":
-                aff, edge_rows, nseg = src_aff, mslot, d_n * m_pad
+                aff, edge_rows, valid, nseg = src_aff, mslot, mvalid, d_n * m_pad
             else:
-                aff, edge_rows, nseg = src_aff, rslot, d_n * w_pad
+                aff, edge_rows, valid, nseg = src_aff, rslot, rvalid, d_n * w_pad
             start = bstart.copy()
             cnt = bcnt.copy()
             for d in np.flatnonzero(aff):
-                start[d], cnt[d], _ = block_ranges_for(edge_rows[d], nseg, bn, be)
+                start[d], cnt[d], _ = device_block_ranges(
+                    edge_rows[d], valid[d], nseg, bn, be
+                )
             carried[key] = (start, cnt, max(1, int(cnt.max())))
         if carried:
             out.__dict__["_block_maps"] = carried

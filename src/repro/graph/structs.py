@@ -87,6 +87,21 @@ def block_ranges_for(
     return start, count, max(1, int(count.max()))
 
 
+def device_block_ranges(
+    rows: np.ndarray, valid: np.ndarray, n: int, block_n: int, block_e: int
+) -> tuple[np.ndarray, np.ndarray, int]:
+    """``block_ranges_for`` over one mesh device's valid edges.
+
+    A device's padded tail (identity candidates aimed at its last row or
+    slot) is no work for the kernel.  Counted in, it would hand that last
+    row block every padded edge block, and ``t_max`` would grow with the
+    largest device's edge count.  Valid edges are a prefix of each row.
+    """
+    return block_ranges_for(
+        rows[: int(np.count_nonzero(valid))], n, block_n, block_e
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class CsrEdgeLayout:
     """Static destination-sorted edge layout, built once per (sub)edge-set.
@@ -303,7 +318,8 @@ class MeshEdgeLayout:
     # ``n_devices * w_pad`` wire slots (pad value ``D * w_pad - 1``), so both
     # admit the contiguous edge-block span representation of
     # ``block_ranges_for``.  Padded edges point at *real* rows but carry
-    # identity candidates, so they are reduction no-ops.  Maps are cached per
+    # identity candidates, so they are reduction no-ops, and the maps span
+    # only each device's valid prefix (``device_block_ranges``).  Maps are cached per
     # geometry in ``__dict__['_block_maps']`` (the frozen-dataclass side cache
     # shared with ``_build_info``); the incremental mesh rebuild in
     # ``partition._build_mesh_layout`` carries unchanged device rows forward.
@@ -317,13 +333,15 @@ class MeshEdgeLayout:
 
         def build():
             if kind == "local":
-                rows, nseg = self.ldst, self.n_pad
+                rows, valid, nseg = self.ldst, self.lvalid, self.n_pad
             elif kind == "mirror":
-                rows, nseg = self.mslot, self.n_devices * self.m_pad
+                rows, valid = self.mslot, self.mvalid
+                nseg = self.n_devices * self.m_pad
             else:
-                rows, nseg = self.rslot, self.n_devices * self.w_pad
+                rows, valid = self.rslot, self.rvalid
+                nseg = self.n_devices * self.w_pad
             per_dev = [
-                block_ranges_for(rows[d], nseg, block_n, block_e)
+                device_block_ranges(rows[d], valid[d], nseg, block_n, block_e)
                 for d in range(self.n_devices)
             ]
             start = np.stack([p[0] for p in per_dev])

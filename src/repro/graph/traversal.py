@@ -231,6 +231,23 @@ def make_superstep_fn(pg: PartitionedGraph) -> Callable[[jax.Array, jax.Array], 
     return superstep
 
 
+class _WindowConsts(NamedTuple):
+    """The dense window's static arrays, passed to it as one argument."""
+
+    lsrc: jax.Array
+    ldst: jax.Array
+    lw: jax.Array  # program edge plane
+    lpart: jax.Array
+    rsrc: jax.Array
+    rdst: jax.Array
+    rw: jax.Array
+    rpart: jax.Array
+    vpart: jax.Array
+    sg: jax.Array | None  # subgraph ids when collecting subgraphs
+    lmap: tuple  # local-plane kernel block map (() on the xla backend)
+    rmap: tuple  # remote-plane kernel block map
+
+
 class TraversalResult(NamedTuple):
     """Raw device buffers from one batched traversal (one bulk transfer)."""
 
@@ -397,35 +414,41 @@ class TraversalEngine:
                 block_n=block_n, block_e=block_e,
                 mirror_degree=self.mirror_degree,
             )
-        self._relax_l_kern = self._relax_r_kern = None
-        if backend != "xla" and self._mesh_prog is None:
-            layout = partitioned_edge_layout(pg)
-            self._relax_l_kern = make_relax_fn(
-                layout.local.dst, self.n, reduce=self.program.reduce,
-                block_n=block_n, block_e=block_e, interpret=interpret,
-            )
-            self._relax_r_kern = make_relax_fn(
-                layout.remote.dst, self.n, reduce=self.program.reduce,
-                block_n=block_n, block_e=block_e, interpret=interpret,
-            )
-        dev = _device_arrays(pg)  # shared across engines on this graph
-        self._lsrc, self._ldst, self._lpart = dev.lsrc, dev.ldst, dev.lpart
-        self._rsrc, self._rdst, self._rpart = dev.rsrc, dev.rdst, dev.rpart
-        # mesh launches never trace the dense window, and the mesh program
-        # shards its own plane -- don't upload dense plane arrays it won't use
-        self._lw, self._rw = (
-            (None, None)
-            if self._mesh_prog is not None
-            else plane_arrays(pg, self.program)
-        )
-        self._vpart = dev.vpart
-        self._sg = None
-        if collect_subgraphs:
-            if "_sg_device" not in pg.__dict__:
-                pg.__dict__["_sg_device"] = jnp.asarray(
-                    pg.subgraph_of_vertex.astype(np.int32)
+        # kernel backends: host-built relax fns over the static block maps
+        # (None on xla, where the window runs XLA segment reductions)
+        self._relax_l = self._relax_r = None
+        # the dense window's static arrays travel as an argument: a jitted
+        # function that closed over them would embed every edge array in the
+        # compiled program as a constant, once per compiled (k, S) variant.
+        # Mesh launches never trace the dense window and the mesh program
+        # shards its own arrays, so a mesh engine uploads none of these.
+        self._consts = None
+        if self._mesh_prog is None:
+            lmap = rmap = ()
+            if backend != "xla":
+                layout = partitioned_edge_layout(pg)
+                self._relax_l, lmap = make_relax_fn(
+                    layout.local.dst, self.n, reduce=self.program.reduce,
+                    block_n=block_n, block_e=block_e, interpret=interpret,
                 )
-            self._sg = pg.__dict__["_sg_device"]
+                self._relax_r, rmap = make_relax_fn(
+                    layout.remote.dst, self.n, reduce=self.program.reduce,
+                    block_n=block_n, block_e=block_e, interpret=interpret,
+                )
+            dev = _device_arrays(pg)  # shared across engines on this graph
+            lw, rw = plane_arrays(pg, self.program)
+            sg = None
+            if collect_subgraphs:
+                if "_sg_device" not in pg.__dict__:
+                    pg.__dict__["_sg_device"] = jnp.asarray(
+                        pg.subgraph_of_vertex.astype(np.int32)
+                    )
+                sg = pg.__dict__["_sg_device"]
+            self._consts = _WindowConsts(
+                dev.lsrc, dev.ldst, lw, dev.lpart,
+                dev.rsrc, dev.rdst, rw, dev.rpart,
+                dev.vpart, sg, lmap, rmap,
+            )
         # one jitted program serves both modes: run() launches a single
         # window of depth m_max, run_window() launches depth k (static arg,
         # compiled once per distinct k/S)
@@ -460,7 +483,7 @@ class TraversalEngine:
         if self._mesh_prog is not None:
             out = self._mesh_prog.window(dist, frontier, nst0, k)
             return TraversalResult(*out[:9]), out[9], out[10]
-        return self._window(dist, frontier, nst0, k)
+        return self._window(dist, frontier, nst0, k, self._consts)
 
     def window_jaxpr(self, *, k: int = 3, s_batch: int = 2):
         """Abstractly trace this engine's dense window program -- the exact
@@ -477,14 +500,18 @@ class TraversalEngine:
             sds((s_batch, self.n), np.bool_),
             sds((s_batch,), np.int32),
             int(k),
+            jax.tree.map(lambda a: sds(a.shape, a.dtype), self._consts),
         )
 
     # -- device program ------------------------------------------------------
 
     def _window_impl(
-        self, dist: jax.Array, frontier: jax.Array, nst0: jax.Array, m_max: int
+        self, dist: jax.Array, frontier: jax.Array, nst0: jax.Array, m_max: int,
+        consts: "_WindowConsts",
     ):
         s_batch = dist.shape[0]
+        (lsrc, ldst, lw, lpart, rsrc, rdst, rw, rpart, vpart, sg, lmap,
+         rmap) = consts
         n, p = self.n, self.n_parts
         prog = self.program
         ident = prog.identity
@@ -494,12 +521,12 @@ class TraversalEngine:
 
         seg_red_l = jax.vmap(
             lambda c: seg_red(
-                c, self._ldst, num_segments=n, indices_are_sorted=True
+                c, ldst, num_segments=n, indices_are_sorted=True
             )
         )
         seg_red_r = jax.vmap(
             lambda c: seg_red(
-                c, self._rdst, num_segments=n, indices_are_sorted=True
+                c, rdst, num_segments=n, indices_are_sorted=True
             )
         )
 
@@ -509,34 +536,34 @@ class TraversalEngine:
         # block-skipping kernel pass (base <- identity when None); the xla
         # forms below are the exact pre-backend expressions.
         def relax_l(cand, base=None):
-            if self._relax_l_kern is not None:
+            if self._relax_l is not None:
                 if base is None:
                     base = jnp.full((cand.shape[0], n), ident, dist.dtype)
-                return self._relax_l_kern(cand, base)
+                return self._relax_l(lmap, cand, base)
             r = seg_red_l(cand)
             return r if base is None else prog.combine(base, r)
 
         def relax_r(cand, base=None):
-            if self._relax_r_kern is not None:
+            if self._relax_r is not None:
                 if base is None:
                     base = jnp.full((cand.shape[0], n), ident, dist.dtype)
-                return self._relax_r_kern(cand, base)
+                return self._relax_r(rmap, cand, base)
             r = seg_red_r(cand)
             return r if base is None else prog.combine(base, r)
         seg_sum_lp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, self._lpart, num_segments=p)
+            lambda v: jax.ops.segment_sum(v, lpart, num_segments=p)
         )
         seg_sum_rp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, self._rpart, num_segments=p)
+            lambda v: jax.ops.segment_sum(v, rpart, num_segments=p)
         )
         seg_sum_vp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, self._vpart, num_segments=p)
+            lambda v: jax.ops.segment_sum(v, vpart, num_segments=p)
         )
         n_sg = self.n_subgraphs
         if self.collect_subgraphs:
             seg_any_sg = jax.vmap(
                 lambda f: jax.ops.segment_max(
-                    f.astype(jnp.int32), self._sg, num_segments=n_sg
+                    f.astype(jnp.int32), sg, num_segments=n_sg
                 )
                 > 0
             )
@@ -551,18 +578,18 @@ class TraversalEngine:
                 )
             nst = nst + fr.any(axis=1).astype(jnp.int32)
 
-            active_le = fr[:, self._lsrc]
+            active_le = fr[:, lsrc]
             cand = jnp.where(
-                active_le, prog.relax(d[:, self._lsrc], self._lw), ident
+                active_le, prog.relax(d[:, lsrc], lw), ident
             )
             acc = relax_l(cand)
             we_s = seg_sum_lp(active_le.astype(jnp.int32))
             wv_s = seg_sum_vp(fr.astype(jnp.int32))
             it_s = fr.any(axis=1).astype(jnp.int32)  # one pass per superstep
 
-            active_re = fr[:, self._rsrc]
+            active_re = fr[:, rsrc]
             cand_r = jnp.where(
-                active_re, prog.relax(d[:, self._rsrc], self._rw), ident
+                active_re, prog.relax(d[:, rsrc], rw), ident
             )
             acc = relax_r(cand_r, acc)
             ms_s = seg_sum_rp(active_re.astype(jnp.int32))
@@ -591,9 +618,9 @@ class TraversalEngine:
 
             def ibody(c):
                 d_i, f_i, we_s, wv_s, it_s, touched = c
-                active_e = f_i[:, self._lsrc]
+                active_e = f_i[:, lsrc]
                 cand = jnp.where(
-                    active_e, prog.relax(d_i[:, self._lsrc], self._lw), ident
+                    active_e, prog.relax(d_i[:, lsrc], lw), ident
                 )
                 new_d = relax_l(cand, d_i)
                 improved = prog.is_active(new_d, d_i)
@@ -609,9 +636,9 @@ class TraversalEngine:
             )
 
             # -- remote exchange at the superstep boundary --------------------
-            active_re = touched[:, self._rsrc]
+            active_re = touched[:, rsrc]
             cand = jnp.where(
-                active_re, prog.relax(d2[:, self._rsrc], self._rw), ident
+                active_re, prog.relax(d2[:, rsrc], rw), ident
             )
             new_d = relax_r(cand, d2)
             next_fr = prog.is_active(new_d, d2)
@@ -649,7 +676,7 @@ class TraversalEngine:
         pact = (
             jax.vmap(
                 lambda f: jax.ops.segment_max(
-                    f.astype(jnp.int32), self._vpart, num_segments=p
+                    f.astype(jnp.int32), vpart, num_segments=p
                 )
             )(fr)
             > 0

@@ -25,9 +25,12 @@ Variants:
   * ``relax_kernel_blockmap`` -- the static-layout fast path.  A precomputed
     block map (``block_ranges_for``: per row block, the contiguous span of
     edge blocks that can hit it) is scalar-prefetched, so the grid
-    enumerates only tiles that provably contain in-range edges, and a
-    leading grid dimension batches multiple sources over the same edge
-    blocks (the dst tile is fetched once per (row, t) regardless of S).
+    enumerates only tiles that provably contain in-range edges.  Sources
+    ride the sublane axis in tiles of up to ``SOURCE_TILE`` rows: one grid
+    step fetches the dst tile and builds its hit mask once, then reduces
+    every source of the tile against it.  A tile of 8 rows (or all rows,
+    when there are fewer) keeps every block's last two dims legal for
+    Mosaic; sources past a multiple of 8 are padded with identity rows.
   * ``bfs_relax_kernel_blockmap`` -- backcompat min-reduce wrapper.
 """
 
@@ -103,12 +106,13 @@ def _kernel_blockmap(
     start_ref,  # [NB] int32 scalar-prefetch: first edge block per row block
     cnt_ref,  # [NB] int32 scalar-prefetch: edge blocks per row block
     dst_ref,  # (1, bE) int32 sorted, padded with n_pad
-    cand_ref,  # (1, bE) candidates for source s (identity where inactive)
-    base_ref,  # (1, bN) base state for (source s, row block)
-    o_ref,  # (1, bN), persists across the t dimension
+    cand_ref,  # (sT, bE) candidates of the tile's sources (identity inactive)
+    base_ref,  # (sT, bN) base state for (source tile, row block)
+    o_ref,  # (sT, bN), persists across the t dimension
     *,
     block_n: int,
     block_e: int,
+    s_tile: int,
     reduce: str,
     identity,
 ):
@@ -127,12 +131,17 @@ def _kernel_blockmap(
         rows = oi * block_n + jax.lax.broadcasted_iota(
             jnp.int32, (block_e, block_n), 1
         )
-        hit = dst[:, None] == rows
-        m = jnp.where(hit, cand_ref[0, :][:, None], identity)
-        if reduce == "min":
-            o_ref[0, :] = jnp.minimum(o_ref[0, :], m.min(axis=0))
-        else:  # "sum": segment_sum accumulate idiom (identity == 0)
-            o_ref[0, :] = o_ref[0, :] + m.sum(axis=0)
+        hit = dst[:, None] == rows  # shared by every source of the tile
+        for r in range(s_tile):
+            m = jnp.where(hit, cand_ref[r, :][:, None], identity)
+            if reduce == "min":
+                o_ref[r, :] = jnp.minimum(o_ref[r, :], m.min(axis=0))
+            else:  # "sum": segment_sum accumulate idiom (identity == 0)
+                o_ref[r, :] = o_ref[r, :] + m.sum(axis=0)
+
+
+#: most sources one grid step reduces: one f32 sublane tile
+SOURCE_TILE = 8
 
 
 def relax_kernel_blockmap(
@@ -154,7 +163,7 @@ def relax_kernel_blockmap(
     dtype: +inf for floats, iinfo.max for ints) or "sum" (stationary
     programs; identity 0).  Padded dst entries must point past the last real
     row; padded candidates must carry the identity.  Output dtype follows
-    ``base``.
+    ``base``.  The grid is ``(ceil(S / s_tile), NB, t_max)``.
     """
     s, e_pad = cand.shape
     n_pad = base.shape[1]
@@ -168,6 +177,11 @@ def relax_kernel_blockmap(
         identity = dt.type(INF)
     else:
         identity = dt.type(jnp.iinfo(dt).max)
+    s_tile = min(s, SOURCE_TILE)
+    s_pad = -(-s // s_tile) * s_tile
+    if s_pad != s:
+        cand = jnp.pad(cand, ((0, s_pad - s), (0, 0)), constant_values=identity)
+        base = jnp.pad(base, ((0, s_pad - s), (0, 0)), constant_values=identity)
 
     def _edge_block(s_i, oi, t, start, cnt):
         del s_i, cnt
@@ -183,27 +197,29 @@ def relax_kernel_blockmap(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, n_pad // block_n, t_max),
+        grid=(s_pad // s_tile, n_pad // block_n, t_max),
         in_specs=[
             pl.BlockSpec((1, block_e), _edge_block),
-            pl.BlockSpec((1, block_e), _cand_block),
-            pl.BlockSpec((1, block_n), _row_block),
+            pl.BlockSpec((s_tile, block_e), _cand_block),
+            pl.BlockSpec((s_tile, block_n), _row_block),
         ],
-        out_specs=pl.BlockSpec((1, block_n), _row_block),
+        out_specs=pl.BlockSpec((s_tile, block_n), _row_block),
     )
     kern = functools.partial(
         _kernel_blockmap,
         block_n=block_n,
         block_e=block_e,
+        s_tile=s_tile,
         reduce=reduce,
         identity=identity,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, n_pad), dt),
+        out_shape=jax.ShapeDtypeStruct((s_pad, n_pad), dt),
         interpret=interpret,
     )(start, cnt, dst_sorted.reshape(1, e_pad), cand, base)
+    return out[:s]
 
 
 def bfs_relax_kernel_blockmap(

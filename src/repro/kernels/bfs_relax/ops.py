@@ -20,7 +20,8 @@ engines build on:
     ``jit``/``while_loop``/``shard_map`` (the mesh engine calls it per
     device shard).
   * ``make_relax_fn`` -- host-side builder for the dense engine: computes
-    the static block map once, uploads it, returns a traced closure.
+    the static block map once, uploads it, and returns it with the traced
+    relax fn that takes it as an argument.
 
 Both engines select this path via ``backend`` in ``RELAX_BACKENDS``:
 ``"xla"`` (default; segment ops, right on CPU), ``"pallas"`` (compiled
@@ -38,6 +39,7 @@ import numpy as np
 
 from repro.graph.structs import BoundedCache, block_ranges_for
 from repro.kernels.bfs_relax.kernel import (
+    SOURCE_TILE,
     bfs_relax_kernel,
     bfs_relax_kernel_blockmap,
     relax_kernel_blockmap,
@@ -278,6 +280,38 @@ def relax_blockmap_call(
     return out[:, :n]
 
 
+def relax_grid(
+    dst: np.ndarray,  # [E] int32 ascending (static, host-side)
+    n: int,
+    s_batch: int,
+    *,
+    block_n: int = 512,
+    block_e: int = 512,
+) -> dict:
+    """The grid of one blockmap-kernel pass over ``dst`` for ``s_batch``
+    sources, as ``make_relax_fn`` would launch it: the clamped block
+    geometry, row blocks, ``t_max``, the tiles that hold edges, and
+    ``grid_steps`` (source tiles x row blocks x ``t_max``; 0 when there are
+    no edges and no kernel runs)."""
+    dst = np.asarray(dst)
+    e = int(dst.shape[0])
+    if e == 0:
+        return {"block_n": 0, "block_e": 0, "row_blocks": 0, "t_max": 0,
+                "tiles_with_edges": 0, "grid_steps": 0}
+    bn, be, n_pad, _ = _block_dims(n, e, block_n, block_e)
+    _, cnt, t_max = block_ranges_for(dst, n, bn, be)
+    row_blocks = n_pad // bn
+    source_tiles = -(-s_batch // min(s_batch, SOURCE_TILE))
+    return {
+        "block_n": bn,
+        "block_e": be,
+        "row_blocks": row_blocks,
+        "t_max": int(t_max),
+        "tiles_with_edges": int(cnt.sum()),
+        "grid_steps": source_tiles * row_blocks * int(t_max),
+    }
+
+
 def make_relax_fn(
     dst: np.ndarray,  # [E] int32 ascending (static, host-side)
     n: int,
@@ -288,19 +322,24 @@ def make_relax_fn(
     interpret: bool = False,
 ):
     """Host-side builder for the dense engine: compute the static block map
-    for a dst-sorted edge array once, upload it, and return a traced
-    ``(cand [S, E], base [S, n]) -> [S, n]`` closure running the
-    block-skipping kernel.  With ``e == 0`` the closure is the combine
-    identity (returns ``base``)."""
+    for a dst-sorted edge array once and upload it.
+
+    Returns ``(relax, blockmap)``: ``relax(blockmap, cand [S, E], base [S,
+    n]) -> [S, n]`` runs the block-skipping kernel, and ``blockmap`` holds
+    the uploaded ``(start, cnt, dst)`` arrays.  A jitted caller passes
+    ``blockmap`` in as an argument; closing over it would embed the arrays
+    in the compiled program as constants.  With ``e == 0``, ``relax`` is the
+    combine identity (returns ``base``) and ``blockmap`` is empty."""
     dst = np.asarray(dst)
     e = int(dst.shape[0])
     if e == 0:
-        return lambda cand, base: base
+        return (lambda blockmap, cand, base: base), ()
     bn, be, _, _ = _block_dims(n, e, block_n, block_e)
     start, cnt, t_max = block_ranges_for(dst, n, bn, be)
-    start_d, cnt_d, dst_d = jnp.asarray(start), jnp.asarray(cnt), jnp.asarray(dst)
+    blockmap = (jnp.asarray(start), jnp.asarray(cnt), jnp.asarray(dst))
 
-    def relax(cand, base):
+    def relax(blockmap, cand, base):
+        start_d, cnt_d, dst_d = blockmap
         return relax_blockmap_call(
             start_d,
             cnt_d,
@@ -314,7 +353,7 @@ def make_relax_fn(
             interpret=interpret,
         )
 
-    return relax
+    return relax, blockmap
 
 
 def relax_csr(

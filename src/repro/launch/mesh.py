@@ -12,16 +12,19 @@ Axis semantics:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a (data, model) mesh with model=1.
     Used by the CPU train/serve demos and tests."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh(
+        (n, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

@@ -79,18 +79,16 @@ def pna_forward_halo(
     avg_log_degree: float = 2.0,
 ) -> jax.Array:
     """Returns [P, Nl, d_out] shard-major node outputs."""
-    from jax.experimental.shard_map import shard_map
-
     axis = axis if axis is not None else tuple(mesh.axis_names)
     spec = P(axis)
     fn = partial(
         _shard_fn, params, cfg, axis, avg_log_degree=avg_log_degree
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return sharded(xs, send_idx, edge_src_ext, edge_dst_loc, edge_mask)

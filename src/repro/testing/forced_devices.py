@@ -8,6 +8,11 @@ shared recipe lives here: spawn a child with the flag *appended* to
 prepended to ``PYTHONPATH`` (so the child resolves ``repro`` regardless of
 how the parent was invoked).  ``tests/conftest.py`` and
 ``benchmarks/traversal_bench.py`` both route through this function.
+
+The child always runs with ``JAX_PLATFORMS=cpu``: its devices are forced
+host devices, a CPU rehearsal by construction.  An accelerator belongs to
+one process at a time, so a child must never wait on a chip the parent
+holds.
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ def run_forced_devices(
     streams on a non-zero exit.
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n_devices}"

@@ -329,10 +329,11 @@ def test_relax_csr_empty_edge_set_and_frontier():
         ),
         np.zeros((1, 4), np.float32),
     )
-    # make_relax_fn's e == 0 closure is the combine identity too
-    fn = make_relax_fn(np.zeros(0, np.int32), 4, reduce="min")
+    # make_relax_fn's e == 0 relax is the combine identity too
+    fn, blockmap = make_relax_fn(np.zeros(0, np.int32), 4, reduce="min")
+    assert blockmap == ()
     np.testing.assert_array_equal(
-        np.asarray(fn(jnp.zeros((1, 0)), state)), np.asarray(state)
+        np.asarray(fn(blockmap, jnp.zeros((1, 0)), state)), np.asarray(state)
     )
 
     # non-empty edges, empty frontier: min pass returns state unchanged

@@ -87,13 +87,12 @@ def test_compressed_psum_error_feedback():
         mean, err = compressed_psum(x, "i", jnp.zeros_like(x))
         return mean, err
 
-    mesh = jax.make_mesh((n_dev,), ("i",))
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    g = shard_map(
+    mesh = jax.make_mesh((n_dev,), ("i",), axis_types=(AxisType.Auto,))
+    g = jax.shard_map(
         f, mesh=mesh, in_specs=P(), out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     mean, err = g(x)
     # single worker: mean == dequantized x; error = quantization residual
