@@ -2,7 +2,7 @@
 
 Two layers over one Finding record:
 
-- **jaxpr auditor** (:mod:`repro.analysis.jaxpr_audit`, rules JX01-JX05):
+- **jaxpr auditor** (:mod:`repro.analysis.jaxpr_audit`, rules JX01-JX06):
   abstractly traces every builtin :class:`~repro.graph.program.VertexProgram`
   through the dense ``TraversalEngine`` window and the mesh
   ``MeshTraversalProgram`` body (via ``AbstractMesh`` -- zero real devices

@@ -23,6 +23,8 @@ RULES = {
     "relayout/window sweeps stay within the window-cache budget",
     "JX05": "reduction identity is the program's dtype-derived identity and "
     "is a fixed point of relax/combine",
+    "JX06": "every window operation sits under one plane scope and one role "
+    "scope (repro.spans)",
     # layer 2: AST lint (source-level, repo-specific)
     "AL01": "no np. / .item() / float() / Python branches on traced values "
     "inside registered traced functions",

@@ -27,6 +27,7 @@ from repro.analysis.jaxpr_audit import (
     check_cache_key_fn,
     check_hot_path,
     check_pallas_grids,
+    check_scopes,
     check_window_collectives,
 )
 from repro.analysis.lint import lint_source
@@ -278,6 +279,30 @@ def _fx_host_callback():
     return check_hot_path(closed, "fixture/host-callback")
 
 
+# -- JX06: device scopes ------------------------------------------------------
+
+
+def _fx_unscoped_op():
+    """The real dense window with one op added outside any scope: a
+    frontier-size counter taken after the superstep loop."""
+    from repro.analysis.jaxpr_audit import default_audit_graph
+    from repro.graph.traversal import TraversalEngine
+
+    engine = TraversalEngine(default_audit_graph(), backend="xla")
+
+    def window(dist, frontier, nst0, consts):
+        out = engine._window_impl(dist, frontier, nst0, 3, consts)
+        return out, out[0].frontier.sum()
+
+    n, dtype = engine.n, engine.program.dtype
+    sds = jax.ShapeDtypeStruct
+    closed = jax.make_jaxpr(window)(
+        sds((2, n), dtype), sds((2, n), np.bool_), sds((2,), np.int32),
+        jax.tree.map(lambda a: sds(a.shape, a.dtype), engine._consts),
+    )
+    return check_scopes(closed, "fixture/unscoped-op")
+
+
 # -- AL01/AL02/AL03/AL04: source-level reconstructions ------------------------
 
 _SRC_NUMPY_IN_TRACED = '''\
@@ -377,6 +402,12 @@ ALL_FIXTURES = (
         "host-callback", "JX01", "debug_print",
         "jax.debug.print traced into the superstep hot path",
         _fx_host_callback,
+    ),
+    Fixture(
+        "unscoped-op", "JX06", "'reduce_sum'",
+        "real dense window with a frontier count added after the loop, "
+        "outside every device scope",
+        _fx_unscoped_op,
     ),
     Fixture(
         "numpy-in-traced-fn", "AL01", "numpy ops force a host round-trip",

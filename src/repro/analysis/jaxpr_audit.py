@@ -22,7 +22,10 @@ against the engine's declared invariants:
         relayout/window sweep stays within the PR 5 cache policy,
   JX05  the program's ``identity`` is the dtype-derived identity of its
         ``reduce`` (what the Pallas kernels pad with) and is a numerical
-        fixed point of ``relax``/``combine``.
+        fixed point of ``relax``/``combine``,
+  JX06  every operation of the window sits under one plane scope and one
+        role scope (``repro.spans``), so none of its device time reads as
+        unscoped.
 
 All checks return ``Finding`` lists; ``audit_tree`` runs the whole matrix.
 """
@@ -61,6 +64,7 @@ from repro.graph.program import (
 )
 from repro.graph.structs import mesh_layout_key
 from repro.kernels.bfs_relax.ops import _identity_scalar
+from repro.spans import scope_of
 
 #: primitives that leave the device / re-enter Python -- none may appear in
 #: a traced window (rule JX01)
@@ -153,6 +157,38 @@ def check_hot_path(traced, label: str) -> list[Finding]:
                 f"host-interop primitive '{name}' on the hot path (at {at})",
             ))
     return findings
+
+
+# -- JX06: device scopes ------------------------------------------------------
+
+
+def check_scopes(traced, label: str) -> list[Finding]:
+    """Every operation of the traced window sits under one plane scope and
+    one role scope.  An equation's name stack is relative to the equation
+    that holds its jaxpr (a ``while``, ``pjit``, ``shard_map``), so stacks
+    are joined on the way down; a ``pallas_call`` is one operation."""
+    unscoped = Counter()
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            names = outer + tuple(
+                n for n in str(eqn.source_info.name_stack).split("/") if n
+            )
+            subs = () if eqn.primitive.name == "pallas_call" else sub_jaxprs(eqn)
+            for sub, _ in subs:
+                walk(sub, names)
+            if not subs and scope_of(names) is None:
+                unscoped[(eqn.primitive.name, "/".join(names) or "top level")] += 1
+
+    walk(_as_jaxpr(traced), ())
+    return [
+        Finding(
+            "JX06", label,
+            f"{count} '{prim}' op(s) at '{at}' outside one plane and one "
+            "role scope (repro.spans): their device time reads as unscoped",
+        )
+        for (prim, at), count in sorted(unscoped.items())
+    ]
 
 
 # -- JX03: Pallas grids -------------------------------------------------------
@@ -680,6 +716,7 @@ def audit_dense(pg, program, backend: str) -> list[Finding]:
     findings = check_hot_path(closed, label)
     findings += check_pallas_grids(closed, label, expect_kernel=backend != "xla")
     findings += check_identity(program, label)
+    findings += check_scopes(closed, label)
     return findings
 
 
@@ -709,6 +746,7 @@ def audit_mesh(
     findings = check_hot_path(closed, label)
     findings += check_pallas_grids(closed, label, expect_kernel=backend != "xla")
     findings += check_mesh_trace(closed, program, label, mirrored=ml.m_pad > 0)
+    findings += check_scopes(closed, label)
     return findings
 
 

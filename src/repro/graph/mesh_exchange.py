@@ -143,6 +143,7 @@ from repro.kernels.bfs_relax.ops import (
     relax_blockmap_call,
     validate_backend,
 )
+from repro.spans import FINISH, collective, scope
 from jax.sharding import PartitionSpec as P
 
 #: collectives ``_body`` contributes OUTSIDE the superstep loop -- the
@@ -531,10 +532,26 @@ class MeshTraversalProgram:
         ``_window_impl``'s output tuple ``(dist, frontier, nst, we, wv, ms,
         it, sg, wire, pact, done)`` with ``dist``/``frontier`` in the padded
         sharded layout."""
-        ml = self.layout
-        key = window_cache_key(ml, m_max, self.backend, self._statics)
-        fn = self._windows.get_or_build(key, lambda: self._build(m_max))
-        return fn(dist, frontier, nst0, *self._consts)
+        return self._window_fn(m_max)(dist, frontier, nst0, *self._consts)
+
+    def _window_fn(self, m_max: int):
+        """The jitted window for the active layout, built on first use."""
+        key = window_cache_key(self.layout, m_max, self.backend, self._statics)
+        return self._windows.get_or_build(key, lambda: self._build(m_max))
+
+    def window_hlo(self, s_batch: int, m_max: int) -> str:
+        """The compiled HLO text of ``window`` for a batch of ``s_batch``
+        rows on the active layout (``TraversalEngine.window_hlo``)."""
+        width = self.layout.state_width
+        sh = traversal_state_sharding(self.mesh)
+        sds = jax.ShapeDtypeStruct
+        state = (
+            sds((s_batch, width), self.program.dtype, sharding=sh),
+            sds((s_batch, width), np.bool_, sharding=sh),
+            sds((s_batch,), np.int32),
+        )
+        lowered = self._window_fn(m_max).lower(*state, *self._consts)
+        return lowered.compile().as_text()
 
     def _build(self, m_max: int):
         body = window_body(
@@ -564,12 +581,14 @@ class MeshTraversalProgram:
         backend: str = "xla", statics=None,
     ):
         # per-device blocks arrive with a leading length-1 device axis
-        lsrc, ldst, lw = lsrc[0], ldst[0], lw[0]
-        lpart, lvalid, part_of_pos = lpart[0], lvalid[0], part_of_pos[0]
-        rsrc, rw, rslot = rsrc[0], rw[0], rslot[0]
-        rpart, rvalid, recv_idx = rpart[0], rvalid[0], recv_idx[0]
-        msrc, mw, mslot = msrc[0], mw[0], mslot[0]
-        mpart, mvalid, mrecv_idx = mpart[0], mvalid[0], mrecv_idx[0]
+        with scope("local", "gather"):
+            lsrc, ldst, lw = lsrc[0], ldst[0], lw[0]
+            lpart, lvalid, part_of_pos = lpart[0], lvalid[0], part_of_pos[0]
+        with scope("remote", "gather"):
+            rsrc, rw, rslot = rsrc[0], rw[0], rslot[0]
+            rpart, rvalid, recv_idx = rpart[0], rvalid[0], recv_idx[0]
+            msrc, mw, mslot = msrc[0], mw[0], mslot[0]
+            mpart, mvalid, mrecv_idx = mpart[0], mvalid[0], mrecv_idx[0]
         s_batch, p = dist.shape[0], n_parts
         ident = prog.identity
         # host-static mirror gate: with no mirror slots the traced program is
@@ -605,11 +624,14 @@ class MeshTraversalProgram:
         # other op (counters, scatters, the collectives) stays on XLA
         use_kernel = backend != "xla"
         if use_kernel:
-            lbs, lbc = blockmaps[0][0], blockmaps[1][0]
-            wbs, wbc = blockmaps[2][0], blockmaps[3][0]
+            with scope("local", "relax"):
+                lbs, lbc = blockmaps[0][0], blockmaps[1][0]
+            with scope("remote", "relax"):
+                wbs, wbc = blockmaps[2][0], blockmaps[3][0]
             bn_l, be_l, lt_max, bn_w, be_w, wt_max = statics[:6]
             if use_mirror:
-                mbs, mbc = blockmaps[4][0], blockmaps[5][0]
+                with scope("remote", "relax"):
+                    mbs, mbc = blockmaps[4][0], blockmaps[5][0]
                 bn_m, be_m, mt_max = statics[6:]
             interp = backend == "pallas-interpret"
 
@@ -676,62 +698,86 @@ class MeshTraversalProgram:
         )
 
         def g_any(flags):  # [S] bool per device -> [S] bool, mesh-global
-            return jax.lax.pmax(flags.astype(jnp.int32), PARTS) > 0
+            with collective():
+                return jax.lax.pmax(flags.astype(jnp.int32), PARTS) > 0
 
-        recv_flat = recv_idx.reshape(-1)  # [D * w_pad] local dst rows
-        mrecv_flat = mrecv_idx.reshape(-1)  # [D * m_pad] local hub rows
+        with scope("remote", "relax"):
+            recv_flat = recv_idx.reshape(-1)  # [D * w_pad] local dst rows
+            mrecv_flat = mrecv_idx.reshape(-1)  # [D * m_pad] local hub rows
 
         def exchange(src_vals, active_re):
             """Wire aggregation -> one all-to-all -> (recv aggregates [S,
             D*w_pad], wire count [S]).  ``combine``-aggregates per
             destination slot BEFORE the collective for any program."""
-            cand = jnp.where(active_re, prog.relax(src_vals, rw), ident)
-            send = red_wire(cand)
-            if prog.reduce == "min":
-                # a slot is on the wire iff some active edge fed it, which
-                # for min-programs is exactly "the aggregate is not identity"
-                wire_s = (send != ident).sum(axis=1).astype(jnp.int32)
-            else:
-                # a sum can legitimately hit the identity; count fed slots
-                wire_s = (
-                    (seg_any_wire(active_re.astype(jnp.int32)) > 0)
-                    .sum(axis=1)
-                    .astype(jnp.int32)
+            with scope("remote", "gather"):
+                cand = jnp.where(active_re, prog.relax(src_vals, rw), ident)
+            with scope("remote", "relax"):
+                send = red_wire(cand)
+            with scope("remote", "counters"):
+                if prog.reduce == "min":
+                    # a slot is on the wire iff some active edge fed it, which
+                    # for min-programs is exactly "the aggregate is not
+                    # identity"
+                    wire_s = (send != ident).sum(axis=1).astype(jnp.int32)
+                else:
+                    # a sum can legitimately hit the identity; count fed slots
+                    wire_s = (
+                        (seg_any_wire(active_re.astype(jnp.int32)) > 0)
+                        .sum(axis=1)
+                        .astype(jnp.int32)
+                    )
+            with scope("remote", "collective"):
+                recv = jax.lax.all_to_all(
+                    send.reshape(s_batch, d_n, w_pad),
+                    PARTS, split_axis=1, concat_axis=1, tiled=True,
                 )
-            recv = jax.lax.all_to_all(
-                send.reshape(s_batch, d_n, w_pad),
-                PARTS, split_axis=1, concat_axis=1, tiled=True,
-            )
-            return recv.reshape(s_batch, -1), wire_s
+                return recv.reshape(s_batch, -1), wire_s
 
         def mirror_sync(send):
             """The second collective: one value per (device, hub) block
             entry, same static-shape tiled all-to-all as the wire plane."""
-            recv = jax.lax.all_to_all(
-                send.reshape(s_batch, d_n, m_pad),
-                PARTS, split_axis=1, concat_axis=1, tiled=True,
-            )
-            return recv.reshape(s_batch, -1)
+            with scope("remote", "collective"):
+                recv = jax.lax.all_to_all(
+                    send.reshape(s_batch, d_n, m_pad),
+                    PARTS, split_axis=1, concat_axis=1, tiled=True,
+                )
+                return recv.reshape(s_batch, -1)
+
+        def upd_counters(s, bufs, rows):
+            """Write each superstep-``s`` counter row into its buffer."""
+            with scope("remote", "counters"):
+                return tuple(
+                    jax.lax.dynamic_update_index_in_dim(buf, row, s, axis=1)
+                    for buf, row in zip(bufs, rows)
+                )
 
         def stationary_superstep(carry):
             # one gather pass (local + wire), program.apply at the boundary
             s, d, fr, we, wv, ms, it, wire, nst = carry
-            nst = nst + g_any(fr.any(axis=1)).astype(jnp.int32)
+            with scope("local", "frontier"):
+                nst = nst + g_any(fr.any(axis=1)).astype(jnp.int32)
 
-            active_le = fr[:, lsrc] & lvalid
-            cand = jnp.where(active_le, prog.relax(d[:, lsrc], lw), ident)
-            acc = relax_l(cand)
-            we_s = seg_sum_lp(active_le.astype(jnp.int32))
-            wv_s = seg_sum_vp(fr.astype(jnp.int32))
-            it_s = g_any(fr.any(axis=1)).astype(jnp.int32)
+            with scope("local", "gather"):
+                active_le = fr[:, lsrc] & lvalid
+                cand = jnp.where(active_le, prog.relax(d[:, lsrc], lw), ident)
+            with scope("local", "relax"):
+                acc = relax_l(cand)
+            with scope("local", "counters"):
+                we_s = seg_sum_lp(active_le.astype(jnp.int32))
+                wv_s = seg_sum_vp(fr.astype(jnp.int32))
+                it_s = g_any(fr.any(axis=1)).astype(jnp.int32)
 
-            active_re = fr[:, rsrc] & rvalid
-            recv, wire_s = exchange(d[:, rsrc], active_re)
-            if prog.reduce == "min":
-                acc = acc.at[:, recv_flat].min(recv)
-            else:
-                acc = acc.at[:, recv_flat].add(recv)
-            ms_s = seg_sum_rp(active_re.astype(jnp.int32))
+            with scope("remote", "gather"):
+                active_re = fr[:, rsrc] & rvalid
+                src_vals = d[:, rsrc]
+            recv, wire_s = exchange(src_vals, active_re)
+            with scope("remote", "relax"):
+                if prog.reduce == "min":
+                    acc = acc.at[:, recv_flat].min(recv)
+                else:
+                    acc = acc.at[:, recv_flat].add(recv)
+            with scope("remote", "counters"):
+                ms_s = seg_sum_rp(active_re.astype(jnp.int32))
 
             if use_mirror:
                 # stateless mirror: combine locally per (owner, hub), sync
@@ -739,74 +785,89 @@ class MeshTraversalProgram:
                 # cross-superstep suppression is sound here.  Fed-slot
                 # billing matches the wire plane's, so wire_msgs is
                 # unchanged vs the unmirrored path.
-                active_me = fr[:, msrc] & mvalid
-                mcand = jnp.where(
-                    active_me, prog.relax(d[:, msrc], mw), ident
-                )
-                msend = red_mir(mcand)
-                if prog.reduce == "min":
-                    wire_m = (msend != ident).sum(axis=1).astype(jnp.int32)
-                else:
-                    wire_m = (
-                        (seg_any_mir(active_me.astype(jnp.int32)) > 0)
-                        .sum(axis=1)
-                        .astype(jnp.int32)
+                with scope("remote", "gather"):
+                    active_me = fr[:, msrc] & mvalid
+                    mcand = jnp.where(
+                        active_me, prog.relax(d[:, msrc], mw), ident
                     )
+                with scope("remote", "relax"):
+                    msend = red_mir(mcand)
+                with scope("remote", "counters"):
+                    if prog.reduce == "min":
+                        wire_m = (msend != ident).sum(axis=1).astype(jnp.int32)
+                    else:
+                        wire_m = (
+                            (seg_any_mir(active_me.astype(jnp.int32)) > 0)
+                            .sum(axis=1)
+                            .astype(jnp.int32)
+                        )
                 mrecv = mirror_sync(msend)
-                if prog.reduce == "min":
-                    acc = acc.at[:, mrecv_flat].min(mrecv)
-                else:
-                    acc = acc.at[:, mrecv_flat].add(mrecv)
-                wire_s = wire_s + wire_m
-                ms_s = ms_s + seg_sum_mp(active_me.astype(jnp.int32))
+                with scope("remote", "relax"):
+                    if prog.reduce == "min":
+                        acc = acc.at[:, mrecv_flat].min(mrecv)
+                    else:
+                        acc = acc.at[:, mrecv_flat].add(mrecv)
+                with scope("remote", "counters"):
+                    wire_s = wire_s + wire_m
+                    ms_s = ms_s + seg_sum_mp(active_me.astype(jnp.int32))
 
-            new_d = prog.apply(d, acc, n_global)
-            next_fr = fr & prog.keep_running(nst)[:, None]
+            with scope("remote", "frontier"):
+                new_d = prog.apply(d, acc, n_global)
+                next_fr = fr & prog.keep_running(nst)[:, None]
+                s_next = s + 1
 
-            upd = lambda buf, row: jax.lax.dynamic_update_index_in_dim(
-                buf, row, s, axis=1
+            we, wv, ms, it, wire = upd_counters(
+                s, (we, wv, ms, it, wire), (we_s, wv_s, ms_s, it_s, wire_s)
             )
-            return (
-                s + 1, new_d, next_fr,
-                upd(we, we_s), upd(wv, wv_s), upd(ms, ms_s),
-                upd(it, it_s), upd(wire, wire_s), nst,
-            )
+            return s_next, new_d, next_fr, we, wv, ms, it, wire, nst
 
         def monotone_superstep(carry):
             if use_cache:
                 s, d, fr, we, wv, ms, it, wire, nst, mcache = carry
             else:
                 s, d, fr, we, wv, ms, it, wire, nst = carry
-            nst = nst + g_any(fr.any(axis=1)).astype(jnp.int32)
+            with scope("local", "frontier"):
+                nst = nst + g_any(fr.any(axis=1)).astype(jnp.int32)
 
             # -- local closure: same iteration count on every device ----------
             def icond(c):
-                return jax.lax.pmax(c[1].any().astype(jnp.int32), PARTS) > 0
+                with scope("local", "frontier"):
+                    return g_any(c[1].any())
 
             def ibody(c):
                 d_i, f_i, we_s, wv_s, it_s, touched = c
-                active_e = f_i[:, lsrc] & lvalid
-                cand = jnp.where(
-                    active_e, prog.relax(d_i[:, lsrc], lw), ident
-                )
-                new_d = relax_l(cand, d_i)
-                improved = prog.is_active(new_d, d_i)
-                we_s = we_s + seg_sum_lp(active_e.astype(jnp.int32))
-                wv_s = wv_s + seg_sum_vp(f_i.astype(jnp.int32))
-                it_s = it_s + g_any(f_i.any(axis=1)).astype(jnp.int32)
-                return new_d, improved, we_s, wv_s, it_s, touched | improved
+                with scope("local", "gather"):
+                    active_e = f_i[:, lsrc] & lvalid
+                    cand = jnp.where(
+                        active_e, prog.relax(d_i[:, lsrc], lw), ident
+                    )
+                with scope("local", "relax"):
+                    new_d = relax_l(cand, d_i)
+                with scope("local", "frontier"):
+                    improved = prog.is_active(new_d, d_i)
+                    touched = touched | improved
+                with scope("local", "counters"):
+                    we_s = we_s + seg_sum_lp(active_e.astype(jnp.int32))
+                    wv_s = wv_s + seg_sum_vp(f_i.astype(jnp.int32))
+                    it_s = it_s + g_any(f_i.any(axis=1)).astype(jnp.int32)
+                return new_d, improved, we_s, wv_s, it_s, touched
 
-            z_p = jnp.zeros((s_batch, p), jnp.int32)
-            z_s = jnp.zeros((s_batch,), jnp.int32)
+            with scope("local", "counters"):
+                z_p = jnp.zeros((s_batch, p), jnp.int32)
+                z_s = jnp.zeros((s_batch,), jnp.int32)
             d2, _, we_s, wv_s, it_s, touched = jax.lax.while_loop(
                 icond, ibody, (d, fr, z_p, z_p, z_s, fr)
             )
 
             # -- exchange: aggregate per destination, then ONE all-to-all -----
-            active_re = touched[:, rsrc] & rvalid
-            recv, wire_s = exchange(d2[:, rsrc], active_re)
-            new_d = d2.at[:, recv_flat].min(recv)
-            ms_s = seg_sum_rp(active_re.astype(jnp.int32))
+            with scope("remote", "gather"):
+                active_re = touched[:, rsrc] & rvalid
+                src_vals = d2[:, rsrc]
+            recv, wire_s = exchange(src_vals, active_re)
+            with scope("remote", "relax"):
+                new_d = d2.at[:, recv_flat].min(recv)
+            with scope("remote", "counters"):
+                ms_s = seg_sum_rp(active_re.astype(jnp.int32))
 
             if use_cache:
                 # -- mirror sync: combine into the window-local cache, send
@@ -814,29 +875,32 @@ class MeshTraversalProgram:
                 # min-programs: an unimproved candidate is >= the cache,
                 # which was synced the superstep it last improved, so the
                 # owner already holds a value <= it (module docstring).
-                active_me = touched[:, msrc] & mvalid
-                mcand = jnp.where(
-                    active_me, prog.relax(d2[:, msrc], mw), ident
-                )
-                new_mc = red_mir(mcand, mcache)
-                improved_m = prog.is_active(new_mc, mcache)
-                msend = jnp.where(improved_m, new_mc, ident)
-                wire_m = (msend != ident).sum(axis=1).astype(jnp.int32)
+                with scope("remote", "gather"):
+                    active_me = touched[:, msrc] & mvalid
+                    mcand = jnp.where(
+                        active_me, prog.relax(d2[:, msrc], mw), ident
+                    )
+                with scope("remote", "relax"):
+                    new_mc = red_mir(mcand, mcache)
+                    improved_m = prog.is_active(new_mc, mcache)
+                    msend = jnp.where(improved_m, new_mc, ident)
+                with scope("remote", "counters"):
+                    wire_m = (msend != ident).sum(axis=1).astype(jnp.int32)
                 mrecv = mirror_sync(msend)
-                new_d = new_d.at[:, mrecv_flat].min(mrecv)
-                wire_s = wire_s + wire_m
-                ms_s = ms_s + seg_sum_mp(active_me.astype(jnp.int32))
+                with scope("remote", "relax"):
+                    new_d = new_d.at[:, mrecv_flat].min(mrecv)
+                with scope("remote", "counters"):
+                    wire_s = wire_s + wire_m
+                    ms_s = ms_s + seg_sum_mp(active_me.astype(jnp.int32))
 
-            next_fr = prog.is_active(new_d, d2)
+            with scope("remote", "frontier"):
+                next_fr = prog.is_active(new_d, d2)
+                s_next = s + 1
 
-            upd = lambda buf, row: jax.lax.dynamic_update_index_in_dim(
-                buf, row, s, axis=1
+            we, wv, ms, it, wire = upd_counters(
+                s, (we, wv, ms, it, wire), (we_s, wv_s, ms_s, it_s, wire_s)
             )
-            out = (
-                s + 1, new_d, next_fr,
-                upd(we, we_s), upd(wv, wv_s), upd(ms, ms_s),
-                upd(it, it_s), upd(wire, wire_s), nst,
-            )
+            out = (s_next, new_d, next_fr, we, wv, ms, it, wire, nst)
             if use_cache:
                 out = out + (new_mc,)
             return out
@@ -847,33 +911,36 @@ class MeshTraversalProgram:
 
         def superstep_cond(carry):
             s, _, fr, *_ = carry
-            return (s < m_max) & (
-                jax.lax.pmax(fr.any().astype(jnp.int32), PARTS) > 0
-            )
+            with scope("remote", "frontier"):
+                return (s < m_max) & g_any(fr.any())
 
-        zeros_smp = jnp.zeros((s_batch, m_max, p), jnp.int32)
-        zeros_sm = jnp.zeros((s_batch, m_max), jnp.int32)
-        init = (
-            jnp.int32(0), dist, frontier,
-            zeros_smp, zeros_smp, zeros_smp, zeros_sm, zeros_sm, nst0,
-        )
+        with scope("local", "counters"):
+            zeros_smp = jnp.zeros((s_batch, m_max, p), jnp.int32)
+            zeros_sm = jnp.zeros((s_batch, m_max), jnp.int32)
+            init = (
+                jnp.int32(0), dist, frontier,
+                zeros_smp, zeros_smp, zeros_smp, zeros_sm, zeros_sm, nst0,
+            )
         if use_cache:
             # the mirror cache is window-local: it starts at identity each
             # window, so the first improvement after a window boundary (or a
             # relayout swap, which happens only between windows) re-syncs --
             # a harmless duplicate send, never a missed one
-            init = init + (
-                jnp.full((s_batch, d_n * m_pad), ident, dist.dtype),
-            )
+            with scope("remote", "relax"):
+                init = init + (
+                    jnp.full((s_batch, d_n * m_pad), ident, dist.dtype),
+                )
         final = jax.lax.while_loop(superstep_cond, superstep_body, init)
         _, d, fr, we, wv, ms, it, wire, nst = final[:9]
         # partitions never span devices: the psum of disjoint partial
         # counters reconstructs the exact global integers
-        we = jax.lax.psum(we, PARTS)
-        wv = jax.lax.psum(wv, PARTS)
-        ms = jax.lax.psum(ms, PARTS)
-        wire = jax.lax.psum(wire, PARTS)
-        pact = jax.lax.psum(seg_sum_vp(fr.astype(jnp.int32)), PARTS) > 0
-        done = ~g_any(fr.any(axis=1))
-        sg = jnp.zeros((s_batch, m_max, 0), bool)  # mesh: single-device-only
+        with scope(FINISH):
+            with collective():
+                we = jax.lax.psum(we, PARTS)
+                wv = jax.lax.psum(wv, PARTS)
+                ms = jax.lax.psum(ms, PARTS)
+                wire = jax.lax.psum(wire, PARTS)
+                pact = jax.lax.psum(seg_sum_vp(fr.astype(jnp.int32)), PARTS) > 0
+            done = ~g_any(fr.any(axis=1))
+            sg = jnp.zeros((s_batch, m_max, 0), bool)  # mesh: single-device-only
         return d, fr, nst, we, wv, ms, it, sg, wire, pact, done

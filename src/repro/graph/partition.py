@@ -33,6 +33,7 @@ from repro.graph.structs import (
     dst_sorted_layout,
     mesh_layout_key,
 )
+from repro.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,18 +60,29 @@ def partitioned_edge_layout(pg: PartitionedGraph) -> PartitionedEdgeLayout:
     if cached is not None:
         return cached
     g = pg.graph
-    local = pg.is_local_edge
-    w = g.edge_weights
-    part = pg.part_of_vertex.astype(np.int32)
-    loc = dst_sorted_layout(g.n_vertices, g.src[local], g.dst[local], w[local])
-    rem = dst_sorted_layout(g.n_vertices, g.src[~local], g.dst[~local], w[~local])
+    with span("graph.layout"):
+        local = pg.is_local_edge
+        w = g.edge_weights
+        part = pg.part_of_vertex.astype(np.int32)
+        with span("graph.layout.local"):
+            loc = dst_sorted_layout(
+                g.n_vertices, g.src[local], g.dst[local], w[local]
+            )
+            local_part = part[loc.src]
+            local_eid = np.flatnonzero(local)[loc.perm]
+        with span("graph.layout.remote"):
+            rem = dst_sorted_layout(
+                g.n_vertices, g.src[~local], g.dst[~local], w[~local]
+            )
+            remote_src_part = part[rem.src]
+            remote_eid = np.flatnonzero(~local)[rem.perm]
     layout = PartitionedEdgeLayout(
         local=loc,
         remote=rem,
-        local_part=part[loc.src],
-        remote_src_part=part[rem.src],
-        local_eid=np.flatnonzero(local)[loc.perm],
-        remote_eid=np.flatnonzero(~local)[rem.perm],
+        local_part=local_part,
+        remote_src_part=remote_src_part,
+        local_eid=local_eid,
+        remote_eid=remote_eid,
     )
     pg.__dict__["_edge_layout"] = layout
     return layout
@@ -658,16 +670,33 @@ def bfs_grow_partition(
     rng = np.random.default_rng(seed)
     n, k = g.n_vertices, n_parts
     cap = int(np.ceil(balance * n / k))
-    row_ptr, col, _ = g.csr
+    with span("graph.partition"):
+        with span("graph.partition.seeds"):
+            row_ptr, col, _ = g.csr
+            seeds = _farthest_seeds(row_ptr, col, n, k, rng)
+        with span("graph.partition.grow"):
+            part = _grow_regions(row_ptr, col, n, k, cap, seeds)
+        with span("graph.partition.refine"):
+            for _ in range(refine_sweeps):
+                part = _refine_once(g, part, k, cap)
 
-    # --- farthest-first seed selection on an undirected view ---------------
+    return PartitionedGraph(g, k, part)
+
+
+def _farthest_seeds(row_ptr, col, n: int, k: int, rng) -> list:
+    """``k`` seeds spread apart: iterative farthest-first on hops."""
     seeds = [int(rng.integers(n))]
     dist = _bfs_hops(row_ptr, col, n, seeds[0])
     for _ in range(k - 1):
         cand = int(np.argmax(np.where(np.isfinite(dist), dist, -1.0)))
         seeds.append(cand)
         dist = np.minimum(dist, _bfs_hops(row_ptr, col, n, cand))
+    return seeds
 
+
+def _grow_regions(row_ptr, col, n: int, k: int, cap: int, seeds) -> np.ndarray:
+    """Round-robin frontier expansion from ``seeds``; each region claims
+    unassigned neighbors until it reaches ``cap``."""
     part = np.full(n, -1, dtype=np.int32)
     sizes = np.zeros(k, dtype=np.int64)
     frontiers: list[np.ndarray] = []
@@ -707,12 +736,7 @@ def bfs_grow_partition(
                 part[v] = p
                 sizes[p] += 1
             break
-
-    # --- greedy boundary refinement -----------------------------------------
-    for _ in range(refine_sweeps):
-        part = _refine_once(g, part, k, cap)
-
-    return PartitionedGraph(g, k, part)
+    return part
 
 
 def _bfs_hops(row_ptr: np.ndarray, col: np.ndarray, n: int, source: int) -> np.ndarray:

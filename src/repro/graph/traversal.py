@@ -99,6 +99,7 @@ from repro.graph.program import (
 )
 from repro.graph.structs import BoundedCache, PartitionedGraph
 from repro.kernels.bfs_relax.ops import make_relax_fn, validate_backend
+from repro.spans import FINISH, scope, span
 
 #: per-graph bound on cached per-program edge-plane device arrays (keyed by
 #: coerced ``plane_key``) and on cached engines (keyed by coerced knobs) --
@@ -399,56 +400,57 @@ class TraversalEngine:
         self.mirror_degree = (
             None if mirror_degree is None else int(mirror_degree)
         )
-        self._mesh_prog = None
-        if mesh is not None and int(mesh.devices.size) > 1:
-            if collect_subgraphs:
-                raise NotImplementedError(
-                    "collect_subgraphs is single-device-only; run the "
-                    "metagraph ground-truth pass without a mesh"
-                )
-            from repro.graph.mesh_exchange import MeshTraversalProgram
-
-            self._mesh_prog = MeshTraversalProgram(
-                pg, mesh, device_of_part=device_of_part,
-                program=self.program, backend=backend,
-                block_n=block_n, block_e=block_e,
-                mirror_degree=self.mirror_degree,
-            )
-        # kernel backends: host-built relax fns over the static block maps
-        # (None on xla, where the window runs XLA segment reductions)
-        self._relax_l = self._relax_r = None
-        # the dense window's static arrays travel as an argument: a jitted
-        # function that closed over them would embed every edge array in the
-        # compiled program as a constant, once per compiled (k, S) variant.
-        # Mesh launches never trace the dense window and the mesh program
-        # shards its own arrays, so a mesh engine uploads none of these.
-        self._consts = None
-        if self._mesh_prog is None:
-            lmap = rmap = ()
-            if backend != "xla":
-                layout = partitioned_edge_layout(pg)
-                self._relax_l, lmap = make_relax_fn(
-                    layout.local.dst, self.n, reduce=self.program.reduce,
-                    block_n=block_n, block_e=block_e, interpret=interpret,
-                )
-                self._relax_r, rmap = make_relax_fn(
-                    layout.remote.dst, self.n, reduce=self.program.reduce,
-                    block_n=block_n, block_e=block_e, interpret=interpret,
-                )
-            dev = _device_arrays(pg)  # shared across engines on this graph
-            lw, rw = plane_arrays(pg, self.program)
-            sg = None
-            if collect_subgraphs:
-                if "_sg_device" not in pg.__dict__:
-                    pg.__dict__["_sg_device"] = jnp.asarray(
-                        pg.subgraph_of_vertex.astype(np.int32)
+        with span("graph.engine_build"):
+            self._mesh_prog = None
+            if mesh is not None and int(mesh.devices.size) > 1:
+                if collect_subgraphs:
+                    raise NotImplementedError(
+                        "collect_subgraphs is single-device-only; run the "
+                        "metagraph ground-truth pass without a mesh"
                     )
-                sg = pg.__dict__["_sg_device"]
-            self._consts = _WindowConsts(
-                dev.lsrc, dev.ldst, lw, dev.lpart,
-                dev.rsrc, dev.rdst, rw, dev.rpart,
-                dev.vpart, sg, lmap, rmap,
-            )
+                from repro.graph.mesh_exchange import MeshTraversalProgram
+
+                self._mesh_prog = MeshTraversalProgram(
+                    pg, mesh, device_of_part=device_of_part,
+                    program=self.program, backend=backend,
+                    block_n=block_n, block_e=block_e,
+                    mirror_degree=self.mirror_degree,
+                )
+            # kernel backends: host-built relax fns over the static block maps
+            # (None on xla, where the window runs XLA segment reductions)
+            self._relax_l = self._relax_r = None
+            # the dense window's static arrays travel as an argument: a jitted
+            # function that closed over them would embed every edge array in the
+            # compiled program as a constant, once per compiled (k, S) variant.
+            # Mesh launches never trace the dense window and the mesh program
+            # shards its own arrays, so a mesh engine uploads none of these.
+            self._consts = None
+            if self._mesh_prog is None:
+                lmap = rmap = ()
+                if backend != "xla":
+                    layout = partitioned_edge_layout(pg)
+                    self._relax_l, lmap = make_relax_fn(
+                        layout.local.dst, self.n, reduce=self.program.reduce,
+                        block_n=block_n, block_e=block_e, interpret=interpret,
+                    )
+                    self._relax_r, rmap = make_relax_fn(
+                        layout.remote.dst, self.n, reduce=self.program.reduce,
+                        block_n=block_n, block_e=block_e, interpret=interpret,
+                    )
+                dev = _device_arrays(pg)  # shared across engines on this graph
+                lw, rw = plane_arrays(pg, self.program)
+                sg = None
+                if collect_subgraphs:
+                    if "_sg_device" not in pg.__dict__:
+                        pg.__dict__["_sg_device"] = jnp.asarray(
+                            pg.subgraph_of_vertex.astype(np.int32)
+                        )
+                    sg = pg.__dict__["_sg_device"]
+                self._consts = _WindowConsts(
+                    dev.lsrc, dev.ldst, lw, dev.lpart,
+                    dev.rsrc, dev.rdst, rw, dev.rpart,
+                    dev.vpart, sg, lmap, rmap,
+                )
         # one jitted program serves both modes: run() launches a single
         # window of depth m_max, run_window() launches depth k (static arg,
         # compiled once per distinct k/S)
@@ -502,6 +504,24 @@ class TraversalEngine:
             int(k),
             jax.tree.map(lambda a: sds(a.shape, a.dtype), self._consts),
         )
+
+    def window_hlo(self, s_batch: int, k: int) -> str:
+        """The compiled HLO text of the window program this engine launches
+        for a batch of ``s_batch`` sources and a depth of ``k`` supersteps
+        (``run`` launches ``k = m_max``).  Each instruction's ``op_name``
+        metadata carries its device scope (``repro.spans``), which a
+        profiler trace's op names are joined to.  Compiles, or loads from the
+        persistent compile cache, the program a launch would run."""
+        sds = jax.ShapeDtypeStruct
+        if self._mesh_prog is not None:
+            return self._mesh_prog.window_hlo(s_batch, k)
+        state = (
+            sds((s_batch, self.n), self.program.dtype),
+            sds((s_batch, self.n), np.bool_),
+            sds((s_batch,), np.int32),
+        )
+        lowered = self._window.lower(*state, int(k), self._consts)
+        return lowered.compile().as_text()
 
     # -- device program ------------------------------------------------------
 
@@ -572,117 +592,144 @@ class TraversalEngine:
             # one gather pass over local + remote edges, program.apply at the
             # boundary, frontier drained by the iteration budget
             s, d, fr, we, wv, ms, it, sg, nst = carry
-            if self.collect_subgraphs:
-                sg = jax.lax.dynamic_update_index_in_dim(
-                    sg, seg_any_sg(fr), s, axis=1
+            with scope("local", "counters"):
+                if self.collect_subgraphs:
+                    sg = jax.lax.dynamic_update_index_in_dim(
+                        sg, seg_any_sg(fr), s, axis=1
+                    )
+            with scope("local", "frontier"):
+                nst = nst + fr.any(axis=1).astype(jnp.int32)
+
+            with scope("local", "gather"):
+                active_le = fr[:, lsrc]
+                cand = jnp.where(
+                    active_le, prog.relax(d[:, lsrc], lw), ident
                 )
-            nst = nst + fr.any(axis=1).astype(jnp.int32)
+            with scope("local", "relax"):
+                acc = relax_l(cand)
+            with scope("local", "counters"):
+                we_s = seg_sum_lp(active_le.astype(jnp.int32))
+                wv_s = seg_sum_vp(fr.astype(jnp.int32))
+                it_s = fr.any(axis=1).astype(jnp.int32)  # one pass per superstep
 
-            active_le = fr[:, lsrc]
-            cand = jnp.where(
-                active_le, prog.relax(d[:, lsrc], lw), ident
-            )
-            acc = relax_l(cand)
-            we_s = seg_sum_lp(active_le.astype(jnp.int32))
-            wv_s = seg_sum_vp(fr.astype(jnp.int32))
-            it_s = fr.any(axis=1).astype(jnp.int32)  # one pass per superstep
+            with scope("remote", "gather"):
+                active_re = fr[:, rsrc]
+                cand_r = jnp.where(
+                    active_re, prog.relax(d[:, rsrc], rw), ident
+                )
+            with scope("remote", "relax"):
+                acc = relax_r(cand_r, acc)
+            with scope("remote", "counters"):
+                ms_s = seg_sum_rp(active_re.astype(jnp.int32))
 
-            active_re = fr[:, rsrc]
-            cand_r = jnp.where(
-                active_re, prog.relax(d[:, rsrc], rw), ident
-            )
-            acc = relax_r(cand_r, acc)
-            ms_s = seg_sum_rp(active_re.astype(jnp.int32))
+            with scope("remote", "frontier"):
+                new_d = prog.apply(d, acc, n)
+                next_fr = fr & prog.keep_running(nst)[:, None]
+                s_next = s + 1
 
-            new_d = prog.apply(d, acc, n)
-            next_fr = fr & prog.keep_running(nst)[:, None]
-
-            we = jax.lax.dynamic_update_index_in_dim(we, we_s, s, axis=1)
-            wv = jax.lax.dynamic_update_index_in_dim(wv, wv_s, s, axis=1)
-            ms = jax.lax.dynamic_update_index_in_dim(ms, ms_s, s, axis=1)
-            it = jax.lax.dynamic_update_index_in_dim(it, it_s, s, axis=1)
-            return s + 1, new_d, next_fr, we, wv, ms, it, sg, nst
+            with scope("remote", "counters"):
+                we = jax.lax.dynamic_update_index_in_dim(we, we_s, s, axis=1)
+                wv = jax.lax.dynamic_update_index_in_dim(wv, wv_s, s, axis=1)
+                ms = jax.lax.dynamic_update_index_in_dim(ms, ms_s, s, axis=1)
+                it = jax.lax.dynamic_update_index_in_dim(it, it_s, s, axis=1)
+            return s_next, new_d, next_fr, we, wv, ms, it, sg, nst
 
         def monotone_body(carry):
             s, d, fr, we, wv, ms, it, sg, nst = carry
 
-            if self.collect_subgraphs:
-                sg = jax.lax.dynamic_update_index_in_dim(
-                    sg, seg_any_sg(fr), s, axis=1
-                )
-            nst = nst + fr.any(axis=1).astype(jnp.int32)
+            with scope("local", "counters"):
+                if self.collect_subgraphs:
+                    sg = jax.lax.dynamic_update_index_in_dim(
+                        sg, seg_any_sg(fr), s, axis=1
+                    )
+            with scope("local", "frontier"):
+                nst = nst + fr.any(axis=1).astype(jnp.int32)
 
             # -- local closure over the partition-local edges -----------------
             def icond(c):
-                return c[1].any()
+                with scope("local", "frontier"):
+                    return c[1].any()
 
             def ibody(c):
                 d_i, f_i, we_s, wv_s, it_s, touched = c
-                active_e = f_i[:, lsrc]
-                cand = jnp.where(
-                    active_e, prog.relax(d_i[:, lsrc], lw), ident
-                )
-                new_d = relax_l(cand, d_i)
-                improved = prog.is_active(new_d, d_i)
-                we_s = we_s + seg_sum_lp(active_e.astype(jnp.int32))
-                wv_s = wv_s + seg_sum_vp(f_i.astype(jnp.int32))
-                it_s = it_s + f_i.any(axis=1).astype(jnp.int32)
-                return new_d, improved, we_s, wv_s, it_s, touched | improved
+                with scope("local", "gather"):
+                    active_e = f_i[:, lsrc]
+                    cand = jnp.where(
+                        active_e, prog.relax(d_i[:, lsrc], lw), ident
+                    )
+                with scope("local", "relax"):
+                    new_d = relax_l(cand, d_i)
+                with scope("local", "frontier"):
+                    improved = prog.is_active(new_d, d_i)
+                    touched = touched | improved
+                with scope("local", "counters"):
+                    we_s = we_s + seg_sum_lp(active_e.astype(jnp.int32))
+                    wv_s = wv_s + seg_sum_vp(f_i.astype(jnp.int32))
+                    it_s = it_s + f_i.any(axis=1).astype(jnp.int32)
+                return new_d, improved, we_s, wv_s, it_s, touched
 
-            z_p = jnp.zeros((s_batch, p), jnp.int32)
-            z_s = jnp.zeros((s_batch,), jnp.int32)
+            with scope("local", "counters"):
+                z_p = jnp.zeros((s_batch, p), jnp.int32)
+                z_s = jnp.zeros((s_batch,), jnp.int32)
             d2, _, we_s, wv_s, it_s, touched = jax.lax.while_loop(
                 icond, ibody, (d, fr, z_p, z_p, z_s, fr)
             )
 
             # -- remote exchange at the superstep boundary --------------------
-            active_re = touched[:, rsrc]
-            cand = jnp.where(
-                active_re, prog.relax(d2[:, rsrc], rw), ident
-            )
-            new_d = relax_r(cand, d2)
-            next_fr = prog.is_active(new_d, d2)
-            ms_s = seg_sum_rp(active_re.astype(jnp.int32))
-
-            we = jax.lax.dynamic_update_index_in_dim(we, we_s, s, axis=1)
-            wv = jax.lax.dynamic_update_index_in_dim(wv, wv_s, s, axis=1)
-            ms = jax.lax.dynamic_update_index_in_dim(ms, ms_s, s, axis=1)
-            it = jax.lax.dynamic_update_index_in_dim(it, it_s, s, axis=1)
-            return s + 1, new_d, next_fr, we, wv, ms, it, sg, nst
+            with scope("remote", "gather"):
+                active_re = touched[:, rsrc]
+                cand = jnp.where(
+                    active_re, prog.relax(d2[:, rsrc], rw), ident
+                )
+            with scope("remote", "relax"):
+                new_d = relax_r(cand, d2)
+            with scope("remote", "frontier"):
+                next_fr = prog.is_active(new_d, d2)
+                s_next = s + 1
+            with scope("remote", "counters"):
+                ms_s = seg_sum_rp(active_re.astype(jnp.int32))
+                we = jax.lax.dynamic_update_index_in_dim(we, we_s, s, axis=1)
+                wv = jax.lax.dynamic_update_index_in_dim(wv, wv_s, s, axis=1)
+                ms = jax.lax.dynamic_update_index_in_dim(ms, ms_s, s, axis=1)
+                it = jax.lax.dynamic_update_index_in_dim(it, it_s, s, axis=1)
+            return s_next, new_d, next_fr, we, wv, ms, it, sg, nst
 
         superstep_body = stationary_body if prog.stationary else monotone_body
 
         def superstep_cond(carry):
             s, _, fr, *_ = carry
-            return (s < m_max) & fr.any()
+            with scope("remote", "frontier"):
+                return (s < m_max) & fr.any()
 
-        zeros_smp = jnp.zeros((s_batch, m_max, p), jnp.int32)
-        init = (
-            jnp.int32(0),
-            dist,
-            frontier,
-            zeros_smp,
-            zeros_smp,
-            zeros_smp,
-            jnp.zeros((s_batch, m_max), jnp.int32),
-            jnp.zeros((s_batch, m_max, n_sg), bool),
-            nst0,
-        )
+        with scope("local", "counters"):
+            zeros_smp = jnp.zeros((s_batch, m_max, p), jnp.int32)
+            init = (
+                jnp.int32(0),
+                dist,
+                frontier,
+                zeros_smp,
+                zeros_smp,
+                zeros_smp,
+                jnp.zeros((s_batch, m_max), jnp.int32),
+                jnp.zeros((s_batch, m_max, n_sg), bool),
+                nst0,
+            )
         _, d, fr, we, wv, ms, it, sg, nst = jax.lax.while_loop(
             superstep_cond, superstep_body, init
         )
         # next-superstep partition activity + done flags, computed on device
         # so the executor's placement decision needs no extra [n]-sized pull
-        pact = (
-            jax.vmap(
-                lambda f: jax.ops.segment_max(
-                    f.astype(jnp.int32), vpart, num_segments=p
-                )
-            )(fr)
-            > 0
-        )
-        done = ~fr.any(axis=1)
-        wire = jnp.zeros((s_batch, m_max), jnp.int32)  # dense: no wire
+        with scope(FINISH):
+            pact = (
+                jax.vmap(
+                    lambda f: jax.ops.segment_max(
+                        f.astype(jnp.int32), vpart, num_segments=p
+                    )
+                )(fr)
+                > 0
+            )
+            done = ~fr.any(axis=1)
+            wire = jnp.zeros((s_batch, m_max), jnp.int32)  # dense: no wire
         return TraversalResult(d, fr, nst, we, wv, ms, it, sg, wire), pact, done
 
     # -- host API ------------------------------------------------------------
@@ -697,14 +744,13 @@ class TraversalEngine:
         """
         sources = np.atleast_1d(np.asarray(sources, dtype=np.int64))
         s_batch = sources.shape[0]
-        if self._mesh_prog is not None:
-            dist, frontier = self._mesh_prog.init_state(sources)
+        with span("graph.init"):
+            if self._mesh_prog is not None:
+                dist, frontier = self._mesh_prog.init_state(sources)
+            else:
+                state, frontier = self.program.init(self.pg, sources)
+                dist, frontier = jnp.asarray(state), jnp.asarray(frontier)
             return WindowState(dist, frontier, jnp.zeros((s_batch,), jnp.int32))
-        state, frontier = self.program.init(self.pg, sources)
-        return WindowState(
-            jnp.asarray(state), jnp.asarray(frontier),
-            jnp.zeros((s_batch,), jnp.int32),
-        )
 
     def backfill_rows(self, state: WindowState, rows, sources) -> WindowState:
         """Replace carried-state batch rows at a window boundary (in place of
@@ -799,21 +845,24 @@ class TraversalEngine:
         if k < 1:
             raise ValueError(f"window size must be >= 1, got {k}")
         if device_of_part is not None and self._mesh_prog is not None:
-            state, _ = self._mesh_prog.ensure_layout(state, device_of_part)
-        res, pact, done = self._launch(
-            state.dist, state.frontier, state.n_supersteps, k
-        )
-        nst, we, wv, ms, it, pact, done = jax.device_get(
-            (
-                res.n_supersteps,
-                res.edges_examined,
-                res.verts_processed,
-                res.msgs_sent,
-                res.inner_iters,
-                pact,
-                done,
+            with span("graph.relayout"):
+                state, _ = self._mesh_prog.ensure_layout(state, device_of_part)
+        with span("graph.launch"):
+            res, pact, done = self._launch(
+                state.dist, state.frontier, state.n_supersteps, k
             )
-        )
+        with span("graph.fetch"):
+            nst, we, wv, ms, it, pact, done = jax.device_get(
+                (
+                    res.n_supersteps,
+                    res.edges_examined,
+                    res.verts_processed,
+                    res.msgs_sent,
+                    res.inner_iters,
+                    pact,
+                    done,
+                )
+            )
         return WindowResult(
             state=WindowState(res.dist, res.frontier, res.n_supersteps),
             n_supersteps=nst,
@@ -835,16 +884,18 @@ class TraversalEngine:
         supersteps.
         """
         state = self.init_state(sources)
-        res, _, _ = self._launch(
-            state.dist, state.frontier, state.n_supersteps, self.m_max
-        )
-        res = jax.device_get(res)
-        if self._mesh_prog is not None:
-            # padded device-major -> global vertex order for host consumers
-            res = res._replace(
-                dist=self.gather_global(res.dist),
-                frontier=self.gather_global(res.frontier),
+        with span("graph.launch"):
+            res, _, _ = self._launch(
+                state.dist, state.frontier, state.n_supersteps, self.m_max
             )
+        with span("graph.fetch"):
+            res = jax.device_get(res)
+            if self._mesh_prog is not None:
+                # padded device-major -> global vertex order for host consumers
+                res = res._replace(
+                    dist=self.gather_global(res.dist),
+                    frontier=self.gather_global(res.frontier),
+                )
         if not self.program.converged(bool(res.frontier.any())):
             raise TraversalNotConverged(self.m_max, res)
         return res
