@@ -7,6 +7,8 @@ boundary, remote edges deliver messages, and vertices improved by a remote
 message form the next superstep's frontier (their subgraphs become active).
 The engine also accumulates the per-partition *work counters* (vertices
 processed, edges examined) that instantiate the paper's time function A.
+Every arc counts toward its source's partition, so the dense window takes
+them from per-vertex degrees, reduced densely into parts, not per-arc scatters.
 
 The per-edge/per-vertex math is no longer hard-coded BFS: both window
 programs route every relaxation, segment reduction, frontier predicate, and
@@ -130,12 +132,15 @@ class _DeviceArrays(NamedTuple):
     rw: jax.Array
     rpart: jax.Array
     vpart: jax.Array
+    ldeg: jax.Array  # [n] int32 local arcs out of each vertex (duplicates too)
+    rdeg: jax.Array  # [n] int32 remote arcs out of each vertex
 
 
 def _device_arrays(pg: PartitionedGraph) -> _DeviceArrays:
     cached = pg.__dict__.get("_traversal_device_arrays")
     if cached is None:
         layout = partitioned_edge_layout(pg)
+        n = pg.graph.n_vertices
         cached = _DeviceArrays(
             lsrc=jnp.asarray(layout.local.src),
             ldst=jnp.asarray(layout.local.dst),
@@ -146,6 +151,12 @@ def _device_arrays(pg: PartitionedGraph) -> _DeviceArrays:
             rw=jnp.asarray(layout.remote.weights),
             rpart=jnp.asarray(layout.remote_src_part),
             vpart=jnp.asarray(pg.part_of_vertex.astype(np.int32)),
+            ldeg=jnp.asarray(
+                np.bincount(layout.local.src, minlength=n).astype(np.int32)
+            ),
+            rdeg=jnp.asarray(
+                np.bincount(layout.remote.src, minlength=n).astype(np.int32)
+            ),
         )
         pg.__dict__["_traversal_device_arrays"] = cached
     return cached
@@ -238,12 +249,12 @@ class _WindowConsts(NamedTuple):
     lsrc: jax.Array
     ldst: jax.Array
     lw: jax.Array  # program edge plane
-    lpart: jax.Array
     rsrc: jax.Array
     rdst: jax.Array
     rw: jax.Array
-    rpart: jax.Array
     vpart: jax.Array
+    ldeg: jax.Array
+    rdeg: jax.Array
     sg: jax.Array | None  # subgraph ids when collecting subgraphs
     lmap: tuple  # local-plane kernel block map (() on the xla backend)
     rmap: tuple  # remote-plane kernel block map
@@ -447,9 +458,8 @@ class TraversalEngine:
                         )
                     sg = pg.__dict__["_sg_device"]
                 self._consts = _WindowConsts(
-                    dev.lsrc, dev.ldst, lw, dev.lpart,
-                    dev.rsrc, dev.rdst, rw, dev.rpart,
-                    dev.vpart, sg, lmap, rmap,
+                    dev.lsrc, dev.ldst, lw, dev.rsrc, dev.rdst, rw,
+                    dev.vpart, dev.ldeg, dev.rdeg, sg, lmap, rmap,
                 )
         # one jitted program serves both modes: run() launches a single
         # window of depth m_max, run_window() launches depth k (static arg,
@@ -530,7 +540,7 @@ class TraversalEngine:
         consts: "_WindowConsts",
     ):
         s_batch = dist.shape[0]
-        (lsrc, ldst, lw, lpart, rsrc, rdst, rw, rpart, vpart, sg, lmap,
+        (lsrc, ldst, lw, rsrc, rdst, rw, vpart, ldeg, rdeg, sg, lmap,
          rmap) = consts
         n, p = self.n, self.n_parts
         prog = self.program
@@ -570,15 +580,17 @@ class TraversalEngine:
                 return self._relax_r(rmap, cand, base)
             r = seg_red_r(cand)
             return r if base is None else prog.combine(base, r)
-        seg_sum_lp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, lpart, num_segments=p)
-        )
-        seg_sum_rp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, rpart, num_segments=p)
-        )
-        seg_sum_vp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, vpart, num_segments=p)
-        )
+
+        def part_sum(x):
+            # [S, n] int32 -> [S, P]: one dense reduction over the vertex
+            # axis, no scatter.  With x a per-vertex degree under a frontier
+            # it is the per-arc count, since every arc counts toward its
+            # source's partition.
+            in_part = vpart[None, None, :] == jnp.arange(p)[None, :, None]
+            return jnp.sum(
+                jnp.where(in_part, x[:, None, :], 0), axis=-1, dtype=jnp.int32
+            )
+
         n_sg = self.n_subgraphs
         if self.collect_subgraphs:
             seg_any_sg = jax.vmap(
@@ -608,8 +620,8 @@ class TraversalEngine:
             with scope("local", "relax"):
                 acc = relax_l(cand)
             with scope("local", "counters"):
-                we_s = seg_sum_lp(active_le.astype(jnp.int32))
-                wv_s = seg_sum_vp(fr.astype(jnp.int32))
+                we_s = part_sum(jnp.where(fr, ldeg, 0))
+                wv_s = part_sum(fr.astype(jnp.int32))
                 it_s = fr.any(axis=1).astype(jnp.int32)  # one pass per superstep
 
             with scope("remote", "gather"):
@@ -620,7 +632,7 @@ class TraversalEngine:
             with scope("remote", "relax"):
                 acc = relax_r(cand_r, acc)
             with scope("remote", "counters"):
-                ms_s = seg_sum_rp(active_re.astype(jnp.int32))
+                ms_s = part_sum(jnp.where(fr, rdeg, 0))
 
             with scope("remote", "frontier"):
                 new_d = prog.apply(d, acc, n)
@@ -663,8 +675,8 @@ class TraversalEngine:
                     improved = prog.is_active(new_d, d_i)
                     touched = touched | improved
                 with scope("local", "counters"):
-                    we_s = we_s + seg_sum_lp(active_e.astype(jnp.int32))
-                    wv_s = wv_s + seg_sum_vp(f_i.astype(jnp.int32))
+                    we_s = we_s + part_sum(jnp.where(f_i, ldeg, 0))
+                    wv_s = wv_s + part_sum(f_i.astype(jnp.int32))
                     it_s = it_s + f_i.any(axis=1).astype(jnp.int32)
                 return new_d, improved, we_s, wv_s, it_s, touched
 
@@ -687,7 +699,7 @@ class TraversalEngine:
                 next_fr = prog.is_active(new_d, d2)
                 s_next = s + 1
             with scope("remote", "counters"):
-                ms_s = seg_sum_rp(active_re.astype(jnp.int32))
+                ms_s = part_sum(jnp.where(touched, rdeg, 0))
                 we = jax.lax.dynamic_update_index_in_dim(we, we_s, s, axis=1)
                 wv = jax.lax.dynamic_update_index_in_dim(wv, wv_s, s, axis=1)
                 ms = jax.lax.dynamic_update_index_in_dim(ms, ms_s, s, axis=1)
@@ -720,14 +732,7 @@ class TraversalEngine:
         # next-superstep partition activity + done flags, computed on device
         # so the executor's placement decision needs no extra [n]-sized pull
         with scope(FINISH):
-            pact = (
-                jax.vmap(
-                    lambda f: jax.ops.segment_max(
-                        f.astype(jnp.int32), vpart, num_segments=p
-                    )
-                )(fr)
-                > 0
-            )
+            pact = part_sum(fr.astype(jnp.int32)) > 0
             done = ~fr.any(axis=1)
             wire = jnp.zeros((s_batch, m_max), jnp.int32)  # dense: no wire
         return TraversalResult(d, fr, nst, we, wv, ms, it, sg, wire), pact, done

@@ -12,8 +12,11 @@ import pytest
 
 from repro.graph import bfs_grow_partition, erdos_renyi_graph, hash_partition
 from repro.graph.bsp import concat_traces, run_bc_forward, run_sssp
+from repro.graph.config import EngineConfig
 from repro.graph.generators import weighted
-from repro.graph.structs import dst_sorted_layout
+from repro.graph.partition import partitioned_edge_layout
+from repro.graph.program import BUILTIN_PROGRAMS, PageRankProgram, SsspProgram
+from repro.graph.structs import Graph, PartitionedGraph, dst_sorted_layout
 from repro.graph.traversal import (
     TraversalNotConverged,
     get_engine,
@@ -262,8 +265,6 @@ def test_dense_engine_backend_parity(name):
     """pallas-interpret == xla on the dense engine: counters bit-identical
     for every program (they stay on XLA), state bit-identical for min
     programs and allclose for the float sum path."""
-    from repro.graph.program import BUILTIN_PROGRAMS
-
     g = weighted(erdos_renyi_graph(250, 4.0, seed=3), seed=1)
     pg = bfs_grow_partition(g, 4)
     srcs = [0, 100]
@@ -289,3 +290,145 @@ def test_engine_rejects_unknown_backend():
     pg = hash_partition(erdos_renyi_graph(50, 3.0, seed=0), 2)
     with pytest.raises(ValueError, match="backend"):
         get_engine(pg, backend="cuda")
+
+
+def _graph_with_duplicates_and_isolated(seed: int = 4) -> Graph:
+    """Weighted directed arcs over vertices 0..139, 120 of them repeated
+    (with their own weights); vertices 140..159 have no arc at all."""
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, 140, 700)
+    dst = (src + rng.integers(1, 140, 700)) % 140
+    again = rng.choice(700, 120, replace=False)
+    src = np.concatenate([src, src[again]]).astype(np.int32)
+    dst = np.concatenate([dst, dst[again]]).astype(np.int32)
+    w = rng.uniform(1.0, 4.0, src.shape[0]).astype(np.float32)
+    return Graph(160, src, dst, w)
+
+
+def _per_arc_counts(pg: PartitionedGraph, frontiers) -> tuple:
+    """(edges_examined, verts_processed) of one frontier sequence, counted
+    per arc and per vertex with ``np.bincount``."""
+    lay = partitioned_edge_layout(pg)
+    p = pg.n_parts
+    we = sum(
+        np.bincount(lay.local_part[f[lay.local.src]], minlength=p)
+        for f in frontiers
+    )
+    wv = sum(
+        np.bincount(pg.part_of_vertex[f], minlength=p) for f in frontiers
+    )
+    return we, wv
+
+
+def _sssp_counter_rows(pg: PartitionedGraph, source: int) -> list:
+    """Host replay of the monotone window's frontier history for one SSSP
+    source: per superstep, the closure loop's frontiers give the local
+    counters and the superstep's touched set gives ``msgs_sent``."""
+    lay = partitioned_edge_layout(pg)
+    loc, rem = lay.local, lay.remote
+    p = pg.n_parts
+    d = np.full(pg.graph.n_vertices, np.inf, np.float32)
+    d[source] = 0.0
+    fr = d == 0.0
+    rows = []
+    while fr.any():
+        frontiers, touched, f = [], fr.copy(), fr
+        while f.any():
+            frontiers.append(f)
+            new = d.copy()
+            act = f[loc.src]
+            np.minimum.at(new, loc.dst[act], d[loc.src[act]] + loc.weights[act])
+            f = new < d
+            touched |= f
+            d = new
+        we, wv = _per_arc_counts(pg, frontiers)
+        ms = np.bincount(lay.remote_src_part[touched[rem.src]], minlength=p)
+        new = d.copy()
+        act = touched[rem.src]
+        np.minimum.at(new, rem.dst[act], d[rem.src[act]] + rem.weights[act])
+        fr = new < d
+        d = new
+        rows.append((we, wv, ms))
+    return rows
+
+
+@pytest.mark.parametrize("graph", ["erdos_renyi", "duplicates_isolated"])
+@pytest.mark.parametrize("partitioner", [hash_partition, bfs_grow_partition])
+@pytest.mark.parametrize("name", ["sssp", "pagerank"])
+def test_dense_counters_equal_per_arc_bincounts(name, partitioner, graph):
+    """The dense window takes its counters from per-vertex degrees; they must
+    equal per-arc ``np.bincount`` counts of the frontier history, duplicate
+    arcs and arc-less vertices included."""
+    if graph == "erdos_renyi":
+        g = weighted(erdos_renyi_graph(200, 4.0, seed=8), seed=5)
+    else:
+        g = _graph_with_duplicates_and_isolated()
+    pg = partitioner(g, 4)
+    lay = partitioned_edge_layout(pg)
+    if name == "pagerank":
+        # stationary: one pass per superstep, so the history is the carried
+        # frontier at each one-superstep window
+        eng = get_engine(
+            pg, program=PageRankProgram(num_iters=6),
+            config=EngineConfig(m_max=16),
+        )
+        state = eng.init_state([0, 0])
+        n_windows = 0
+        while True:
+            fr = np.asarray(state.frontier)
+            w = eng.run_window(state, 1)
+            for i in range(fr.shape[0]):
+                we, wv = _per_arc_counts(pg, [fr[i]])
+                ms = np.bincount(
+                    lay.remote_src_part[fr[i][lay.remote.src]],
+                    minlength=pg.n_parts,
+                )
+                np.testing.assert_array_equal(w.edges_examined[i, 0], we)
+                np.testing.assert_array_equal(w.verts_processed[i, 0], wv)
+                np.testing.assert_array_equal(w.msgs_sent[i, 0], ms)
+            n_windows += 1
+            state = w.state
+            if w.done.all():
+                break
+        assert n_windows == 6
+        return
+    sources = [0, 77, 150]
+    res = get_engine(
+        pg, program=SsspProgram(), config=EngineConfig(m_max=64)
+    ).run(sources)
+    for i, s in enumerate(sources):
+        expect = _sssp_counter_rows(pg, s)
+        m = len(expect)
+        assert int(res.n_supersteps[i]) == m
+        for f, j in (("edges_examined", 0), ("verts_processed", 1),
+                     ("msgs_sent", 2)):
+            got = np.asarray(getattr(res, f))[i]
+            np.testing.assert_array_equal(
+                got[:m], np.stack([r[j] for r in expect]), err_msg=f
+            )
+            assert not got[m:].any(), f
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+@pytest.mark.parametrize("name", sorted(BUILTIN_PROGRAMS))
+def test_dense_window_has_no_partition_scatter(name, backend):
+    """No scatter into ``n_parts`` bins is left in the dense window: every
+    per-partition counter is a dense reduction over the vertex axis.  The
+    ``[n]``-sized value reductions of the xla backend stay scatters."""
+    from repro.analysis.jaxpr_audit import iter_eqns
+
+    g = weighted(erdos_renyi_graph(120, 4.0, seed=2), seed=3)
+    pg = bfs_grow_partition(g, 5)
+    eng = get_engine(
+        pg, program=BUILTIN_PROGRAMS[name](),
+        config=EngineConfig(m_max=8, backend=backend),
+    )
+    shapes = [
+        tuple(v.aval.shape)
+        for eqn, _ in iter_eqns(eng.window_jaxpr(k=3, s_batch=2).jaxpr)
+        if "scatter" in eqn.primitive.name
+        for v in eqn.outvars
+    ]
+    assert not [s for s in shapes if s and s[-1] == pg.n_parts], shapes
+    if backend == "xla":
+        assert (2, g.n_vertices) in shapes  # the value reductions remain
