@@ -50,8 +50,7 @@ def main(argv=None) -> int:
     from bench import harness as h
 
     cell = h.load_cell(args.workload)
-    h.accelerators(cell.chips)
-    setup = h.set_up(cell)
+    setup = h.set_up(cell, devices=h.accelerators(cell.chips))
     pool = h.key_pool(cell, setup.degree)
     out = open(args.out, "a") if args.out else sys.stdout
     try:

@@ -7,6 +7,8 @@ its files by name:
   partitioning and the engine configuration;
 * ``bench/generators/<generator>.py``: the graph generator the
   configuration names (``bench/graphs.py``);
+* ``bench/partitioners/<method>.py``: the program's partitioner the
+  configuration's ``partition.method`` names, as ``partition(graph, spec)``;
 * ``bench/traffic/<traffic>.json``: the program kind, the keys per batch
   and where they are drawn from;
 * ``bench/programs/<program>.py``: the program kind the traffic names: its
@@ -22,7 +24,8 @@ its files by name:
   to ``<name>.py``.
 
 A run generates the graph from the configuration's seed, partitions it,
-opens a ``GraphSession`` and compiles the cell's one window program, then
+opens a ``GraphSession`` (over a mesh of the cell's chips where it has more
+than one) and compiles the cell's one window program, then
 runs whole passes over the cell's fixed pool of key batches in a closed
 loop: a pass starts only while less than ``seconds`` have passed, and the
 window ends when the last batch's result is on the host.  ``--seed``
@@ -215,12 +218,20 @@ def empty_frontier_warm_up(setup: Setup, s_batch: int) -> None:
     session.run_window(state, session.engine(program).m_max, program=program)
 
 
-def set_up(cell: Cell, root: str = ROOT) -> Setup:
+def set_up(cell: Cell, root: str = ROOT, devices=None) -> Setup:
     """Graph, partition, layout, session, and the window program compiled
-    and warmed up on the cell's batch shape."""
+    and warmed up on the cell's batch shape.  A cell on more than one chip
+    opens its session over a mesh of ``devices`` (JAX's first ``chips``
+    where not given), the parts in contiguous blocks per device, and builds
+    the mesh layout that its engine then finds in the layout cache."""
     from bench.graphs import make_arcs
+    from repro.dist.sharding import partition_mesh
     from repro.graph.config import EngineConfig
-    from repro.graph.partition import bfs_grow_partition, partitioned_edge_layout
+    from repro.graph.partition import (
+        contiguous_device_map,
+        mesh_edge_layout,
+        partitioned_edge_layout,
+    )
     from repro.graph.session import open_session
     from repro.graph.structs import Graph
 
@@ -231,17 +242,24 @@ def set_up(cell: Cell, root: str = ROOT) -> Setup:
     secs["generate_s"] = time.perf_counter() - t
     t = time.perf_counter()
     part = cfg["partition"]
-    if part["method"] != "bfs_grow":
-        raise ValueError(f"unknown partition method {part['method']!r}")
-    pg = bfs_grow_partition(
-        Graph(n, src, dst, weights), int(part["n_parts"]), seed=int(part["seed"])
-    )
+    partitioner = load_module("partitioners", part["method"], root)
+    pg = partitioner.partition(Graph(n, src, dst, weights), part)
     secs["partition_s"] = time.perf_counter() - t
+    engine = EngineConfig(**cfg["engine"])
+    if cell.chips > 1:
+        engine = engine.replace(mesh=partition_mesh(cell.chips, devices))
     t = time.perf_counter()
-    layout = partitioned_edge_layout(pg)
+    if engine.mesh is not None:
+        layout = mesh_edge_layout(pg, contiguous_device_map(pg.n_parts, cell.chips),
+                                  cell.chips, mirror_degree=engine.mirror_degree)
+        local_arcs = int(layout.lvalid.sum())
+        remote_arcs = int(layout.rvalid.sum() + layout.mvalid.sum())
+    else:
+        layout = partitioned_edge_layout(pg)
+        local_arcs, remote_arcs = layout.local.n_edges, layout.remote.n_edges
     secs["layout_s"] = time.perf_counter() - t
     t = time.perf_counter()
-    session = open_session(pg, EngineConfig(**cfg["engine"]))
+    session = open_session(pg, engine)
     program = cell.kind.make(cell.traffic)
     session.engine(program)
     secs["session_s"] = time.perf_counter() - t
@@ -249,7 +267,7 @@ def set_up(cell: Cell, root: str = ROOT) -> Setup:
         n=n, src=src, dst=dst, weights=weights,
         degree=np.bincount(src, minlength=n), session=session, program=program,
         run_batch=getattr(cell.kind, "run_batch", session_run),
-        local_arcs=layout.local.n_edges, remote_arcs=layout.remote.n_edges,
+        local_arcs=local_arcs, remote_arcs=remote_arcs,
         seconds=secs,
     )
     t = time.perf_counter()
@@ -402,10 +420,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
     from bench.roofline import passes
 
     cell = cell or load_cell(workload, root)
-    devices = accelerators(cell.chips) if check_device else jax.devices()[:1]
+    devices = accelerators(cell.chips) if check_device else jax.devices()[:cell.chips]
     log = CompileLog.listen()
     t_setup = time.perf_counter()
-    setup = set_up(cell, root)
+    setup = set_up(cell, root, devices)
     setup.seconds["start_s"] = t_setup - t_start
     pool = key_pool(cell, setup.degree)
     setup_s = time.perf_counter() - t_start

@@ -1,4 +1,5 @@
-"""Host seconds of the set-up's partitioned_edge_layout call."""
+"""Host seconds of the set-up's layout build: partitioned_edge_layout on
+one chip, mesh_edge_layout over a mesh."""
 
 
 def read(ctx):

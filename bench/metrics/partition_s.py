@@ -1,4 +1,4 @@
-"""Host seconds of the set-up's bfs_grow_partition call."""
+"""Host seconds of the set-up's call of the configuration's partitioner."""
 
 
 def read(ctx):

@@ -4,14 +4,20 @@ cut to a size the CPU runs in seconds, with interpret-mode kernels.
 ``SSSP`` is a cell of the tests alone: the ``sssp`` program kind and
 ``bench/traffic/sssp8.json`` on the Graph500 configuration with hashed
 weights, so the weighted path keeps its controls and faults checked until
-a committed cell drives it."""
+a committed cell drives it.
+
+A cell on more than one chip runs in a child with as many forced host
+devices (``mesh_subprocess``, marked ``mesh``)."""
 
 from __future__ import annotations
 
 import copy
 import time
 
+import pytest
+
 from bench import harness, load_module
+from repro.testing.forced_devices import run_forced_devices
 
 SMALL = {"kronecker": {"scale": 8}}
 SSSP = "graph500-s8w.sssp8"
@@ -48,3 +54,17 @@ def run_small(workload: str, *, trace: bool = False, seed: int = 2**31 + 11,
     out = harness.run(workload, seed, 0.05, trace, time.perf_counter(),
                       root=root, cell=cell, check_device=False)
     return out["result"]
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers",
+        "mesh: multi-device test; runs a child process with "
+        "XLA_FLAGS=--xla_force_host_platform_device_count=N",
+    )
+
+
+@pytest.fixture(scope="session")
+def mesh_subprocess():
+    """``run_forced_devices``: a script run under N forced host devices."""
+    return run_forced_devices

@@ -5,7 +5,8 @@ The first test adds a configuration, a traffic mix, limits and a per-layer
 metric over the existing generator and program kind.  The second adds a
 graph generator and a program kind of their own (WCC, driven one
 superstep per window, as an elastic executor drives a traversal), with
-their own reference and warm-up."""
+their own reference and warm-up.  The third adds a partition method, and an
+unknown one is refused."""
 
 from __future__ import annotations
 
@@ -13,6 +14,9 @@ import hashlib
 import json
 import os
 import shutil
+
+import numpy as np
+import pytest
 
 from bench import harness
 from bench.tests.conftest import run_small
@@ -33,6 +37,18 @@ NEW_READER = '''"""Mean supersteps of the window's batches."""
 
 def read(ctx):
     return sum(int(b.n_supersteps.max()) for b in ctx.batches) / len(ctx.batches)
+'''
+
+ROUND_ROBIN_PARTITIONER = '''"""Vertex ``v`` in part ``v mod n_parts``."""
+
+import numpy as np
+
+
+def partition(graph, spec):
+    from repro.graph.structs import PartitionedGraph
+
+    k = int(spec["n_parts"])
+    return PartitionedGraph(graph, k, (np.arange(graph.n_vertices) % k).astype(np.int32))
 '''
 
 RINGS_GENERATOR = '''"""Disjoint rings of equal size: ``n`` vertices, ``rings`` components."""
@@ -209,3 +225,33 @@ def test_new_generator_and_program_kind_run_unchanged_harness(tmp_path):
                for b, d in zip(done, loaded.kind.control(loaded, setup, done))]
     values, failed = harness.compare(loaded, setup, control)
     assert values["label_mismatch"] > 0 and failed == sum(b.keys.size for b in done)
+
+
+def test_new_partition_method_runs_unchanged_harness(tmp_path):
+    root, before = _copy_benchmark(tmp_path)
+    _add(root, "bench/partitioners/round_robin.py", ROUND_ROBIN_PARTITIONER)
+    config = dict(NEW_CONFIG, name="kron-s8-rr",
+                  partition={"method": "round_robin", "n_parts": 4})
+    cell = "kron-s8-rr.bfs2"
+    _write_spec(root, _add_cell(root, config, "bfs2", NEW_TRAFFIC,
+                                {"hop_mismatch": 0, "edge_count_mismatch": 0}, cell))
+
+    after = _digests(os.path.join(root, "bench"))
+    assert all(after[k] == v for k, v in before.items())  # nothing edited
+
+    loaded = harness.load_cell(cell, root)
+    setup = harness.set_up(loaded, root)
+    assert np.array_equal(setup.session.pg.part_of_vertex, np.arange(setup.n) % 4)
+    result = run_small(cell, root=root, cell=loaded)
+    assert result["correct"] and result["failed"] == 0
+
+
+def test_unknown_partition_method_is_refused(tmp_path):
+    root, _ = _copy_benchmark(tmp_path)
+    config = dict(NEW_CONFIG, name="kron-s8-metis",
+                  partition={"method": "metis", "n_parts": 4, "seed": 2})
+    cell = "kron-s8-metis.bfs2"
+    _write_spec(root, _add_cell(root, config, "bfs2", NEW_TRAFFIC,
+                                {"hop_mismatch": 0, "edge_count_mismatch": 0}, cell))
+    with pytest.raises(SystemExit, match="no partitioners module 'metis'"):
+        harness.set_up(harness.load_cell(cell, root), root)

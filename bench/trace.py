@@ -9,9 +9,16 @@ written with ``jax.profiler.TraceAnnotation``).  ``reduce`` then works on
 plain tuples, so a small recorded trace checks it without a chip.
 
 All intervals are clipped to the ``bench.window`` span.  Busy time is the
-union of the operation intervals, averaged over the devices; kernel time is
-the union of the relax kernel's intervals; an idle gap is named after the
-innermost ``bench.*`` span (other than the window) that covers its middle.
+union of the operation intervals, averaged over the devices, and kept per
+device besides; kernel time is the union of the relax kernel's intervals,
+collective time the union of the collectives' (ops whose HLO opcode is
+``all-to-all``, ``all-reduce``, ``all-gather``, ``reduce-scatter`` or
+``collective-permute``, or one of their ``-start``/``-done`` halves; on the
+TPU their names are JAX's, ``pmax.41`` or ``all_to_all.4``), both averaged
+over the devices; an op's seconds in ``device_ops`` are averaged over the
+devices too.  An idle
+gap is named after the innermost ``bench.*`` span (other than the window)
+that covers its middle.
 """
 
 from __future__ import annotations
@@ -32,6 +39,13 @@ CONTAINER = re.compile(r"(?<![\w-])(while|conditional|call)\(")
 #: the TPU.  The event carries the op's HLO text and not the kernel's name,
 #: so this holds while the relax kernel is the window's only Pallas kernel
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
+#: the opcode of a collective op in its HLO text, ``%pmax.41 = s32[8]{0}
+#: all-reduce(...)``: a fusion that takes a collective's result names it
+#: only as an operand
+COLLECTIVE = re.compile(
+    r" (all-to-all|all-reduce|all-gather|reduce-scatter|collective-permute)"
+    r"(-start|-done)?\("
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,6 +55,7 @@ class Op:
     start_ns: float
     dur_ns: float
     is_kernel: bool
+    is_collective: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +71,8 @@ class Reduced:
     busy_s: float  # averaged over devices
     kernel_s: float  # averaged over devices
     n_devices: int
+    device_busy_s: list  # busy seconds of each device, by device name
+    collective_s: float  # averaged over devices
     device_ops: list  # [[name, seconds]], most time first, at most 10
     idle_gaps: list  # [[host span name, seconds]], longest first, at most 10
 
@@ -83,7 +100,8 @@ def read_xplane(path: str) -> tuple[list[Op], list[Span]]:
                 if on_device:
                     kernel = KERNEL_MARK in ev.name
                     name = short_name(ev.name) + (" (relax kernel)" if kernel else "")
-                    ops.append(Op(plane.name, name, ev.start_ns, ev.duration_ns, kernel))
+                    ops.append(Op(plane.name, name, ev.start_ns, ev.duration_ns,
+                                  kernel, bool(COLLECTIVE.search(ev.name))))
                 elif ev.name.startswith("bench."):
                     spans.append(Span(ev.name, ev.start_ns, ev.duration_ns))
     return ops, spans
@@ -125,13 +143,16 @@ def reduce(ops: list[Op], spans: list[Span]) -> Reduced:
         if end > start:
             by_device[op.device].append((op, start, end))
     n_dev = max(1, len(by_device))
-    busy = kernel = 0.0
+    kernel = collective = 0.0
+    device_busy = []
     per_name = defaultdict(float)
     gaps = []
-    for device_ops in by_device.values():
+    for _, device_ops in sorted(by_device.items()):
         busy_iv = _union((s, e) for _, s, e in device_ops)
-        busy += _total(busy_iv)
+        device_busy.append(_total(busy_iv))
         kernel += _total(_union((s, e) for op, s, e in device_ops if op.is_kernel))
+        collective += _total(_union((s, e) for op, s, e in device_ops
+                                    if op.is_collective))
         for op, s, e in device_ops:
             per_name[op.name] += e - s
         edges = [w0] + [x for iv in busy_iv for x in iv] + [w1]
@@ -148,9 +169,11 @@ def reduce(ops: list[Op], spans: list[Span]) -> Reduced:
     top_ops = sorted(per_name.items(), key=lambda kv: kv[1], reverse=True)[:10]
     return Reduced(
         window_s=(w1 - w0) * 1e-9,
-        busy_s=busy / n_dev * 1e-9,
+        busy_s=sum(device_busy) / n_dev * 1e-9,
         kernel_s=kernel / n_dev * 1e-9,
         n_devices=len(by_device),
-        device_ops=[[name, ns * 1e-9] for name, ns in top_ops],
+        device_busy_s=[ns * 1e-9 for ns in device_busy],
+        collective_s=collective / n_dev * 1e-9,
+        device_ops=[[name, ns / n_dev * 1e-9] for name, ns in top_ops],
         idle_gaps=[[label(g), (g[1] - g[0]) * 1e-9] for g in gaps[:10]],
     )
