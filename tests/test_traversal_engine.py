@@ -432,3 +432,60 @@ def test_dense_window_has_no_partition_scatter(name, backend):
     assert not [s for s in shapes if s and s[-1] == pg.n_parts], shapes
     if backend == "xla":
         assert (2, g.n_vertices) in shapes  # the value reductions remain
+
+
+def _unit_interval_graph(seed: int = 7) -> Graph:
+    """An ER graph with weights uniform in [0, 1), Graph500 kernel 3's
+    distribution, equal for ``(u, v)`` and ``(v, u)``; a fifth of the
+    edges weigh exactly 0."""
+    g = erdos_renyi_graph(300, 5.0, seed=seed)
+    pair = (np.minimum(g.src, g.dst).astype(np.int64) * g.n_vertices
+            + np.maximum(g.src, g.dst))
+    _, edge = np.unique(pair, return_inverse=True)
+    rng = np.random.default_rng(seed)
+    w = rng.random(edge.max() + 1).astype(np.float32)
+    w[rng.random(w.size) < 0.2] = 0.0
+    return Graph(g.n_vertices, g.src, g.dst, w[edge])
+
+
+@pytest.mark.parametrize("partitioner", [hash_partition, bfs_grow_partition])
+def test_sssp_on_unit_interval_weights_with_zeros(partitioner):
+    """Weighted SSSP over [0, 1) weights, zeros included: the local closure
+    improves reached vertices again, and the answer agrees with float64
+    Bellman-Ford and scipy's Dijkstra to float32 rounding, is an exact
+    float32 fixpoint, and is bit-identical between the backends."""
+    from scipy import sparse
+    from scipy.sparse import csgraph
+
+    from bench.reference import sssp_fixpoint_violations
+
+    g = _unit_interval_graph()
+    assert (g.edge_weights == 0).any()
+    pg = partitioner(g, 4)
+    sources = [0, 17, 123, 299]
+    runs = {
+        backend: get_engine(
+            pg, program=SsspProgram(), m_max=256, backend=backend
+        ).run(sources)
+        for backend in ("xla", "pallas-interpret")
+    }
+    rx, rk = runs["xla"], runs["pallas-interpret"]
+    for f in ("dist", "inner_iters", "verts_processed", "n_supersteps"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(rx, f)), np.asarray(getattr(rk, f)), err_msg=f
+        )
+    adj = sparse.csr_matrix(
+        (g.edge_weights.astype(np.float64), (g.src, g.dst)),
+        shape=(g.n_vertices, g.n_vertices),
+    )  # explicit zeros stay edges in scipy's csgraph
+    want = csgraph.dijkstra(adj, directed=True, indices=sources)
+    dist = np.asarray(rk.dist)
+    for i, s in enumerate(sources):
+        np.testing.assert_allclose(dist[i], reference_sssp(pg, s), rtol=1e-6)
+        np.testing.assert_allclose(dist[i], want[i], rtol=1e-6)
+        # some vertex entered the frontier again after it was reached
+        reached = int(np.isfinite(dist[i]).sum())
+        assert int(np.asarray(rk.verts_processed)[i].sum()) > reached
+    assert not sssp_fixpoint_violations(g.src, g.dst, g.edge_weights, sources,
+                                        dist).any()
+    assert ((dist == 0).sum(axis=1) > 1).any()  # a path of zero weights
