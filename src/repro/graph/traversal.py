@@ -591,6 +591,19 @@ class TraversalEngine:
                 jnp.where(in_part, x[:, None, :], 0), axis=-1, dtype=jnp.int32
             )
 
+        def sendable(state, frontier):
+            # [S, n]: the frontier folded into the state before a plane's
+            # [S, E] gather.  ``relax`` maps ``identity`` to ``identity`` (the
+            # VertexProgram contract, rule JX05), so
+            # ``relax(sendable[:, src], w)`` is bit for bit
+            # ``where(frontier[:, src], relax(state[:, src], w), identity)``,
+            # from one gather per arc in place of two.  The barrier keeps the
+            # select out of the gather's fusion, so the gather reads one
+            # materialised buffer rather than both operands per index.
+            return jax.lax.optimization_barrier(
+                jnp.where(frontier, state, ident)
+            )
+
         n_sg = self.n_subgraphs
         if self.collect_subgraphs:
             seg_any_sg = jax.vmap(
@@ -613,10 +626,8 @@ class TraversalEngine:
                 nst = nst + fr.any(axis=1).astype(jnp.int32)
 
             with scope("local", "gather"):
-                active_le = fr[:, lsrc]
-                cand = jnp.where(
-                    active_le, prog.relax(d[:, lsrc], lw), ident
-                )
+                snd = sendable(d, fr)  # both planes read it
+                cand = prog.relax(snd[:, lsrc], lw)
             with scope("local", "relax"):
                 acc = relax_l(cand)
             with scope("local", "counters"):
@@ -625,10 +636,7 @@ class TraversalEngine:
                 it_s = fr.any(axis=1).astype(jnp.int32)  # one pass per superstep
 
             with scope("remote", "gather"):
-                active_re = fr[:, rsrc]
-                cand_r = jnp.where(
-                    active_re, prog.relax(d[:, rsrc], rw), ident
-                )
+                cand_r = prog.relax(snd[:, rsrc], rw)
             with scope("remote", "relax"):
                 acc = relax_r(cand_r, acc)
             with scope("remote", "counters"):
@@ -665,10 +673,7 @@ class TraversalEngine:
             def ibody(c):
                 d_i, f_i, we_s, wv_s, it_s, touched = c
                 with scope("local", "gather"):
-                    active_e = f_i[:, lsrc]
-                    cand = jnp.where(
-                        active_e, prog.relax(d_i[:, lsrc], lw), ident
-                    )
+                    cand = prog.relax(sendable(d_i, f_i)[:, lsrc], lw)
                 with scope("local", "relax"):
                     new_d = relax_l(cand, d_i)
                 with scope("local", "frontier"):
@@ -689,10 +694,7 @@ class TraversalEngine:
 
             # -- remote exchange at the superstep boundary --------------------
             with scope("remote", "gather"):
-                active_re = touched[:, rsrc]
-                cand = jnp.where(
-                    active_re, prog.relax(d2[:, rsrc], rw), ident
-                )
+                cand = prog.relax(sendable(d2, touched)[:, rsrc], rw)
             with scope("remote", "relax"):
                 new_d = relax_r(cand, d2)
             with scope("remote", "frontier"):

@@ -6,6 +6,7 @@ sizes -- including the ``presorted=True`` legacy path and n/e odd with
 respect to the block sizes.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -21,8 +22,11 @@ from repro.graph.traversal import (
     TraversalNotConverged,
     get_engine,
     make_superstep_fn,
+    plane_arrays,
     reference_bfs,
+    reference_pagerank,
     reference_sssp,
+    reference_wcc,
 )
 from repro.kernels.bfs_relax import bfs_relax, bfs_relax_csr, reference_bfs_relax
 
@@ -489,3 +493,163 @@ def test_sssp_on_unit_interval_weights_with_zeros(partitioner):
     assert not sssp_fixpoint_violations(g.src, g.dst, g.edge_weights, sources,
                                         dist).any()
     assert ((dist == 0).sum(axis=1) > 1).any()  # a path of zero weights
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+@pytest.mark.parametrize("name", sorted(BUILTIN_PROGRAMS))
+def test_dense_window_gathers_once_per_plane(name, backend):
+    """Each pass gathers each plane's arcs once: the frontier is folded into
+    the state over the vertex axis first, so the window holds one ``[S, E]``
+    gather per plane, each reading a materialised buffer (the output of an
+    optimisation barrier), and no per-arc gather of the bool frontier."""
+    from repro.analysis.jaxpr_audit import iter_eqns
+
+    g = weighted(erdos_renyi_graph(120, 4.0, seed=2), seed=3)
+    pg = bfs_grow_partition(g, 5)
+    lay = partitioned_edge_layout(pg)
+    e_l, e_r = lay.local.src.size, lay.remote.src.size
+    assert e_l != e_r  # the planes' gathers are told apart by their width
+    eng = get_engine(
+        pg, program=BUILTIN_PROGRAMS[name](),
+        config=EngineConfig(m_max=8, backend=backend),
+    )
+    eqns = [e for e, _ in iter_eqns(eng.window_jaxpr(k=3, s_batch=2).jaxpr)]
+    barriered = {
+        v for e in eqns if e.primitive.name == "optimization_barrier"
+        for v in e.outvars
+    }
+    gathers = [e for e in eqns if e.primitive.name == "gather"]
+    widths = sorted(tuple(e.outvars[0].aval.shape) for e in gathers)
+    assert widths == sorted([(2, e_l), (2, e_r)]), widths
+    assert not [e for e in gathers if e.outvars[0].aval.dtype == np.bool_]
+    assert all(e.invars[0] in barriered for e in gathers)
+
+
+def _unfolded_window(prog, pg, state, k: int) -> dict:
+    """Host replay of one ``k``-superstep window of the dense engine over a
+    batch, in the per-arc form: an arc's candidate is
+    ``relax(state[src], w)`` where its source is in the frontier, and
+    ``identity`` elsewhere.  The counters are per-arc ``np.bincount`` counts.
+    Returns the window's carried state and counters under their
+    ``WindowResult`` names."""
+    lay = partitioned_edge_layout(pg)
+    loc, rem = lay.local, lay.remote
+    planes = [np.asarray(a) for a in plane_arrays(pg, prog)]
+    p, n, ident = pg.n_parts, pg.graph.n_vertices, prog.identity
+    d, fr, nst = (np.array(a) for a in state)
+    s_b = d.shape[0]
+    out = {f: np.zeros((s_b, k, p), np.int32)
+           for f in ("edges_examined", "verts_processed", "msgs_sent")}
+    out["inner_iters"] = np.zeros((s_b, k), np.int32)
+
+    def relax(arcs, w, d, f, base):
+        cand = np.where(f[arcs.src], prog.relax(d[arcs.src], w), ident)
+        base = base.copy()
+        (np.minimum if prog.reduce == "min" else np.add).at(
+            base, arcs.dst, cand
+        )
+        return base
+
+    def count(field, i, s, part_of, mask):
+        out[field][i, s] += np.bincount(part_of[mask], minlength=p)
+
+    for s in range(k):
+        if not fr.any():
+            break
+        for i in range(s_b):
+            f = fr[i]
+            nst[i] += f.any()
+            if prog.stationary:
+                count("edges_examined", i, s, lay.local_part, f[loc.src])
+                count("verts_processed", i, s, pg.part_of_vertex, f)
+                count("msgs_sent", i, s, lay.remote_src_part, f[rem.src])
+                out["inner_iters"][i, s] = f.any()
+                zero = np.zeros_like(d[i])
+                acc = (relax(loc, planes[0], d[i], f, zero)
+                       + relax(rem, planes[1], d[i], f, zero))
+                d[i] = prog.apply(d[i], acc, n)
+                fr[i] = f & prog.keep_running(nst[i])
+                continue
+            touched = f.copy()
+            while f.any():
+                count("edges_examined", i, s, lay.local_part, f[loc.src])
+                count("verts_processed", i, s, pg.part_of_vertex, f)
+                out["inner_iters"][i, s] += 1
+                new = relax(loc, planes[0], d[i], f, d[i])
+                f = prog.is_active(new, d[i])
+                touched |= f
+                d[i] = new
+            count("msgs_sent", i, s, lay.remote_src_part, touched[rem.src])
+            new = relax(rem, planes[1], d[i], touched, d[i])
+            fr[i] = prog.is_active(new, d[i])
+            d[i] = new
+    return dict(out, dist=d, frontier=fr, n_supersteps=nst)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+@pytest.mark.parametrize("name", ["bfs", "sssp", "wcc", "pagerank"])
+def test_dense_window_fold_matches_per_arc_form(name, backend):
+    """Windows of the folded gather equal the per-arc form they replace, in
+    state and every counter, over short windows, a row deactivated by
+    ``backfill_rows`` and a row whose frontier was thinned, so that vertices
+    outside the frontier hold values they have not sent yet.  A fold that
+    forgot the frontier would send them.  The rows left alone end at the
+    reference answer; for SSSP the per-arc form is also checked against
+    ``make_superstep_fn``."""
+    g = _unit_interval_graph()
+    pg = bfs_grow_partition(g, 4)
+    prog = (PageRankProgram(num_iters=6) if name == "pagerank"
+            else BUILTIN_PROGRAMS[name]())
+    eng = get_engine(
+        pg, program=prog, config=EngineConfig(m_max=64, backend=backend)
+    )
+    sources = [0, 17, 123, 299]
+    state = eng.init_state(sources)
+    for n_window in range(64):
+        if n_window == 1:
+            state = eng.backfill_rows(state, [1], [-1])
+            state = state._replace(
+                frontier=state.frontier.at[0, ::2].set(False)
+            )
+        want = _unfolded_window(prog, pg, jax.device_get(state), 2)
+        w = eng.run_window(state, 2)
+        state = w.state
+        got = dict(w._asdict(), **jax.device_get(state)._asdict())
+        for f, expect in want.items():
+            if f == "dist" and prog.reduce == "sum":
+                # float sums in another order: the kernel's and numpy's
+                np.testing.assert_allclose(
+                    got[f], expect, rtol=1e-6, atol=0, err_msg=f
+                )
+            else:
+                np.testing.assert_array_equal(got[f], expect, err_msg=f)
+        if name == "sssp" and n_window == 1:
+            step = make_superstep_fn(pg)
+            host = jax.device_get(state)
+            want = _unfolded_window(prog, pg, host, 1)
+            for i in range(len(sources)):
+                r = step(host.dist[i], host.frontier[i])
+                np.testing.assert_array_equal(r.dist, want["dist"][i])
+                for f in ("edges_examined", "verts_processed", "msgs_sent",
+                          "inner_iters"):
+                    np.testing.assert_array_equal(
+                        getattr(r, f), want[f][i, 0], err_msg=f
+                    )
+        if w.done.all():
+            break
+    assert w.done.all()
+    dist = np.asarray(state.dist)
+    assert np.isfinite(dist[0]).sum() > 1
+    for i in (2, 3):
+        if name == "bfs":
+            np.testing.assert_array_equal(dist[i], reference_bfs(pg, sources[i]))
+        elif name == "sssp":
+            np.testing.assert_allclose(
+                dist[i], reference_sssp(pg, sources[i]), rtol=1e-6
+            )
+        elif name == "wcc":
+            np.testing.assert_array_equal(dist[i], reference_wcc(pg))
+        else:
+            np.testing.assert_allclose(
+                dist[i], reference_pagerank(pg, num_iters=6), rtol=1e-5
+            )
