@@ -90,8 +90,10 @@ def bench_segment_sum() -> dict:
 
 
 def bench_bfs_relax() -> dict:
+    from repro.analysis.jaxpr_audit import check_hot_path, check_pallas_grids
+    from repro.graph.program import SsspProgram
     from repro.graph.structs import dst_sorted_layout
-    from repro.kernels.bfs_relax import bfs_relax_csr, reference_bfs_relax
+    from repro.kernels.bfs_relax import make_relax_fn, reference_bfs_relax
 
     rng = np.random.default_rng(1)
     n, e = 1024, 4096
@@ -101,28 +103,35 @@ def bench_bfs_relax() -> dict:
     layout = dst_sorted_layout(n, src, dst, w)
     dist = jnp.full((n,), jnp.inf).at[0].set(0.0)
     frontier = jnp.zeros((n,), bool).at[0].set(True)
-    # the digest also audits the exact jitted hot path it benchmarks (consts
-    # staged on device outside the trace, as in production): a degenerate
-    # grid or a host callback here fails the bench, not just CI lint
-    import functools
 
-    from repro.analysis.jaxpr_audit import check_hot_path, check_pallas_grids
-    from repro.kernels.bfs_relax import ops as relax_ops
+    # the dense engine's kernel entry: the block map is built and uploaded
+    # once, outside the trace, and passed in as an argument
+    prog = SsspProgram()
+    relax, blockmap = make_relax_fn(
+        layout.dst, n, reduce=prog.reduce, interpret=_interpret()
+    )
 
-    bn, be, _, _ = relax_ops._block_dims(n, e, 512, 512)
-    src_d, dst_d, w_d = relax_ops._layout_edges_on_device(layout)
-    start_d, cnt_d, t_max = relax_ops._layout_blockmap_on_device(layout, bn, be)
-    closed = jax.make_jaxpr(
-        functools.partial(
-            relax_ops._bfs_relax_csr_jit,
-            n=n, block_n=bn, block_e=be, t_max=t_max, interpret=_interpret(),
+    @jax.jit
+    def relax_pass(blockmap, src, w, dist, frontier):
+        # candidates as the engine builds them: the frontier-masked relax of
+        # each arc's gathered source state
+        cand = jnp.where(
+            frontier[:, src], prog.relax(dist[:, src], w), prog.identity
         )
-    )(dist[None], frontier[None], src_d, dst_d, w_d, start_d, cnt_d)
+        return relax(blockmap, cand, dist)
+
+    args = (
+        blockmap, jnp.asarray(layout.src), jnp.asarray(layout.weights),
+        dist[None], frontier[None],
+    )
+    # the digest also audits the exact jitted pass it checks: a degenerate
+    # grid or a host callback here fails the bench, not just CI lint
+    closed = jax.make_jaxpr(relax_pass)(*args)
     findings = check_hot_path(closed, "bench/bfs_relax")
     findings += check_pallas_grids(closed, "bench/bfs_relax", expect_kernel=True)
     assert not findings, "\n".join(str(f) for f in findings)
 
-    out = bfs_relax_csr(dist, frontier, layout, interpret=_interpret())
+    out = relax_pass(*args)[0]
     ref = reference_bfs_relax(
         dist, frontier, jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w)
     )

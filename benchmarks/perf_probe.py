@@ -105,12 +105,13 @@ def probe_traversal(scale: int = 12, n_sources: int = 16):
     footprint: one executable per (graph, S) covering the entire traversal."""
     from repro.graph.generators import rmat_graph
     from repro.graph.partition import bfs_grow_partition
+    from repro.graph.config import EngineConfig
     from repro.graph.traversal import TraversalEngine
     import jax.numpy as jnp
 
     g = rmat_graph(scale, 8, seed=3)
     pg = bfs_grow_partition(g, 8, seed=1)
-    eng = TraversalEngine(pg, m_max=512)
+    eng = TraversalEngine(pg, config=EngineConfig(m_max=512))
     dist = jnp.full((n_sources, g.n_vertices), jnp.inf, jnp.float32)
     frontier = jnp.zeros((n_sources, g.n_vertices), bool)
     compiled = eng._traverse.lower(dist, frontier).compile()

@@ -132,6 +132,7 @@ from repro.core.elastic import ElasticBSPExecutor
 from repro.core.placement import default_placement, ffd_placement
 from repro.core.timing import DEFAULT_ALPHA, DEFAULT_BETA, TimeFunction
 from repro.graph.bsp import run_bc_forward, run_program, run_sssp
+from repro.graph.config import EngineConfig
 from repro.graph.generators import erdos_renyi_graph, rmat_graph, weighted
 from repro.graph.partition import bfs_grow_partition
 from repro.graph.program import BUILTIN_PROGRAMS, PageRankProgram
@@ -249,12 +250,12 @@ def _window_sweep(pg, source: int = 0) -> dict:
     host syncs per window size, same ffd plan throughout."""
     _, trace = run_sssp(pg, source, collect_subgraphs=False)
     plan = ffd_placement(TimeFunction.from_trace(trace))
-    ex = ElasticBSPExecutor(pg)
     per_k = {}
     for k in WINDOW_SIZES:
-        ex.run(source, plan, window=k)  # warm (compile) this window depth
+        ex = ElasticBSPExecutor(pg, config=EngineConfig(window=k))
+        ex.run(source, plan)  # warm (compile) this window depth
         t0 = time.perf_counter()
-        rep = ex.run(source, plan, window=k)
+        rep = ex.run(source, plan)
         wall = time.perf_counter() - t0
         per_k[str(k)] = {
             "wall_s": wall,
@@ -295,7 +296,9 @@ def _mesh_child() -> dict:
 
     per_d = {}
     for d_n in MESH_SIZES:
-        eng = get_engine(pg, m_max=512, mesh=partition_mesh(d_n))
+        eng = get_engine(
+            pg, config=EngineConfig(m_max=512, mesh=partition_mesh(d_n)),
+        )
         eng.run(sources)  # warm (compile)
         t0 = time.perf_counter()
         res = eng.run(sources)
@@ -361,7 +364,9 @@ def _programs_child() -> dict:
     mesh = partition_mesh(MESH_FORCED_DEVICES)
     rows = {}
     for name, prog in _bench_programs().items():
-        res = get_engine(pg, program=prog, m_max=512, mesh=mesh).run([0])
+        res = get_engine(
+            pg, program=prog, config=EngineConfig(m_max=512, mesh=mesh),
+        ).run([0])
         wire, pre = int(res.wire_msgs.sum()), int(res.msgs_sent.sum())
         assert 0 < wire < pre, (
             f"{name}: combiner aggregation must shrink the wire "
@@ -449,7 +454,9 @@ def _kernel_path_sweep() -> dict:
                 if name == "pagerank"
                 else BUILTIN_PROGRAMS[name]()
             )
-            eng = get_engine(pg, program=prog, m_max=512, backend=backend)
+            eng = get_engine(
+                pg, program=prog, config=EngineConfig(m_max=512, backend=backend),
+            )
             eng.run([0])  # warm (compile)
             t0 = time.perf_counter()
             res = eng.run([0])
@@ -524,10 +531,12 @@ def _print_kernel_path_sweep(sweep: dict) -> None:
 def _relayout_run(pg, plan, mesh, *, relayout: bool, window: int = 8) -> dict:
     """One warmed elastic run; returns its ledger row (plus dist for the
     caller's bit-identity assert)."""
-    ex = ElasticBSPExecutor(pg, mesh=mesh)
-    ex.run(0, plan, window=window, relayout=relayout)  # warm (compile)
+    ex = ElasticBSPExecutor(
+        pg, config=EngineConfig(mesh=mesh, window=window, relayout=relayout)
+    )
+    ex.run(0, plan)  # warm (compile)
     t0 = time.perf_counter()
-    rep = ex.run(0, plan, window=window, relayout=relayout)
+    rep = ex.run(0, plan)
     wall = time.perf_counter() - t0
     return {
         "wall_s": wall,
@@ -688,7 +697,9 @@ def _mirror_child() -> dict:
     pg = _mirror_bench_pg()
     mesh = partition_mesh(MIRROR_MESH_D)
     base = {
-        name: get_engine(pg, program=prog, m_max=512, mesh=mesh).run([0])
+        name: get_engine(
+            pg, program=prog, config=EngineConfig(m_max=512, mesh=mesh),
+        ).run([0])
         for name, prog in _bench_programs().items()
     }
     per_degree = {}
@@ -698,7 +709,9 @@ def _mirror_child() -> dict:
         for name, prog in _bench_programs().items():
             r0 = base[name]
             r1 = get_engine(
-                pg, program=prog, m_max=512, mesh=mesh, mirror_degree=t
+                pg,
+                program=prog,
+                config=EngineConfig(m_max=512, mesh=mesh, mirror_degree=t),
             ).run([0])
             parity = _assert_mirror_parity(name, prog, r0, r1, f"degree {t}: ")
             m = int(np.asarray(r0.n_supersteps).max())
@@ -963,7 +976,7 @@ def _dynamic_run(pg, muts, *, repartition: bool):
     end to end: ``open_session -> session.executor -> run(mutations=...)``.
     Returns ``(metrics_row, final_dist)``."""
     from repro.core.repartition import RepartitionConfig, partition_penalty
-    from repro.graph import EngineConfig, open_session
+    from repro.graph import open_session
 
     session = open_session(pg, EngineConfig(window=DYNAMIC_WINDOW))
     _, trace0 = run_sssp(pg, 0, collect_subgraphs=False)
@@ -1160,15 +1173,19 @@ def _smoke_child() -> dict:
     pg = bfs_grow_partition(g, SMOKE_PARTS, seed=1)
 
     # wire-savings invariant: per-destination aggregation shrinks the wire
-    res = get_engine(pg, m_max=128, mesh=partition_mesh(SMOKE_DEVICES)).run([0])
+    res = get_engine(
+        pg, config=EngineConfig(m_max=128, mesh=partition_mesh(SMOKE_DEVICES)),
+    ).run([0])
     wire, pre = int(res.wire_msgs.sum()), int(res.msgs_sent.sum())
     assert 0 < wire < pre, f"wire-savings violated: {wire} vs {pre}"
 
     # kernel-backend parity invariant: the Pallas relax path (interpret
     # mode) reproduces the XLA mesh run bit-for-bit on the tiny graph
     res_k = get_engine(
-        pg, m_max=128, mesh=partition_mesh(SMOKE_DEVICES),
-        backend="pallas-interpret",
+        pg,
+        config=EngineConfig(
+            m_max=128, mesh=partition_mesh(SMOKE_DEVICES), backend="pallas-interpret",
+        ),
     ).run([0])
     assert np.array_equal(np.asarray(res_k.dist), np.asarray(res.dist)), (
         "pallas-interpret mesh dist diverged from xla"
@@ -1182,7 +1199,10 @@ def _smoke_child() -> dict:
     from repro.graph.program import SsspProgram
 
     res_m = get_engine(
-        pg, m_max=128, mesh=partition_mesh(SMOKE_DEVICES), mirror_degree=2
+        pg,
+        config=EngineConfig(
+            m_max=128, mesh=partition_mesh(SMOKE_DEVICES), mirror_degree=2,
+        ),
     ).run([0])
     _assert_mirror_parity("sssp", SsspProgram(), res, res_m, "smoke: ")
     wire_m = int(np.asarray(res_m.wire_msgs).sum())

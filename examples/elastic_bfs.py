@@ -246,8 +246,6 @@ def main():
             1e-12, TimeFunction.from_trace(wl.trace).t_min()
         )
         # one EngineConfig carries every engine knob through the stack
-        # (the legacy mesh=/backend=/window= kwarg spellings still work but
-        # are deprecated -- see graph.config)
         cfg = EngineConfig(
             mesh=mesh, backend=args.backend,
             mirror_degree=args.mirror_degree,

@@ -286,9 +286,12 @@ def _fx_unscoped_op():
     """The real dense window with one op added outside any scope: a
     frontier-size counter taken after the superstep loop."""
     from repro.analysis.jaxpr_audit import default_audit_graph
+    from repro.graph.config import EngineConfig
     from repro.graph.traversal import TraversalEngine
 
-    engine = TraversalEngine(default_audit_graph(), backend="xla")
+    engine = TraversalEngine(
+        default_audit_graph(), config=EngineConfig(backend="xla")
+    )
 
     def window(dist, frontier, nst0, consts):
         out = engine._window_impl(dist, frontier, nst0, 3, consts)

@@ -708,10 +708,13 @@ def audit_delta_cycle(
 
 def audit_dense(pg, program, backend: str) -> list[Finding]:
     """Trace + audit one dense engine window."""
+    from repro.graph.config import EngineConfig
     from repro.graph.traversal import TraversalEngine
 
     label = f"dense/{program.name}/{backend}"
-    engine = TraversalEngine(pg, program=program, backend=backend)
+    engine = TraversalEngine(
+        pg, program=program, config=EngineConfig(backend=backend)
+    )
     closed = engine.window_jaxpr()
     findings = check_hot_path(closed, label)
     findings += check_pallas_grids(closed, label, expect_kernel=backend != "xla")

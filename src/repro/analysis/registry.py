@@ -72,13 +72,6 @@ TRACED_FUNCTIONS = (
     ),
     TracedFn(
         "kernels/bfs_relax/kernel.py",
-        "_kernel",
-        ("start_ref", "cnt_ref", "src_ref", "dst_ref", "w_ref", "dist_ref",
-         "frontier_ref", "o_ref"),
-        "Pallas kernel",
-    ),
-    TracedFn(
-        "kernels/bfs_relax/kernel.py",
         "_kernel_blockmap",
         ("start_ref", "cnt_ref", "dst_ref", "cand_ref", "base_ref", "o_ref"),
         "Pallas kernel (generic relax)",

@@ -25,22 +25,22 @@ The mapping from the paper's cloud model to JAX:
                           counters x the calibrated rate)
   billing              -> repro.core.billing on the *actual* executed trace
 
-Windowed execution (the scaling knob): ``run(..., window=k)`` executes ``k``
-supersteps per device launch on the resumable ``TraversalEngine`` window API
-and pulls only the ``O(k*P)`` counter window at each placement point -- one
-bulk host sync per window (``ceil(S/k) + 1`` syncs per run, the +1 being the
-final distance pull) instead of a frontier/counter round-trip every
-superstep.  ``window=1`` is the legacy per-superstep path, bit-identical in
-``dist`` and work counters for any ``k`` (the math does not depend on where
-the window boundaries fall).
+Windowed execution (the scaling knob): ``EngineConfig(window=k)`` executes
+``k`` supersteps per device launch on the resumable ``TraversalEngine``
+window API and pulls only the ``O(k*P)`` counter window at each placement
+point -- one bulk host sync per window (``ceil(S/k) + 1`` syncs per run, the
++1 being the final distance pull) instead of a frontier/counter round-trip
+every superstep.  ``window=1`` is the legacy per-superstep path,
+bit-identical in ``dist`` and work counters for any ``k`` (the math does not
+depend on where the window boundaries fall).
 
-Mesh execution (``mesh=partition_mesh(D)``): the traversal itself runs on the
-mesh-sharded engine (partition axis -> device mesh, real all-to-all exchange;
-see ``graph.mesh_exchange``), and the per-window placement commit becomes
-*physical resharding*: each partition's state shard is ``place_shard``-ed to
-the device its VM maps onto (``Placement.device_row``), so migration is a
-device-to-device transfer, not a bookkeeping entry.  Two ledgers are kept
-deliberately separate:
+Mesh execution (``EngineConfig(mesh=partition_mesh(D))``): the traversal
+itself runs on the mesh-sharded engine (partition axis -> device mesh, real
+all-to-all exchange; see ``graph.mesh_exchange``), and the per-window
+placement commit becomes *physical resharding*: each partition's state
+shard is ``place_shard``-ed to the device its VM maps onto
+(``Placement.device_row``), so migration is a device-to-device transfer, not
+a bookkeeping entry.  Two ledgers are kept deliberately separate:
 
   * ``migration_bytes`` / ``CostReport.migration_secs`` bill the *simulated
     cloud* moves of the plan (every VM change, priced at
@@ -52,8 +52,8 @@ deliberately separate:
     active VMs the VM -> device map is injective and the two ledgers
     coincide -- the billed migration is the physical one.
 
-Dynamic re-layout (``run(..., relayout=True)``, mesh mode) closes the loop
-the data-plane resharding left open: the *compute* layout follows the
+Dynamic re-layout (``EngineConfig(relayout=True)``, mesh mode) closes the
+loop the data-plane resharding left open: the *compute* layout follows the
 planner too.  At every window boundary the spliced placement row is bridged
 onto mesh devices (``Placement.device_row`` via ``device_of_vm``) and handed
 to ``TraversalEngine.run_window(device_of_part=...)`` -- the engine swaps to
@@ -102,7 +102,7 @@ from repro.core.repartition import RepartitionConfig, incremental_repartition
 from repro.core.replan import OnlineReplanner, ReplanConfig
 from repro.core.timing import DEFAULT_ALPHA, DEFAULT_BETA, TimeFunction
 from repro.graph import deltas as graph_deltas
-from repro.graph.config import UNSET, EngineConfig, resolve_config, versioned_report
+from repro.graph.config import EngineConfig, versioned_report
 from repro.graph.mesh_exchange import place_shard
 from repro.graph.program import SsspProgram, VertexProgram
 from repro.graph.structs import PartitionedGraph
@@ -160,16 +160,9 @@ class ElasticBSPExecutor:
         beta: float = DEFAULT_BETA,
         tau_scale: float = 1.0,
         billing: BillingModel | None = None,
-        mesh=UNSET,
-        backend: str = UNSET,
-        mirror_degree: int | None = UNSET,
         config: EngineConfig | None = None,
     ):
-        cfg = resolve_config(
-            config,
-            {"mesh": mesh, "backend": backend, "mirror_degree": mirror_degree},
-            owner="ElasticBSPExecutor",
-        )
+        cfg = config or EngineConfig()
         self.config = cfg
         self.pg = pg
         self.program = program or SsspProgram()
@@ -300,13 +293,14 @@ class ElasticBSPExecutor:
         replan: bool = False,
         replan_config: ReplanConfig | None = None,
         sketch: TimeFunction | None = None,
-        relayout: bool = UNSET,
-        window: int = UNSET,
         max_supersteps: int = 4096,
         mutations=None,
         repartition: RepartitionConfig | bool | None = None,
     ) -> ExecutionReport:
         """Execute the program under ``plan``; see the module docstring.
+
+        The window depth and the re-layout policy are the executor's
+        ``config.window`` and ``config.relayout``.
 
         ``relayout=True`` (mesh mode; a no-op dense, where one device does
         all the work) makes the compute layout follow the planner: each
@@ -329,20 +323,8 @@ class ElasticBSPExecutor:
         """
         pg = self.pg
         t0 = time.perf_counter()
-        if window is not UNSET or relayout is not UNSET:
-            import warnings
-
-            warnings.warn(
-                "ElasticBSPExecutor.run(window=, relayout=) is deprecated; "
-                "set EngineConfig(window=, relayout=) on the executor",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-        if window is UNSET:
-            window = self.config.window
-        if relayout is UNSET:
-            relayout = self.config.relayout
-        window = max(1, int(window))
+        window = max(1, int(self.config.window))
+        relayout = self.config.relayout
         auto_relayout = isinstance(relayout, str) and relayout == "auto"
         relayout = (
             (auto_relayout or bool(relayout))

@@ -13,15 +13,16 @@ Layers:
                  PageRank) both engines are parameterized by
   traversal   -- device-resident multi-source BSP engine (whole traversal in
                  one lax.while_loop) + the per-superstep fn for the executor;
-                 ``mesh=`` shards the partition axis over a device mesh
+                 ``EngineConfig(mesh=)`` shards the partition axis over a
+                 device mesh
   mesh_exchange -- the shard_map window program: per-destination aggregation
                  + all-to-all remote exchange, physical shard placement
   bsp         -- host drivers building BSP work traces (one bulk transfer
                  per traversal batch)
   sampler     -- fanout neighbor sampler for minibatch GNN training
   config      -- ``EngineConfig``, the one frozen knob surface every
-                 engine-shaped constructor accepts (legacy kwargs keep
-                 working for one release behind ``DeprecationWarning`` shims)
+                 engine-shaped constructor accepts (the only way to set an
+                 engine knob)
   deltas      -- streaming edge mutations: bounded ``EdgeDeltaBuffer``
                  merged into the static layouts at window boundaries,
                  byte-identical to a from-scratch build

@@ -1,18 +1,11 @@
 """One frozen configuration surface for every engine-shaped constructor.
 
-Engine knobs accreted per subsystem: ``TraversalEngine(mesh=, backend=,
-mirror_degree=, block_n=, block_e=)``, ``ElasticBSPExecutor.run(window=,
-relayout=)``, ``TraversalService(mesh=, backend=)`` -- the same five ideas
-spelled slightly differently at each layer.  ``EngineConfig`` collapses them
-into one immutable value that travels intact from ``open_session`` down
-through ``bsp.run_program``, the elastic executor, and the serving layer.
-
-Migration contract: every legacy keyword keeps working for one release via
-thin shims that raise ``DeprecationWarning`` (see ``TraversalEngine`` /
-``get_engine`` / ``ElasticBSPExecutor`` / ``TraversalService``); passing
-``config=`` is the forward path.  When both are given, the explicit legacy
-keyword wins -- callers mid-migration can override one knob without
-rebuilding the config.
+``EngineConfig`` holds every engine knob (mesh, backend, block sizes,
+window, relayout, ...) in one immutable value.  It is the only way to set
+them: ``TraversalEngine``, ``get_engine``, ``ElasticBSPExecutor`` and
+``TraversalService`` each take it as their one engine argument, and it
+travels intact from ``open_session`` down through ``bsp.run_program``, the
+elastic executor, and the serving layer.
 
 ``REPORT_SCHEMA_VERSION`` + ``versioned_report`` define the shared
 ``asdict()`` surface of ``TraversalResult`` / ``ExecutionReport`` /
@@ -30,10 +23,6 @@ from typing import Any
 #: Version of the shared report-dict surface.  Bump when a field is renamed
 #: or removed; adding fields is backward compatible and does NOT bump.
 REPORT_SCHEMA_VERSION = 1
-
-#: sentinel distinguishing "caller left the legacy kwarg alone" from any
-#: real value (None is meaningful for mesh / mirror_degree)
-UNSET: Any = object()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,40 +45,6 @@ class EngineConfig:
 
     def replace(self, **kw) -> "EngineConfig":
         return dataclasses.replace(self, **kw)
-
-    def resolve(self, name: str, legacy_value: Any) -> Any:
-        """The effective value of knob ``name``: the legacy kwarg when the
-        caller passed one, this config's field otherwise."""
-        if legacy_value is UNSET:
-            return getattr(self, name)
-        return legacy_value
-
-
-def resolve_config(
-    config: "EngineConfig | None",
-    legacy: dict[str, Any],
-    *,
-    owner: str,
-) -> "EngineConfig":
-    """Shared deprecation shim: fold legacy kwargs over ``config``.
-
-    ``legacy`` maps knob name -> passed value (``UNSET`` when the caller
-    left it alone).  Passing any legacy knob *without* a config warns once
-    per call site that the kwarg spelling is deprecated; the returned config
-    always reflects the effective knob values.
-    """
-    passed = {k: v for k, v in legacy.items() if v is not UNSET}
-    if passed and config is None:
-        import warnings
-
-        warnings.warn(
-            f"{owner}: engine kwargs {sorted(passed)} are deprecated; "
-            "pass graph.config.EngineConfig(...) via config= instead",
-            DeprecationWarning,
-            stacklevel=3,
-        )
-    base = config or EngineConfig()
-    return base.replace(**passed) if passed else base
 
 
 def versioned_report(kind: str, fields: dict) -> dict:

@@ -67,7 +67,7 @@ def block_ranges_for(
     block-skipping relax kernel scalar-prefetches.  ``t_max = max(count)``
     bounds the kernel's inner grid dimension (vs ``ceil(E/block_e)`` for a
     dense grid that tests intersection per tile).  Shared by
-    ``CsrEdgeLayout.block_ranges`` (dense engine) and
+    ``kernels.bfs_relax.make_relax_fn`` (dense engine) and
     ``MeshEdgeLayout.local_block_map``/``wire_block_map`` (per-device maps).
     """
     dst = np.asarray(dst)
@@ -106,12 +106,12 @@ def device_block_ranges(
 class CsrEdgeLayout:
     """Static destination-sorted edge layout, built once per (sub)edge-set.
 
-    The traversal engine and the ``bfs_relax`` kernel consume edges in this
-    fixed order for the lifetime of a graph, which (a) lets every segment
-    reduction take the ``indices_are_sorted`` fast path, (b) kills the
-    per-call ``argsort`` the kernel wrapper used to pay, and (c) makes the
-    per-tile destination ranges *static*, so the kernel grid can skip
-    (row_block, edge_block) tiles that provably hold no in-range edge.
+    The traversal engine and its relax kernel (``make_relax_fn``) consume
+    edges in this fixed order for the lifetime of a graph, which (a) lets
+    every segment reduction take the ``indices_are_sorted`` fast path, (b)
+    needs no per-call ``argsort``, and (c) makes the per-tile destination
+    ranges *static*, so the kernel grid can skip (row_block, edge_block)
+    tiles that provably hold no in-range edge.
 
     Contract: ``dst`` is ascending; ``src``/``weights`` are permuted to match.
     ``perm`` retains the applied permutation (indices into the edge arrays the
@@ -129,26 +129,6 @@ class CsrEdgeLayout:
     @property
     def n_edges(self) -> int:
         return int(self.dst.shape[0])
-
-    def block_ranges(self, block_n: int, block_e: int) -> tuple[np.ndarray, np.ndarray, int]:
-        """Per-row-block contiguous edge-block span: (start [NB], count [NB], t_max).
-
-        Because ``dst`` is sorted, the set of edge blocks intersecting a row
-        block ``[ob*block_n, (ob+1)*block_n)`` is a contiguous range of edge
-        blocks -- representable as a start index and a count, which is what
-        the block-skipping kernel scalar-prefetches.  ``t_max = max(count)``
-        bounds the kernel's inner grid dimension (vs ``ceil(E/block_e)`` for
-        the dense grid that tests intersection per tile).
-        """
-        key = ("block_ranges", int(block_n), int(block_e))
-        cached = self.__dict__.get("_block_cache")
-        if not isinstance(cached, BoundedCache):
-            cached = BoundedCache(_BLOCK_CACHE_MAX)
-            self.__dict__["_block_cache"] = cached
-        return cached.get_or_build(
-            key,
-            lambda: block_ranges_for(self.dst, self.n_vertices, int(block_n), int(block_e)),
-        )
 
 
 def mesh_layout_key(

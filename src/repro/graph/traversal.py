@@ -31,14 +31,15 @@ Execution modes sharing the same math:
     counters into preallocated ``[S, m_max, P]`` device buffers, one bulk
     transfer after convergence.  State carries a leading source axis ``S``
     so multi-source sweeps (BC forward) amortize compilation and launches.
-  * ``TraversalEngine(mesh=...)`` -- the **mesh-sharded** engine: the same
-    window program, but the partition axis is laid out over a 1-D
-    ``jax.sharding.Mesh`` (``dist.sharding.partition_mesh``).  Each device
-    owns a fixed-shape padded vertex shard (``structs.MeshEdgeLayout``), the
-    local closure runs per device with ``pmax``-synchronized iteration
-    counts, and the superstep-boundary exchange is a *real* collective:
-    per-destination min-aggregation into static wire slots (one message per
-    ``(dst_vertex, dst_device)``, not per edge) followed by one static-shape
+  * ``TraversalEngine(config=EngineConfig(mesh=...))`` -- the
+    **mesh-sharded** engine: the same window program, but the partition axis
+    is laid out over a 1-D ``jax.sharding.Mesh``
+    (``dist.sharding.partition_mesh``).  Each device owns a fixed-shape
+    padded vertex shard (``structs.MeshEdgeLayout``), the local closure runs
+    per device with ``pmax``-synchronized iteration counts, and the
+    superstep-boundary exchange is a *real* collective: per-destination
+    min-aggregation into static wire slots (one message per ``(dst_vertex,
+    dst_device)``, not per edge) followed by one static-shape
     ``jax.lax.all_to_all`` (``graph.mesh_exchange``).  Distances and the
     ``[S, m_max, P]`` counters are bit-identical to the dense engine for any
     device count; a one-device mesh silently uses the dense path.
@@ -61,7 +62,7 @@ All modes consume the static dst-sorted CSR layout built once per graph
 ``indices_are_sorted`` fast path and no per-call ``argsort`` exists anywhere
 on the traversal hot path.
 
-Knobs (see ``TraversalEngine``):
+Knobs (fields of ``graph.config.EngineConfig``, the engine's ``config``):
   * ``m_max``      -- trace-buffer depth = superstep cap.  Buffers are
     ``[S, m_max, P]`` int32; 4096 x 40 partitions is ~0.7 MB per counter.
   * batching ``S`` -- callers pass ``[S, n]`` initial state; one compiled
@@ -69,8 +70,9 @@ Knobs (see ``TraversalEngine``):
   * ``collect_subgraphs`` -- also record per-superstep active-subgraph
     bitmasks ``[S, m_max, n_subgraphs]`` on device (the metagraph layer's
     ground truth), still transferred in the same single bulk pull.
-  * ``mesh`` / ``device_of_part`` -- shard the partition axis over mesh
-    devices (default: balanced contiguous blocks).
+  * ``mesh`` -- shard the partition axis over mesh devices; the engine's
+    own ``device_of_part`` argument places partitions on them (default:
+    balanced contiguous blocks).
 
 Windowed execution (``init_state`` / ``run_window``): the same device program
 also runs *resumably* -- ``run_window(state, k)`` executes up to ``k``
@@ -91,7 +93,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.graph.config import UNSET, EngineConfig, resolve_config
+from repro.graph.config import EngineConfig
 from repro.graph.partition import partitioned_edge_layout
 from repro.graph.program import (
     SsspProgram,
@@ -363,25 +365,10 @@ class TraversalEngine:
         pg: PartitionedGraph,
         *,
         program: VertexProgram | None = None,
-        m_max: int = UNSET,
-        collect_subgraphs: bool = UNSET,
-        mesh=UNSET,
         device_of_part: np.ndarray | None = None,
-        backend: str = UNSET,
-        block_n: int = UNSET,
-        block_e: int = UNSET,
-        mirror_degree: int | None = UNSET,
         config: EngineConfig | None = None,
     ):
-        cfg = resolve_config(
-            config,
-            {
-                "m_max": m_max, "collect_subgraphs": collect_subgraphs,
-                "mesh": mesh, "backend": backend, "block_n": block_n,
-                "block_e": block_e, "mirror_degree": mirror_degree,
-            },
-            owner="TraversalEngine",
-        )
+        cfg = config or EngineConfig()
         m_max = cfg.m_max
         collect_subgraphs = cfg.collect_subgraphs
         mesh = cfg.mesh
@@ -912,11 +899,6 @@ def get_engine(
     pg: PartitionedGraph,
     *,
     program: VertexProgram | None = None,
-    m_max: int = UNSET,
-    collect_subgraphs: bool = UNSET,
-    mesh=UNSET,
-    backend: str = UNSET,
-    mirror_degree: int | None = UNSET,
     config: EngineConfig | None = None,
 ) -> TraversalEngine:
     """Per-graph engine cache (keyed by the knobs, stored on the instance).
@@ -927,17 +909,9 @@ def get_engine(
     and, in mesh mode, the mesh's device ids; the default balanced
     contiguous partition map is assumed (construct ``TraversalEngine``
     directly for a custom ``device_of_part``).  Knobs come from ``config``
-    (an ``EngineConfig``); the bare kwargs are the deprecated legacy
-    spelling and override the config when passed.
+    (an ``EngineConfig``; None means ``EngineConfig()``).
     """
-    cfg = resolve_config(
-        config,
-        {
-            "m_max": m_max, "collect_subgraphs": collect_subgraphs,
-            "mesh": mesh, "backend": backend, "mirror_degree": mirror_degree,
-        },
-        owner="get_engine",
-    )
+    cfg = config or EngineConfig()
     engines = pg.__dict__.get("_traversal_engines")
     if not isinstance(engines, BoundedCache):
         engines = BoundedCache(_ENGINE_CACHE_MAX)

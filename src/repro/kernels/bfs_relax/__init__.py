@@ -1,21 +1,15 @@
 from repro.kernels.bfs_relax.ops import (
     RELAX_BACKENDS,
-    bfs_relax,
-    bfs_relax_csr,
     make_relax_fn,
     relax_blockmap_call,
-    relax_csr,
     validate_backend,
 )
 from repro.kernels.bfs_relax.ref import reference_bfs_relax
 
 __all__ = [
     "RELAX_BACKENDS",
-    "bfs_relax",
-    "bfs_relax_csr",
     "make_relax_fn",
     "relax_blockmap_call",
-    "relax_csr",
     "reference_bfs_relax",
     "validate_backend",
 ]

@@ -1,4 +1,4 @@
-"""Program-generic frontier relaxation Pallas TPU kernels -- the paper's
+"""Program-generic frontier relaxation Pallas TPU kernel -- the paper's
 per-superstep hot spot (GoFFish compute() = repeated edge relaxations).
 
 Same TPU adaptation as segment_sum: candidate messages (program.relax of the
@@ -19,19 +19,15 @@ algebra:
     with a zero identity; the base (normally all-zero) seeds the
     accumulator, which lets callers chain local- and remote-plane passes.
 
-Variants:
-  * ``bfs_relax_kernel`` -- dense (row_block, edge_block) grid; every tile
-    runs and tests ``intersects`` itself.  Kept for ad-hoc edge orders.
-  * ``relax_kernel_blockmap`` -- the static-layout fast path.  A precomputed
-    block map (``block_ranges_for``: per row block, the contiguous span of
-    edge blocks that can hit it) is scalar-prefetched, so the grid
-    enumerates only tiles that provably contain in-range edges.  Sources
-    ride the sublane axis in tiles of up to ``SOURCE_TILE`` rows: one grid
-    step fetches the dst tile and builds its hit mask once, then reduces
-    every source of the tile against it.  A tile of 8 rows (or all rows,
-    when there are fewer) keeps every block's last two dims legal for
-    Mosaic; sources past a multiple of 8 are padded with identity rows.
-  * ``bfs_relax_kernel_blockmap`` -- backcompat min-reduce wrapper.
+``relax_kernel_blockmap`` is the one kernel.  A precomputed block map
+(``block_ranges_for``: per row block, the contiguous span of edge blocks
+that can hit it) is scalar-prefetched, so the grid enumerates only tiles
+that provably contain in-range edges.  Sources ride the sublane axis in
+tiles of up to ``SOURCE_TILE`` rows: one grid step fetches the dst tile and
+builds its hit mask once, then reduces every source of the tile against it.
+A tile of 8 rows (or all rows, when there are fewer) keeps every block's
+last two dims legal for Mosaic; sources past a multiple of 8 are padded with
+identity rows.
 """
 
 from __future__ import annotations
@@ -44,62 +40,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 INF = float("inf")  # python scalar: jnp constants would be captured tracers
-
-
-def _kernel(
-    dst_ref,  # (1, bE) int32 sorted, padded with n
-    cand_ref,  # (1, bE) f32 candidate dist (inf where inactive)
-    dist_ref,  # (1, bN) f32 current distances for this row block
-    o_ref,  # (1, bN) f32, persists across edge blocks
-    *,
-    block_n: int,
-    block_e: int,
-):
-    oi = pl.program_id(0)
-    ki = pl.program_id(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        o_ref[...] = dist_ref[...]
-
-    dst = dst_ref[0, :]
-    row_start = oi * block_n
-    intersects = (dst[block_e - 1] >= row_start) & (dst[0] < row_start + block_n)
-
-    @pl.when(intersects)
-    def _relax():
-        rows = row_start + jax.lax.broadcasted_iota(jnp.int32, (block_e, block_n), 1)
-        hit = dst[:, None] == rows
-        m = jnp.where(hit, cand_ref[0, :][:, None], INF)
-        o_ref[0, :] = jnp.minimum(o_ref[0, :], m.min(axis=0))
-
-
-def bfs_relax_kernel(
-    dst_sorted: jax.Array,  # [E] int32 sorted by destination
-    cand: jax.Array,  # [E] f32 candidates aligned with dst_sorted
-    dist: jax.Array,  # [N] f32
-    *,
-    block_n: int = 512,
-    block_e: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    (e,) = cand.shape
-    (n,) = dist.shape
-    assert e % block_e == 0 and n % block_n == 0
-    grid = (n // block_n, e // block_e)
-    kern = functools.partial(_kernel, block_n=block_n, block_e=block_e)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_e), lambda oi, ki: (0, ki)),
-            pl.BlockSpec((1, block_e), lambda oi, ki: (0, ki)),
-            pl.BlockSpec((1, block_n), lambda oi, ki: (0, oi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_n), lambda oi, ki: (0, oi)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
-        interpret=interpret,
-    )(dst_sorted.reshape(1, e), cand.reshape(1, e), dist.reshape(1, n))[0]
 
 
 def _kernel_blockmap(
@@ -221,29 +161,3 @@ def relax_kernel_blockmap(
     )(start, cnt, dst_sorted.reshape(1, e_pad), cand, base)
     return out[:s]
 
-
-def bfs_relax_kernel_blockmap(
-    start: jax.Array,
-    cnt: jax.Array,
-    dst_sorted: jax.Array,
-    cand: jax.Array,
-    dist: jax.Array,
-    *,
-    block_n: int,
-    block_e: int,
-    t_max: int,
-    interpret: bool = False,
-) -> jax.Array:
-    """Backcompat wrapper: min-reduce blockmap relaxation (BFS/SSSP)."""
-    return relax_kernel_blockmap(
-        start,
-        cnt,
-        dst_sorted,
-        cand,
-        dist,
-        block_n=block_n,
-        block_e=block_e,
-        t_max=t_max,
-        reduce="min",
-        interpret=interpret,
-    )

@@ -1,49 +1,36 @@
-"""jit'd wrappers around the relaxation kernels + the engine backend switch.
+"""The engines' two entries to the relax kernel, and the backend switch.
 
-``bfs_relax`` is the general entry: computes candidates (XLA gather), sorts
-by destination unless ``presorted=True``, pads to block multiples, runs the
-dense-grid kernel.
+Both reach the one kernel, ``relax_kernel_blockmap``, which computes
+``combine(base, segment_reduce(cand, dst))`` over a dst-sorted edge array
+for the whole ``VertexProgram`` algebra: ``reduce="min"`` (BFS / SSSP /
+WCC; identity-padded, dtype follows the state, WCC's int32 labels included)
+and ``reduce="sum"`` (PageRank).  The caller gathers the candidates,
+``where(frontier[:, src], program.relax(state[:, src], w), identity)``.
 
-``bfs_relax_csr`` is the static-layout fast path: edges come from a
-``CsrEdgeLayout`` (dst already ascending -- no argsort, ever), the layout's
-precomputed block map drives the block-skipping kernel, and a leading source
-dimension batches multiple BFS sweeps through one kernel launch.
+  * ``make_relax_fn`` -- the dense engine's entry (``graph.traversal``): a
+    host-side builder that computes the static block map of a dst-sorted
+    edge array once, uploads it, and returns it with the traced relax fn
+    that takes it as an argument.
+  * ``relax_blockmap_call`` -- the mesh engine's entry
+    (``graph.mesh_exchange``, once per device shard): fully traced given a
+    precomputed block map, so it is safe inside
+    ``jit``/``while_loop``/``shard_map``.  ``make_relax_fn``'s fn calls it.
 
-``relax_csr`` generalizes the same path over the whole ``VertexProgram``
-algebra: ``reduce="min"`` (BFS/SSSP/WCC, identity-padded, dtype follows the
-state -- WCC's int32 labels included) and ``reduce="sum"`` (PageRank,
-reusing the segment-sum accumulate idiom).  The lower-level pieces both
-engines build on:
-
-  * ``relax_blockmap_call`` -- fully traced ``combine(base,
-    segment_reduce(cand, dst))`` given a precomputed block map; safe inside
-    ``jit``/``while_loop``/``shard_map`` (the mesh engine calls it per
-    device shard).
-  * ``make_relax_fn`` -- host-side builder for the dense engine: computes
-    the static block map once, uploads it, and returns it with the traced
-    relax fn that takes it as an argument.
-
-Both engines select this path via ``backend`` in ``RELAX_BACKENDS``:
-``"xla"`` (default; segment ops, right on CPU), ``"pallas"`` (compiled
-kernels, TPU), ``"pallas-interpret"`` (kernel semantics on CPU -- the CI
-parity mode).
+``relax_grid`` reports the grid one ``make_relax_fn`` pass launches.  Both
+engines select the kernel via ``backend`` in ``RELAX_BACKENDS``: ``"xla"``
+(default; segment ops, right on CPU), ``"pallas"`` (the compiled kernel,
+TPU), ``"pallas-interpret"`` (kernel semantics on CPU -- the CI parity
+mode).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.graph.structs import BoundedCache, block_ranges_for
-from repro.kernels.bfs_relax.kernel import (
-    SOURCE_TILE,
-    bfs_relax_kernel,
-    bfs_relax_kernel_blockmap,
-    relax_kernel_blockmap,
-)
+from repro.graph.structs import block_ranges_for
+from repro.kernels.bfs_relax.kernel import SOURCE_TILE, relax_kernel_blockmap
 
 RELAX_BACKENDS = ("xla", "pallas", "pallas-interpret")
 
@@ -69,161 +56,6 @@ def _block_dims(n: int, e: int, block_n: int, block_e: int) -> tuple[int, int, i
     e_pad = max(block_e, (e + block_e - 1) // block_e * block_e)
     n_pad = max(block_n, (n + block_n - 1) // block_n * block_n)
     return block_n, block_e, n_pad, e_pad
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_n", "block_e", "interpret", "presorted")
-)
-def bfs_relax(
-    dist: jax.Array,  # [N] f32
-    frontier: jax.Array,  # [N] bool
-    src: jax.Array,  # [E] int32
-    dst: jax.Array,  # [E] int32
-    w: jax.Array,  # [E] f32
-    *,
-    block_n: int = 512,
-    block_e: int = 512,
-    interpret: bool = False,
-    presorted: bool = False,  # dst already ascending (static edge order)
-) -> jax.Array:
-    (n,) = dist.shape
-    (e,) = src.shape
-    cand = jnp.where(frontier[src], dist[src] + w, jnp.inf)
-    if not presorted:
-        order = jnp.argsort(dst)
-        dst, cand = dst[order], cand[order]
-    block_n, block_e, n_pad, e_pad = _block_dims(n, e, block_n, block_e)
-    dst = jnp.pad(dst, (0, e_pad - e), constant_values=n_pad)
-    cand = jnp.pad(cand, (0, e_pad - e), constant_values=jnp.inf)
-    dist_p = jnp.pad(dist, (0, n_pad - n), constant_values=jnp.inf)
-    out = bfs_relax_kernel(
-        dst, cand, dist_p, block_n=block_n, block_e=block_e, interpret=interpret
-    )
-    return out[:n]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n", "block_n", "block_e", "t_max", "interpret"),
-)
-def _bfs_relax_csr_jit(
-    dist,  # [S, N] f32
-    frontier,  # [S, N] bool
-    src,  # [E] int32 (dst-sorted order)
-    dst,  # [E] int32 ascending
-    w,  # [E] f32
-    start,  # [NB] int32 block map
-    cnt,  # [NB] int32
-    *,
-    n: int,
-    block_n: int,
-    block_e: int,
-    t_max: int,
-    interpret: bool,
-):
-    e = src.shape[0]
-    cand = jnp.where(frontier[:, src], dist[:, src] + w, jnp.inf)
-    _, _, n_pad, e_pad = _block_dims(n, e, block_n, block_e)
-    dst_p = jnp.pad(dst, (0, e_pad - e), constant_values=n_pad)
-    cand_p = jnp.pad(cand, ((0, 0), (0, e_pad - e)), constant_values=jnp.inf)
-    dist_p = jnp.pad(dist, ((0, 0), (0, n_pad - n)), constant_values=jnp.inf)
-    out = bfs_relax_kernel_blockmap(
-        start,
-        cnt,
-        dst_p,
-        cand_p,
-        dist_p,
-        block_n=block_n,
-        block_e=block_e,
-        t_max=t_max,
-        interpret=interpret,
-    )
-    return out[:, :n]
-
-
-#: bounded device-upload cache per layout.  PR 5's ``mesh_layout_key``
-#: taught the layer that layout caches need canonical keys and a bound; the
-#: entries here are keyed the same way -- by the *coerced* static inputs
-#: (kind tag + int block geometry), never by array identity -- and LRU-bound
-#: so sweeping block geometries (benchmarks do) cannot grow the cache
-#: unboundedly per layout.
-_DEVICE_CACHE_MAX = 8
-
-
-def _device_cached(layout, key: tuple, build):
-    """Fetch-or-build an entry in the layout's bounded device cache."""
-    cache = layout.__dict__.get("_device_cache")
-    if not isinstance(cache, BoundedCache):
-        cache = BoundedCache(_DEVICE_CACHE_MAX)
-        layout.__dict__["_device_cache"] = cache
-    return cache.get_or_build(key, build)
-
-
-def _layout_edges_on_device(layout):
-    return _device_cached(
-        layout,
-        ("edges",),
-        lambda: tuple(
-            jnp.asarray(a) for a in (layout.src, layout.dst, layout.weights)
-        ),
-    )
-
-
-def _layout_blockmap_on_device(layout, block_n: int, block_e: int):
-    def build():
-        start, cnt, t_max = layout.block_ranges(block_n, block_e)
-        return jnp.asarray(start), jnp.asarray(cnt), t_max
-
-    return _device_cached(
-        layout, ("blockmap", int(block_n), int(block_e)), build
-    )
-
-
-def bfs_relax_csr(
-    dist: jax.Array,  # [N] or [S, N] f32
-    frontier: jax.Array,  # matching bool
-    layout,  # CsrEdgeLayout (static, host-side)
-    *,
-    block_n: int = 512,
-    block_e: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """``min(dist, segment_min(cand, dst))`` over a static dst-sorted layout.
-
-    Always takes the presorted path (the layout *is* the sort), and skips
-    empty (row_block, edge_block) tiles via the layout's block map.  Accepts
-    a batched ``[S, N]`` state to amortize kernel launches across sources.
-    """
-    squeeze = dist.ndim == 1
-    if squeeze:
-        dist, frontier = dist[None], frontier[None]
-    n = dist.shape[1]
-    e = layout.n_edges
-    if e == 0:
-        return dist[0] if squeeze else dist
-    block_n, block_e, _, _ = _block_dims(n, e, block_n, block_e)
-    src_d, dst_d, w_d = _layout_edges_on_device(layout)
-    start_d, cnt_d, t_max = _layout_blockmap_on_device(layout, block_n, block_e)
-    out = _bfs_relax_csr_jit(
-        dist,
-        frontier,
-        src_d,
-        dst_d,
-        w_d,
-        start_d,
-        cnt_d,
-        n=n,
-        block_n=block_n,
-        block_e=block_e,
-        t_max=t_max,
-        interpret=interpret,
-    )
-    return out[0] if squeeze else out
-
-
-# ---------------------------------------------------------------------------
-# program-generic entry points (the engine backend)
-# ---------------------------------------------------------------------------
 
 
 def _identity_scalar(reduce: str, dtype):
@@ -355,66 +187,3 @@ def make_relax_fn(
 
     return relax, blockmap
 
-
-def relax_csr(
-    program,  # graph.program.VertexProgram
-    state: jax.Array,  # [N] or [S, N], dtype = program.dtype
-    frontier: jax.Array,  # matching bool
-    layout,  # CsrEdgeLayout (static, host-side)
-    *,
-    block_n: int = 512,
-    block_e: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """One program-generic relaxation pass over a static dst-sorted layout.
-
-    Computes ``cand = where(frontier[src], program.relax(state[src], w),
-    identity)`` (XLA gather) then reduces per destination with the
-    block-skipping kernel.  Matches the engine's consumption of each
-    reduction: monotone programs (``reduce="min"``) return
-    ``combine(state, segment_min(cand, dst))``; stationary programs
-    (``reduce="sum"``) return the pre-apply accumulator
-    ``segment_sum(cand, dst)``.
-
-    The plane value fed to ``program.relax`` is ``layout.weights`` -- for
-    programs with a non-graph ``plane_key`` (BFS unit hops, PageRank
-    ``1/out_degree``) build the layout with that plane as its weights
-    (``resolve_edge_plane`` + the layout's retained ``perm``).
-    """
-    squeeze = state.ndim == 1
-    if squeeze:
-        state, frontier = state[None], frontier[None]
-    n = state.shape[1]
-    e = layout.n_edges
-    ident = _identity_scalar(program.reduce, state.dtype)
-    if e == 0:
-        out = (
-            state
-            if program.reduce == "min"
-            else jnp.full_like(state, ident)
-        )
-        return out[0] if squeeze else out
-    bn, be, _, _ = _block_dims(n, e, block_n, block_e)
-    src_d, dst_d, w_d = _layout_edges_on_device(layout)
-    start_d, cnt_d, t_max = _layout_blockmap_on_device(layout, bn, be)
-    cand = jnp.where(
-        frontier[:, src_d], program.relax(state[:, src_d], w_d), ident
-    )
-    base = (
-        state
-        if program.reduce == "min"
-        else jnp.full_like(state, ident)
-    )
-    out = relax_blockmap_call(
-        start_d,
-        cnt_d,
-        dst_d,
-        cand,
-        base,
-        reduce=program.reduce,
-        block_n=bn,
-        block_e=be,
-        t_max=t_max,
-        interpret=interpret,
-    )
-    return out[0] if squeeze else out

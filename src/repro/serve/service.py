@@ -58,7 +58,7 @@ from repro.core.billing import BillingModel, CostReport, evaluate
 from repro.core.placement import Placement
 from repro.core.replan import ReplanConfig
 from repro.core.timing import DEFAULT_ALPHA, DEFAULT_BETA
-from repro.graph.config import UNSET, EngineConfig, resolve_config, versioned_report
+from repro.graph.config import EngineConfig, versioned_report
 from repro.serve.batcher import MicroBatcher
 from repro.serve.queue import Admitted, AdmissionQueue, TraversalQuery, lane_key
 from repro.serve.scheduler import CapacityScheduler, lpt_rows
@@ -192,18 +192,12 @@ class TraversalService:
         *,
         config: ServiceConfig | None = None,
         default_program=None,
-        mesh=UNSET,
-        backend: str = UNSET,
         engine_config: EngineConfig | None = None,
     ):
         from repro.graph.program import SsspProgram
         from repro.graph.traversal import get_engine
 
-        ecfg = resolve_config(
-            engine_config,
-            {"mesh": mesh, "backend": backend},
-            owner="TraversalService",
-        )
+        ecfg = engine_config or EngineConfig()
         self.pg = pg
         self.config = config or ServiceConfig()
         self.default_program = default_program or SsspProgram()

@@ -7,11 +7,11 @@ Asserts, under real 8-device execution:
     bit-identical to the static-layout run for BFS *and* PageRank shapes,
     with state bit-identical for the monotone program and rounding-equal for
     the stationary one (float sums reassociate across layouts, same
-    convention as the dense-vs-mesh checks), and the executor's
-    ``relayout=True`` reproduces the static run's dist / executed tau /
+    convention as the dense-vs-mesh checks), and the executor under
+    ``EngineConfig(relayout=True)`` reproduces the static run's dist / executed tau /
     billed economics exactly while residency tracks the planned devices,
-  * engine equivalence: ``TraversalEngine(mesh=partition_mesh(D))`` produces
-    bit-identical dist and ``[S, m_max, P]`` counters vs the dense engine
+  * engine equivalence: the engine under
+    ``EngineConfig(mesh=partition_mesh(D))`` produces bit-identical dist and ``[S, m_max, P]`` counters vs the dense engine
     for D in {1, 2, 8}, on an R-MAT and an Erdos-Renyi graph -- including
     the ragged case (P=5 partitions, not divisible by any D tested),
   * cross-program equivalence on the ragged P=5 graph: weighted SSSP and
@@ -23,7 +23,7 @@ Asserts, under real 8-device execution:
     raw active-remote-edge count -- for every program,
   * windowed chaining on the mesh engine (k in {1, 8}) reproduces the
     single-launch results,
-  * executor equivalence: ``ElasticBSPExecutor(mesh=...)`` yields
+  * executor equivalence: ``ElasticBSPExecutor`` on a mesh yields
     bit-identical dist, executed tau, and ``migration_secs`` for
     D in {1, 2, 8} and window k in {1, 8} (the billed cloud migration must
     not depend on how many local devices stand in for the VMs), while the
@@ -43,6 +43,7 @@ from repro.core import TimeFunction, ffd_placement
 from repro.core.elastic import ElasticBSPExecutor
 from repro.dist.sharding import partition_mesh
 from repro.graph.bsp import run_sssp
+from repro.graph.config import EngineConfig
 from repro.graph.generators import erdos_renyi_graph, rmat_graph, weighted
 from repro.graph.partition import bfs_grow_partition
 from repro.graph.program import PageRankProgram, SsspProgram, WccProgram
@@ -69,9 +70,11 @@ graphs = {
 # -- engine equivalence ------------------------------------------------------
 for name, pg in graphs.items():
     sources = [0, 17, pg.graph.n_vertices - 1]
-    dense = get_engine(pg, m_max=M_MAX).run(sources)
+    dense = get_engine(pg, config=EngineConfig(m_max=M_MAX)).run(sources)
     for d_n in MESH_SIZES:
-        eng = get_engine(pg, m_max=M_MAX, mesh=partition_mesh(d_n))
+        eng = get_engine(
+            pg, config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(d_n)),
+        )
         res = eng.run(sources)
         for field in (
             "dist",
@@ -122,7 +125,7 @@ def check_program(name, prog, pgx, srcs, refs, *, state_exact, mesh_sizes):
     """Dense run vs numpy reference, then dense-vs-mesh equivalence: integer
     counters always bit-identical; state bit-identical for min-programs and
     rounding-tolerant for stationary sums.  Returns the dense result."""
-    dense = get_engine(pgx, program=prog, m_max=M_MAX).run(srcs)
+    dense = get_engine(pgx, program=prog, config=EngineConfig(m_max=M_MAX)).run(srcs)
     for i, ref in enumerate(refs):
         assert_state(
             dense.dist[i], ref, state_exact and ref.dtype == dense.dist.dtype,
@@ -130,7 +133,9 @@ def check_program(name, prog, pgx, srcs, refs, *, state_exact, mesh_sizes):
         )
     for d_n in mesh_sizes:
         res = get_engine(
-            pgx, program=prog, m_max=M_MAX, mesh=partition_mesh(d_n)
+            pgx,
+            program=prog,
+            config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(d_n)),
         ).run(srcs)
         for field in COUNTERS:
             np.testing.assert_array_equal(
@@ -167,7 +172,9 @@ check_program(
 
 # stationary windowed chaining on the mesh: the iteration budget and the
 # carried nst must survive window boundaries across 8 real devices
-eng = get_engine(pg5, program=pr, m_max=M_MAX, mesh=partition_mesh(8))
+eng = get_engine(
+    pg5, program=pr, config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(8)),
+)
 full = eng.run([0])
 for k in (3, 8):
     state = eng.init_state([0])
@@ -194,8 +201,8 @@ print("program pagerank: mesh windowed chaining k in (3, 8) keeps the budget")
 # -- windowed chaining on the mesh engine ------------------------------------
 pg = graphs["rmat"]
 sources = [0, 17, pg.graph.n_vertices - 1]
-dense = get_engine(pg, m_max=M_MAX).run(sources)
-eng = get_engine(pg, m_max=M_MAX, mesh=partition_mesh(8))
+dense = get_engine(pg, config=EngineConfig(m_max=M_MAX)).run(sources)
+eng = get_engine(pg, config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(8)))
 for k in WINDOWS:
     state = eng.init_state(sources)
     chunks = []
@@ -228,8 +235,12 @@ def run_with_swaps(
 ):
     """Windowed run forcing a different device_of_part each window."""
     eng = TraversalEngine(
-        pgx, program=prog, m_max=M_MAX, mesh=partition_mesh(d_n),
-        backend=backend, mirror_degree=mirror_degree,
+        pgx,
+        program=prog,
+        config=EngineConfig(
+            m_max=M_MAX, mesh=partition_mesh(d_n), backend=backend,
+            mirror_degree=mirror_degree,
+        ),
     )
     state = eng.init_state(srcs)
     chunks = []
@@ -254,7 +265,9 @@ for prog_name, prog, state_exact in (
     sources = [0] if prog.stationary else srcs
     for d_n in (2, 8):
         base = get_engine(
-            pg5, program=prog, m_max=M_MAX, mesh=partition_mesh(d_n)
+            pg5,
+            program=prog,
+            config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(d_n)),
         ).run(sources)
         swap_seq = [
             np.arange(5, dtype=np.int32) % d_n,
@@ -293,12 +306,16 @@ for prog_name, prog_ctor, pgx, b_srcs, state_exact in (
 ):
     for d_n in (2, 8):
         rx = get_engine(
-            pgx, program=prog_ctor(), m_max=M_MAX, mesh=partition_mesh(d_n),
-            backend="xla",
+            pgx,
+            program=prog_ctor(),
+            config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(d_n), backend="xla"),
         ).run(b_srcs)
         rk = get_engine(
-            pgx, program=prog_ctor(), m_max=M_MAX, mesh=partition_mesh(d_n),
-            backend="pallas-interpret",
+            pgx,
+            program=prog_ctor(),
+            config=EngineConfig(
+                m_max=M_MAX, mesh=partition_mesh(d_n), backend="pallas-interpret",
+            ),
         ).run(b_srcs)
         for field in BACKEND_COUNTERS:
             np.testing.assert_array_equal(
@@ -315,7 +332,9 @@ for prog_name, prog_ctor, pgx, b_srcs, state_exact in (
 # maps (incrementally rebuilt with the layout) must keep results identical
 for d_n in (2, 8):
     base = get_engine(
-        pg5w, program=SsspProgram(), m_max=M_MAX, mesh=partition_mesh(d_n)
+        pg5w,
+        program=SsspProgram(),
+        config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(d_n)),
     ).run(srcs)
     swap_seq = [
         np.arange(5, dtype=np.int32) % d_n,
@@ -349,7 +368,8 @@ pg_split = PartitionedGraph(
 )
 for backend in ("xla", "pallas-interpret"):
     r = get_engine(
-        pg_split, m_max=M_MAX, mesh=partition_mesh(2), backend=backend
+        pg_split,
+        config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(2), backend=backend),
     ).run([0, half])
     assert int(r.wire_msgs.sum()) == 0, (backend, int(r.wire_msgs.sum()))
     if backend == "xla":
@@ -374,11 +394,16 @@ for prog_name, prog_ctor, pgx, m_srcs, state_exact in (
 ):
     for d_n in (2, 8):
         r0 = get_engine(
-            pgx, program=prog_ctor(), m_max=M_MAX, mesh=partition_mesh(d_n)
+            pgx,
+            program=prog_ctor(),
+            config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(d_n)),
         ).run(m_srcs)
         r1 = get_engine(
-            pgx, program=prog_ctor(), m_max=M_MAX, mesh=partition_mesh(d_n),
-            mirror_degree=MIRROR_DEGREE,
+            pgx,
+            program=prog_ctor(),
+            config=EngineConfig(
+                m_max=M_MAX, mesh=partition_mesh(d_n), mirror_degree=MIRROR_DEGREE,
+            ),
         ).run(m_srcs)
         for field in COUNTERS:
             np.testing.assert_array_equal(
@@ -405,7 +430,9 @@ for prog_name, prog_ctor, pgx, m_srcs, state_exact in (
 # partition-determined, so it survives device-map changes)
 for d_n in (2, 8):
     base = get_engine(
-        pg5w, program=SsspProgram(), m_max=M_MAX, mesh=partition_mesh(d_n)
+        pg5w,
+        program=SsspProgram(),
+        config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(d_n)),
     ).run(srcs)
     swap_seq = [
         np.arange(5, dtype=np.int32) % d_n,
@@ -428,12 +455,20 @@ for d_n in (2, 8):
 # same block-map Pallas kernels; counters and state must match xla exactly
 for d_n in (2, 8):
     rx = get_engine(
-        pg5w, program=SsspProgram(), m_max=M_MAX, mesh=partition_mesh(d_n),
-        backend="xla", mirror_degree=MIRROR_DEGREE,
+        pg5w,
+        program=SsspProgram(),
+        config=EngineConfig(
+            m_max=M_MAX, mesh=partition_mesh(d_n), backend="xla",
+            mirror_degree=MIRROR_DEGREE,
+        ),
     ).run(srcs)
     rk = get_engine(
-        pg5w, program=SsspProgram(), m_max=M_MAX, mesh=partition_mesh(d_n),
-        backend="pallas-interpret", mirror_degree=MIRROR_DEGREE,
+        pg5w,
+        program=SsspProgram(),
+        config=EngineConfig(
+            m_max=M_MAX, mesh=partition_mesh(d_n), backend="pallas-interpret",
+            mirror_degree=MIRROR_DEGREE,
+        ),
     ).run(srcs)
     for field in BACKEND_COUNTERS:
         np.testing.assert_array_equal(
@@ -449,13 +484,14 @@ print("mirror backend parity: pallas-interpret==xla for D in (2, 8)")
 _, trace5 = run_sssp(graphs["rmat"], 0)
 plan5 = ffd_placement(TimeFunction.from_trace(trace5))
 mesh8 = partition_mesh(8)
-rep_s = ElasticBSPExecutor(graphs["rmat"], mesh=mesh8).run(0, plan5, window=1)
-rep_t = ElasticBSPExecutor(graphs["rmat"], mesh=mesh8).run(
-    0, plan5, window=1, relayout=True
-)
-rep_a = ElasticBSPExecutor(graphs["rmat"], mesh=mesh8).run(
-    0, plan5, window=1, relayout="auto"
-)
+cfg8 = EngineConfig(mesh=mesh8, window=1)
+rep_s = ElasticBSPExecutor(graphs["rmat"], config=cfg8).run(0, plan5)
+rep_t = ElasticBSPExecutor(
+    graphs["rmat"], config=cfg8.replace(relayout=True)
+).run(0, plan5)
+rep_a = ElasticBSPExecutor(
+    graphs["rmat"], config=cfg8.replace(relayout="auto")
+).run(0, plan5)
 assert rep_t.relayouts_skipped == 0, "relayout=True must never skip"
 np.testing.assert_array_equal(rep_a.dist, rep_s.dist)
 np.testing.assert_array_equal(rep_a.actual_tau.tau, rep_s.actual_tau.tau)
@@ -465,9 +501,11 @@ assert rep_a.relayouts <= rep_t.relayouts
 
 # force the payback bar impossibly high: every proposed swap is vetoed,
 # the skip counter records each veto, and results are still identical
-ex_never = ElasticBSPExecutor(graphs["rmat"], mesh=mesh8)
+ex_never = ElasticBSPExecutor(
+    graphs["rmat"], config=cfg8.replace(relayout="auto")
+)
 ex_never.AUTO_RELAYOUT_MIN_STEPS = 10**9
-rep_n = ex_never.run(0, plan5, window=1, relayout="auto")
+rep_n = ex_never.run(0, plan5)
 assert rep_n.relayouts == 0, "an infinite payback bar must veto every swap"
 if rep_t.relayouts:
     assert rep_n.relayouts_skipped > 0, (
@@ -488,10 +526,11 @@ for name, pg_x in graphs.items():
     swapped_any = 0
     for d_n in (2, 8):
         mesh = partition_mesh(d_n)
-        rep_s = ElasticBSPExecutor(pg_x, mesh=mesh).run(0, plan, window=1)
-        rep_d = ElasticBSPExecutor(pg_x, mesh=mesh).run(
-            0, plan, window=1, relayout=True
-        )
+        cfg = EngineConfig(mesh=mesh, window=1)
+        rep_s = ElasticBSPExecutor(pg_x, config=cfg).run(0, plan)
+        rep_d = ElasticBSPExecutor(
+            pg_x, config=cfg.replace(relayout=True)
+        ).run(0, plan)
         np.testing.assert_array_equal(rep_d.dist, rep_s.dist)
         np.testing.assert_array_equal(rep_d.actual_tau.tau, rep_s.actual_tau.tau)
         assert rep_d.cost.migration_secs == rep_s.cost.migration_secs
@@ -518,8 +557,10 @@ for name, pg in graphs.items():
     base = {}
     for k in WINDOWS:
         for d_n in MESH_SIZES:
-            ex = ElasticBSPExecutor(pg, mesh=partition_mesh(d_n))
-            rep = ex.run(0, plan, window=k)
+            ex = ElasticBSPExecutor(
+                pg, config=EngineConfig(mesh=partition_mesh(d_n), window=k),
+            )
+            rep = ex.run(0, plan)
             if k not in base:
                 base[k] = rep
             ref = base[k]
@@ -555,7 +596,7 @@ for name, pg in graphs.items():
 # unchanged), and the continued traversal must land exactly on the mutated
 # graph's fixpoint (the inserted 0.5-weight shortcuts change it, so the
 # reactivation path is what makes this pass).
-from repro.graph import EngineConfig, open_session
+from repro.graph import open_session
 from repro.graph.deltas import EdgeDeltaBuffer, apply_delta_buffer
 
 rng_d = np.random.default_rng(21)
@@ -598,7 +639,9 @@ for d_n in (2, 8):
         err_msg=f"delta merge D={d_n}: continued run != mutated fixpoint",
     )
     # the shortcuts must actually matter, or the reactivation is untested
-    base5 = get_engine(pg5w, m_max=M_MAX, mesh=partition_mesh(d_n)).run(srcs)
+    base5 = get_engine(
+        pg5w, config=EngineConfig(m_max=M_MAX, mesh=partition_mesh(d_n)),
+    ).run(srcs)
     assert not np.array_equal(np.asarray(fresh.dist), np.asarray(base5.dist)), (
         f"delta merge D={d_n}: inserted shortcuts changed nothing"
     )

@@ -15,8 +15,9 @@ The load-bearing invariants:
     monotone traversal lands exactly on the mutated graph's fixpoint,
   * the elastic executor and the serving layer interleave mutations with
     traffic and record them in their reports,
-  * the legacy engine kwargs keep working behind ``DeprecationWarning``
-    shims and produce results identical to the ``EngineConfig`` path,
+  * ``EngineConfig`` is the one spelling of every engine knob: equal
+    configs share a cached engine, and the bare knob keywords are unknown
+    to every entry point,
   * every report type shares the schema-versioned ``asdict()`` surface.
 """
 
@@ -344,41 +345,58 @@ def test_service_interleaves_mutations_with_queries():
     assert d["mutations_applied"] == 1
 
 
-# -- the unified config surface: shims warn, results match --------------------
+# -- the unified config surface: one spelling, cached by value --------------
 
 
-def test_legacy_kwargs_warn_and_match_config_path():
+def test_engine_cache_keys_on_config():
+    """Equal ``EngineConfig`` values resolve to one cached engine with equal
+    results; a config differing in a keyed knob builds a new engine; no
+    config means ``EngineConfig()``."""
     pg = _ragged_pg()
-    with pytest.deprecated_call():
-        legacy = get_engine(pg, m_max=64)
-    cfg_engine = get_engine(pg, config=EngineConfig(m_max=64))
-    assert legacy is cfg_engine, "shim must resolve to the same cached engine"
+    first = get_engine(pg, config=EngineConfig(m_max=64))
+    assert get_engine(pg, config=EngineConfig(m_max=64)) is first
+    assert get_engine(pg, config=EngineConfig(m_max=32)) is not first
+    assert get_engine(pg) is get_engine(pg, config=EngineConfig())
 
-    with pytest.deprecated_call():
-        res_l = get_engine(pg, program=SsspProgram(), m_max=64).run([0])
-    res_c = get_engine(
+    res_a = get_engine(
         pg, program=SsspProgram(), config=EngineConfig(m_max=64)
     ).run([0])
-    np.testing.assert_array_equal(res_l.dist, res_c.dist)
+    res_b = get_engine(
+        pg, program=SsspProgram(), config=EngineConfig(m_max=64)
+    ).run([0])
+    np.testing.assert_array_equal(res_a.dist, res_b.dist)
 
+
+def _engine_entry_call(entry: str, pg):
+    """A call of one engine entry point with a bare knob keyword."""
     from repro.core.elastic import ElasticBSPExecutor
-
-    with pytest.deprecated_call():
-        ElasticBSPExecutor(pg, backend="xla")
-
+    from repro.graph.traversal import TraversalEngine
     from repro.serve import ServiceConfig, TraversalService
 
-    with pytest.deprecated_call():
-        TraversalService(pg, config=ServiceConfig(), backend="xla")
+    if entry == "TraversalEngine":
+        return lambda: TraversalEngine(pg, m_max=64)
+    if entry == "get_engine":
+        return lambda: get_engine(pg, backend="xla")
+    if entry == "ElasticBSPExecutor":
+        return lambda: ElasticBSPExecutor(pg, mirror_degree=2)
+    if entry == "ElasticBSPExecutor.run":
+        ex = ElasticBSPExecutor(pg, config=EngineConfig(m_max=64))
+        return lambda: ex.run(0, None, window=2)
+    assert entry == "TraversalService"
+    return lambda: TraversalService(pg, config=ServiceConfig(), mesh=None)
 
-    # the config path itself must stay warning-free
-    import warnings
 
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        get_engine(pg, config=EngineConfig(m_max=64))
-        ElasticBSPExecutor(pg, config=EngineConfig())
-        TraversalService(pg, config=ServiceConfig())
+@pytest.mark.parametrize(
+    "entry",
+    ["TraversalEngine", "get_engine", "ElasticBSPExecutor",
+     "ElasticBSPExecutor.run", "TraversalService"],
+)
+def test_bare_engine_knob_is_unknown_keyword(entry):
+    """Knobs are set through ``EngineConfig`` only: a bare knob keyword is
+    a ``TypeError`` at every entry point."""
+    call = _engine_entry_call(entry, _ragged_pg())
+    with pytest.raises(TypeError, match="unexpected keyword"):
+        call()
 
 
 def test_traversal_result_asdict_schema():

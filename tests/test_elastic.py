@@ -9,6 +9,7 @@ from repro.core import TimeFunction, ffd_placement, mfp_placement, default_place
 from repro.core.elastic import ElasticBSPExecutor
 from repro.graph import bfs_grow_partition, erdos_renyi_graph, road_grid_graph
 from repro.graph.bsp import run_sssp
+from repro.graph.config import EngineConfig
 from repro.graph.traversal import reference_bfs
 
 
@@ -73,11 +74,10 @@ def test_single_divergence_triggers_exactly_one_replan():
     wrong_source, real_source = 7, 200
     assert pg.part_of_vertex[wrong_source] != pg.part_of_vertex[real_source]
     plan, _ = _plan_from_trace(pg, wrong_source, ffd_placement)
-    ex = ElasticBSPExecutor(pg)
     for window in (1, 4):
+        ex = ElasticBSPExecutor(pg, config=EngineConfig(window=window))
         rep = ex.run(
             real_source, plan, strategy_fn=ffd_placement, replan=True,
-            window=window,
         )
         np.testing.assert_allclose(rep.dist, reference_bfs(pg, real_source))
         assert rep.replans == 1, f"window={window}: {rep.replans} replans"
@@ -92,11 +92,10 @@ def test_windowed_execution_matches_per_superstep_path(seed, n_parts):
     g = erdos_renyi_graph(300, 5.0, seed=seed)
     pg = bfs_grow_partition(g, n_parts, seed=1)
     plan, tf = _plan_from_trace(pg, 0, ffd_placement)
-    ex = ElasticBSPExecutor(pg)
-    base = ex.run(0, plan, window=1)
+    base = ElasticBSPExecutor(pg, config=EngineConfig(window=1)).run(0, plan)
     np.testing.assert_allclose(base.actual_tau.tau, tf.tau)
     for k in (4, 16):
-        rep = ex.run(0, plan, window=k)
+        rep = ElasticBSPExecutor(pg, config=EngineConfig(window=k)).run(0, plan)
         np.testing.assert_array_equal(rep.dist, base.dist)
         np.testing.assert_array_equal(rep.actual_tau.tau, base.actual_tau.tau)
         assert rep.n_supersteps == base.n_supersteps
@@ -107,8 +106,8 @@ def test_windowed_host_sync_budget():
     g = road_grid_graph(25, 25, seed=2)  # long-diameter graph, many supersteps
     pg = bfs_grow_partition(g, 6, seed=3)
     plan, tf = _plan_from_trace(pg, 0, ffd_placement)
-    ex = ElasticBSPExecutor(pg)
-    rep = ex.run(0, plan, window=8)
+    ex = ElasticBSPExecutor(pg, config=EngineConfig(window=8))
+    rep = ex.run(0, plan)
     assert rep.n_supersteps == tf.n_supersteps
     assert rep.host_syncs <= math.ceil(rep.n_supersteps / 8) + 1
 
@@ -159,9 +158,9 @@ def test_moves_scheduled_past_convergence_are_not_counted():
         tau=np.vstack([plan.tau, np.zeros((8, pg.n_parts))]),
         vm_of=np.vstack([plan.vm_of, extra_vm]),
     )
-    ex = ElasticBSPExecutor(pg)
-    base = ex.run(0, plan, window=16)
-    rep = ex.run(0, padded, window=16)  # whole run + tail in one window
+    ex = ElasticBSPExecutor(pg, config=EngineConfig(window=16))
+    base = ex.run(0, plan)
+    rep = ex.run(0, padded)  # whole run + tail in one window
     assert rep.n_supersteps == base.n_supersteps
     assert rep.n_migrations == base.n_migrations
     assert rep.migration_bytes == base.migration_bytes
@@ -177,9 +176,10 @@ def test_relayout_is_noop_on_the_dense_path():
     pg = bfs_grow_partition(g, 4, seed=1)
     _, trace = run_sssp(pg, 0)
     plan = ffd_placement(TimeFunction.from_trace(trace))
-    ex = ElasticBSPExecutor(pg)
-    base = ex.run(0, plan, window=2)
-    rep = ex.run(0, plan, window=2, relayout=True)
+    base = ElasticBSPExecutor(pg, config=EngineConfig(window=2)).run(0, plan)
+    rep = ElasticBSPExecutor(
+        pg, config=EngineConfig(window=2, relayout=True)
+    ).run(0, plan)
     np.testing.assert_array_equal(rep.dist, base.dist)
     np.testing.assert_array_equal(rep.actual_tau.tau, base.actual_tau.tau)
     assert rep.relayouts == 0

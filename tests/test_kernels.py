@@ -7,9 +7,12 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.bfs_relax import bfs_relax, reference_bfs_relax
+from repro.graph.structs import dst_sorted_layout
+from repro.kernels.bfs_relax import reference_bfs_relax
 from repro.kernels.flash_attention import flash_attention, reference_attention
 from repro.kernels.segment_sum import reference_segment_sum, sorted_segment_sum
+
+from _relax_entry import engine_relax
 
 KEY = jax.random.PRNGKey(0)
 
@@ -138,7 +141,8 @@ def test_bfs_relax_vs_oracle(case):
         np.where(rng.random(n) < 0.5, rng.uniform(0, 10, n), np.inf), jnp.float32
     )
     frontier = jnp.asarray(rng.random(n) < frontier_frac)
-    out = bfs_relax(dist, frontier, src, dst, w, interpret=True)
+    layout = dst_sorted_layout(n, *map(np.asarray, (src, dst, w)))
+    out = engine_relax(dist, frontier, layout)
     ref = reference_bfs_relax(dist, frontier, src, dst, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6, rtol=1e-6)
 
@@ -150,14 +154,12 @@ def test_bfs_relax_full_traversal_matches_engine():
     from repro.graph.partition import hash_partition
 
     g = weighted(erdos_renyi_graph(300, 5.0, seed=3), seed=1)
-    src = jnp.asarray(g.src)
-    dst = jnp.asarray(g.dst)
-    w = jnp.asarray(g.edge_weights)
     n = g.n_vertices
+    layout = dst_sorted_layout(n, g.src, g.dst, g.edge_weights)
     dist = jnp.full((n,), jnp.inf).at[0].set(0.0)
     frontier = jnp.zeros((n,), bool).at[0].set(True)
     for _ in range(n):
-        new = bfs_relax(dist, frontier, src, dst, w, interpret=True)
+        new = engine_relax(dist, frontier, layout)
         frontier = new < dist
         if not bool(frontier.any()):
             break
@@ -170,14 +172,11 @@ def test_bfs_relax_empty_frontier_is_identity():
     n, e = 128, 512
     rng = np.random.default_rng(0)
     dist = jnp.asarray(rng.uniform(0, 5, n), jnp.float32)
-    out = bfs_relax(
-        dist,
-        jnp.zeros((n,), bool),
-        jnp.asarray(rng.integers(0, n, e), jnp.int32),
-        jnp.asarray(rng.integers(0, n, e), jnp.int32),
-        jnp.ones((e,), jnp.float32),
-        interpret=True,
+    layout = dst_sorted_layout(
+        n, rng.integers(0, n, e).astype(np.int32),
+        rng.integers(0, n, e).astype(np.int32), np.ones(e, np.float32),
     )
+    out = engine_relax(dist, jnp.zeros((n,), bool), layout)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(dist))
 
 
@@ -197,7 +196,6 @@ def _program_pg(n=200, avg_deg=4.0, seed=7):
 def _program_layout(pg, prog):
     """dst-sorted layout carrying the program's edge plane as weights."""
     from repro.graph.program import resolve_edge_plane
-    from repro.graph.structs import dst_sorted_layout
 
     g = pg.graph
     plane = resolve_edge_plane(pg, prog)
@@ -206,7 +204,7 @@ def _program_layout(pg, prog):
 
 
 @pytest.mark.parametrize("name", ["bfs", "sssp", "wcc", "pagerank"])
-def test_relax_csr_matches_xla_per_program(name):
+def test_relax_fn_matches_xla_per_program(name):
     """One relax pass: kernel (interpret) vs the engine's XLA segment ops,
     exact for min programs (WCC's int32 labels included), allclose for the
     float sum path."""
@@ -215,7 +213,6 @@ def test_relax_csr_matches_xla_per_program(name):
     prog = BUILTIN_PROGRAMS[name]()
     pg = _program_pg()
     lay = _program_layout(pg, prog)
-    from repro.kernels.bfs_relax import relax_csr
 
     rng = np.random.default_rng(42)
     n = pg.graph.n_vertices
@@ -228,7 +225,7 @@ def test_relax_csr_matches_xla_per_program(name):
         )
         frontier0 = rng.random(frontier0.shape) < 0.4
     frontier = jnp.asarray(frontier0)
-    out = relax_csr(prog, state, frontier, lay, interpret=True)
+    out = engine_relax(state, frontier, lay, program=prog)
 
     src, dst, w = map(jnp.asarray, (lay.src, lay.dst, lay.weights))
     ident = prog.identity
@@ -288,10 +285,8 @@ def test_block_dims_degenerate():
         assert n_pad >= n and e_pad >= e, (n, e)
 
 
-def test_relax_csr_single_edge_graph():
+def test_relax_fn_single_edge_graph():
     from repro.graph.program import SsspProgram
-    from repro.graph.structs import dst_sorted_layout
-    from repro.kernels.bfs_relax import relax_csr
 
     prog = SsspProgram()
     lay = dst_sorted_layout(
@@ -300,19 +295,18 @@ def test_relax_csr_single_edge_graph():
     )
     state = jnp.asarray([[0.0, np.inf, np.inf]], jnp.float32)
     frontier = jnp.asarray([[True, False, False]])
-    out = relax_csr(prog, state, frontier, lay, interpret=True)
+    out = engine_relax(state, frontier, lay, program=prog)
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray([[0.0, np.inf, 1.5]], np.float32)
     )
 
 
-def test_relax_csr_empty_edge_set_and_frontier():
+def test_relax_fn_empty_edge_set_and_frontier():
     """e == 0 returns the combine identity without launching a kernel; an
     empty frontier feeds all-identity candidates and must be a no-op for
     min programs."""
     from repro.graph.program import PageRankProgram, SsspProgram
-    from repro.graph.structs import dst_sorted_layout
-    from repro.kernels.bfs_relax import make_relax_fn, relax_csr
+    from repro.kernels.bfs_relax import make_relax_fn
 
     empty = dst_sorted_layout(
         4, np.zeros(0, np.int32), np.zeros(0, np.int32), np.zeros(0, np.float32)
@@ -320,12 +314,12 @@ def test_relax_csr_empty_edge_set_and_frontier():
     state = jnp.asarray([[1.0, 2.0, 3.0, 4.0]], jnp.float32)
     fr = jnp.ones((1, 4), bool)
     np.testing.assert_array_equal(
-        np.asarray(relax_csr(SsspProgram(), state, fr, empty, interpret=True)),
+        np.asarray(engine_relax(state, fr, empty, program=SsspProgram())),
         np.asarray(state),
     )
     np.testing.assert_array_equal(
         np.asarray(
-            relax_csr(PageRankProgram(), state, fr, empty, interpret=True)
+            engine_relax(state, fr, empty, program=PageRankProgram())
         ),
         np.zeros((1, 4), np.float32),
     )
@@ -341,7 +335,7 @@ def test_relax_csr_empty_edge_set_and_frontier():
         4, np.array([0, 1], np.int32), np.array([1, 2], np.int32),
         np.ones(2, np.float32),
     )
-    out = relax_csr(
-        SsspProgram(), state, jnp.zeros((1, 4), bool), lay, interpret=True
+    out = engine_relax(
+        state, jnp.zeros((1, 4), bool), lay, program=SsspProgram()
     )
     np.testing.assert_array_equal(np.asarray(out), np.asarray(state))

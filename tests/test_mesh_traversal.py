@@ -17,6 +17,7 @@ import pytest
 
 from repro.core.placement import Placement
 from repro.dist.sharding import partition_mesh
+from repro.graph.config import EngineConfig
 from repro.graph.generators import erdos_renyi_graph
 from repro.graph.mesh_exchange import relayout_rows, relayout_state
 from repro.graph.partition import (
@@ -428,9 +429,9 @@ def test_one_device_mesh_falls_back_to_dense_path():
     g = erdos_renyi_graph(250, 4.0, seed=5)
     pg = bfs_grow_partition(g, 4, seed=3)
     mesh = partition_mesh(1)
-    eng = TraversalEngine(pg, m_max=64, mesh=mesh)
+    eng = TraversalEngine(pg, config=EngineConfig(m_max=64, mesh=mesh))
     assert eng._mesh_prog is None  # dense program serves 1-device meshes
-    dense = get_engine(pg, m_max=64).run([0, 11])
+    dense = get_engine(pg, config=EngineConfig(m_max=64)).run([0, 11])
     res = eng.run([0, 11])
     np.testing.assert_array_equal(res.dist, dense.dist)
     np.testing.assert_array_equal(res.edges_examined, dense.edges_examined)
@@ -450,7 +451,9 @@ def test_mesh_rejects_collect_subgraphs():
         devices = np.empty((2,), dtype=object)
 
     with pytest.raises(NotImplementedError, match="single-device"):
-        TraversalEngine(pg, mesh=_FakeMesh(), collect_subgraphs=True)
+        TraversalEngine(
+            pg, config=EngineConfig(mesh=_FakeMesh(), collect_subgraphs=True),
+        )
 
 
 # -- real multi-device execution ---------------------------------------------

@@ -15,6 +15,7 @@ from repro.core.placement import ffd_placement
 from repro.core.replan import ReplanConfig
 from repro.core.timing import TimeFunction
 from repro.graph.bsp import run_program
+from repro.graph.config import EngineConfig
 from repro.graph.generators import erdos_renyi_graph, weighted
 from repro.graph.partition import bfs_grow_partition
 from repro.graph.program import (
@@ -67,7 +68,9 @@ def test_bfs_program_ignores_weights(pg_weighted):
     """BfsProgram must produce hop counts even on a weighted graph (the unit
     edge plane overrides the graph weights)."""
     sources = [0, 17, 123]
-    res = get_engine(pg_weighted, program=BfsProgram(), m_max=256).run(sources)
+    res = get_engine(
+        pg_weighted, program=BfsProgram(), config=EngineConfig(m_max=256),
+    ).run(sources)
     for i, s in enumerate(sources):
         np.testing.assert_array_equal(
             res.dist[i], reference_bfs(pg_weighted, s).astype(np.float32)
@@ -77,7 +80,7 @@ def test_bfs_program_ignores_weights(pg_weighted):
 def test_sssp_program_matches_weighted_oracle(pg_weighted):
     sources = [1, 42, 200]
     res = get_engine(
-        pg_weighted, program=SsspProgram(), m_max=256
+        pg_weighted, program=SsspProgram(), config=EngineConfig(m_max=256),
     ).run(sources)
     for i, s in enumerate(sources):
         np.testing.assert_allclose(
@@ -87,7 +90,9 @@ def test_sssp_program_matches_weighted_oracle(pg_weighted):
 
 def test_wcc_program_labels_components(pg_two_components):
     pg = pg_two_components
-    res = get_engine(pg, program=WccProgram(), m_max=256).run([0])
+    res = get_engine(
+        pg, program=WccProgram(), config=EngineConfig(m_max=256),
+    ).run([0])
     labels = res.dist[0]
     assert labels.dtype == np.int32  # the program's state spec, not float
     np.testing.assert_array_equal(labels, reference_wcc(pg).astype(np.int32))
@@ -97,7 +102,9 @@ def test_wcc_program_labels_components(pg_two_components):
 
 def test_pagerank_program_matches_power_iteration(pg_unweighted):
     prog = PageRankProgram(damping=0.85, num_iters=18)
-    res = get_engine(pg_unweighted, program=prog, m_max=64).run([0])
+    res = get_engine(
+        pg_unweighted, program=prog, config=EngineConfig(m_max=64),
+    ).run([0])
     ref = reference_pagerank(pg_unweighted, 0.85, 18)
     np.testing.assert_allclose(res.dist[0], ref, rtol=1e-5, atol=1e-9)
     assert abs(float(res.dist[0].sum()) - 1.0) < 1e-4
@@ -113,9 +120,9 @@ def test_bfs_through_program_api_bit_identical_to_default(pg_unweighted):
     weights == the pre-algebra engine) and the explicit BfsProgram must agree
     bit-for-bit in state AND every [S, m_max, P] counter buffer."""
     sources = [0, 17, 123, 259]
-    r_def = get_engine(pg_unweighted, m_max=256).run(sources)
+    r_def = get_engine(pg_unweighted, config=EngineConfig(m_max=256)).run(sources)
     r_bfs = get_engine(
-        pg_unweighted, program=BfsProgram(), m_max=256
+        pg_unweighted, program=BfsProgram(), config=EngineConfig(m_max=256),
     ).run(sources)
     for field in (
         "dist", "n_supersteps", "edges_examined", "verts_processed",
@@ -134,7 +141,7 @@ def test_run_window_chaining_matches_run(pg_unweighted, make_prog):
     """Chained run_window must reproduce run() for monotone source-free AND
     stationary programs (the budget must survive window boundaries)."""
     prog = make_prog()
-    eng = get_engine(pg_unweighted, program=prog, m_max=64)
+    eng = get_engine(pg_unweighted, program=prog, config=EngineConfig(m_max=64))
     full = eng.run([0])
     for k in (1, 3, 7):
         state = eng.init_state([0])
@@ -163,9 +170,9 @@ def test_executor_runs_wcc(pg_two_components):
     prog = WccProgram()
     _, traces = run_program(pg, prog, [0], max_supersteps=256)
     plan = ffd_placement(TimeFunction.from_trace(traces[0]))
-    rep = ElasticBSPExecutor(pg, program=prog).run(
-        0, plan, window=4, max_supersteps=256
-    )
+    rep = ElasticBSPExecutor(
+        pg, program=prog, config=EngineConfig(window=4)
+    ).run(0, plan, max_supersteps=256)
     np.testing.assert_array_equal(rep.dist, reference_wcc(pg).astype(np.int32))
     # WCC starts everywhere: superstep 0 must have every partition active
     assert traces[0].active[0].all()
@@ -182,9 +189,10 @@ def test_executor_runs_pagerank_and_profile_is_stationary(pg_unweighted):
     assert trace.n_supersteps == 11
     assert trace.active.all()  # stationary: flat activity profile
     plan = ffd_placement(TimeFunction.from_trace(trace))
-    rep = ElasticBSPExecutor(pg, program=prog).run(
-        0, plan, strategy_fn=ffd_placement, replan=True, window=4,
-        max_supersteps=64,
+    rep = ElasticBSPExecutor(
+        pg, program=prog, config=EngineConfig(window=4)
+    ).run(
+        0, plan, strategy_fn=ffd_placement, replan=True, max_supersteps=64,
     )
     np.testing.assert_allclose(
         rep.dist, reference_pagerank(pg, 0.85, 11), rtol=1e-5, atol=1e-9
@@ -248,15 +256,26 @@ def test_validate_program_rejects_bad_specs():
 def test_builtin_registry_and_engine_cache(pg_unweighted):
     assert set(BUILTIN_PROGRAMS) == {"bfs", "sssp", "wcc", "pagerank"}
     # equal program keys share one cached engine; distinct keys do not
-    e1 = get_engine(pg_unweighted, program=SsspProgram(), m_max=64)
-    e2 = get_engine(pg_unweighted, program=SsspProgram(), m_max=64)
-    e3 = get_engine(pg_unweighted, m_max=64)  # default is SsspProgram
+    e1 = get_engine(
+        pg_unweighted, program=SsspProgram(), config=EngineConfig(m_max=64),
+    )
+    e2 = get_engine(
+        pg_unweighted, program=SsspProgram(), config=EngineConfig(m_max=64),
+    )
+    # the default program is SsspProgram
+    e3 = get_engine(pg_unweighted, config=EngineConfig(m_max=64))
     assert e1 is e2 is e3
-    assert get_engine(pg_unweighted, program=BfsProgram(), m_max=64) is not e1
     assert get_engine(
-        pg_unweighted, program=PageRankProgram(num_iters=3), m_max=64
+        pg_unweighted, program=BfsProgram(), config=EngineConfig(m_max=64),
+    ) is not e1
+    assert get_engine(
+        pg_unweighted,
+        program=PageRankProgram(num_iters=3),
+        config=EngineConfig(m_max=64),
     ) is not get_engine(
-        pg_unweighted, program=PageRankProgram(num_iters=4), m_max=64
+        pg_unweighted,
+        program=PageRankProgram(num_iters=4),
+        config=EngineConfig(m_max=64),
     )
 
 

@@ -1,9 +1,9 @@
 """Device-resident traversal engine + static-CSR relax kernel regression.
 
-Sweeps the block-skipping kernel (interpret mode) against the pure-jnp
-oracle and the engine against the host Bellman-Ford oracle, on random ragged
-sizes -- including the ``presorted=True`` legacy path and n/e odd with
-respect to the block sizes.
+Sweeps the block-skipping kernel (interpret mode, through the dense engine's
+``make_relax_fn`` entry) against the pure-jnp oracle and the engine against
+the host Bellman-Ford oracle, on random ragged sizes -- n/e odd with respect
+to the block sizes.
 """
 
 import jax
@@ -28,9 +28,11 @@ from repro.graph.traversal import (
     reference_sssp,
     reference_wcc,
 )
-from repro.kernels.bfs_relax import bfs_relax, bfs_relax_csr, reference_bfs_relax
+from repro.kernels.bfs_relax import reference_bfs_relax
 
-RAGGED_CASES = [
+from _relax_entry import engine_relax
+
+RAGGED_SIZES = [
     # (n, e) deliberately not multiples of the 64-block sizes used below
     (100, 300),
     (257, 1023),
@@ -38,6 +40,10 @@ RAGGED_CASES = [
     (1000, 333),
     (65, 65),
     (7, 5),
+]
+#: (n, e, seed): every size under one seed, the first four under a second
+RAGGED_CASES = [(n, e, n * 31 + e) for n, e in RAGGED_SIZES] + [
+    (n, e, e * 17 + n) for n, e in RAGGED_SIZES[:4]
 ]
 
 
@@ -54,13 +60,13 @@ def _random_relax_inputs(n, e, seed):
 
 
 @pytest.mark.parametrize("case", RAGGED_CASES)
-def test_bfs_relax_csr_matches_reference_ragged(case):
-    n, e = case
-    src, dst, w, dist, frontier = _random_relax_inputs(n, e, seed=n * 31 + e)
+def test_relax_fn_matches_reference_ragged(case):
+    n, e, seed = case
+    src, dst, w, dist, frontier = _random_relax_inputs(n, e, seed=seed)
     layout = dst_sorted_layout(n, src, dst, w)
-    out = bfs_relax_csr(
+    out = engine_relax(
         jnp.asarray(dist), jnp.asarray(frontier), layout,
-        block_n=64, block_e=64, interpret=True,
+        block_n=64, block_e=64,
     )
     ref = reference_bfs_relax(
         jnp.asarray(dist), jnp.asarray(frontier),
@@ -69,26 +75,7 @@ def test_bfs_relax_csr_matches_reference_ragged(case):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-@pytest.mark.parametrize("case", RAGGED_CASES[:4])
-def test_bfs_relax_presorted_path_matches_reference(case):
-    """The legacy wrapper's presorted=True path must skip the argsort and
-    still be exact when fed the static layout's edge order."""
-    n, e = case
-    src, dst, w, dist, frontier = _random_relax_inputs(n, e, seed=e * 17 + n)
-    layout = dst_sorted_layout(n, src, dst, w)
-    out = bfs_relax(
-        jnp.asarray(dist), jnp.asarray(frontier),
-        jnp.asarray(layout.src), jnp.asarray(layout.dst), jnp.asarray(layout.weights),
-        block_n=64, block_e=64, interpret=True, presorted=True,
-    )
-    ref = reference_bfs_relax(
-        jnp.asarray(dist), jnp.asarray(frontier),
-        jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w),
-    )
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_bfs_relax_csr_batched_matches_per_source():
+def test_relax_fn_batched_matches_per_source():
     n, e, s_batch = 203, 611, 5
     rng = np.random.default_rng(3)
     src = rng.integers(0, n, e).astype(np.int32)
@@ -100,7 +87,7 @@ def test_bfs_relax_csr_batched_matches_per_source():
         jnp.float32,
     )
     frontier = jnp.asarray(rng.random((s_batch, n)) < 0.3)
-    out = bfs_relax_csr(dist, frontier, layout, block_n=64, block_e=64, interpret=True)
+    out = engine_relax(dist, frontier, layout, block_n=64, block_e=64)
     for s in range(s_batch):
         ref = reference_bfs_relax(
             dist[s], frontier[s], jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w)
@@ -115,7 +102,7 @@ def test_batched_engine_bitmatches_oracle_every_source(partitioner):
     g = erdos_renyi_graph(300, 5.0, seed=11)
     pg = partitioner(g, 4)
     sources = [0, 17, 123, 299]
-    res = get_engine(pg, m_max=256).run(sources)
+    res = get_engine(pg, config=EngineConfig(m_max=256)).run(sources)
     for i, s in enumerate(sources):
         ref = reference_bfs(pg, s)
         np.testing.assert_array_equal(res.dist[i], ref.astype(np.float32))
@@ -125,7 +112,7 @@ def test_batched_engine_weighted_matches_oracle():
     g = weighted(erdos_renyi_graph(250, 5.0, seed=13), seed=2)
     pg = bfs_grow_partition(g, 4, seed=3)
     sources = [1, 42, 200]
-    res = get_engine(pg, m_max=256).run(sources)
+    res = get_engine(pg, config=EngineConfig(m_max=256)).run(sources)
     for i, s in enumerate(sources):
         np.testing.assert_allclose(
             res.dist[i], reference_sssp(pg, s), rtol=1e-6
@@ -183,7 +170,7 @@ def test_engine_raises_on_superstep_cap():
     g = erdos_renyi_graph(200, 4.0, seed=21)
     pg = hash_partition(g, 4)
     with pytest.raises(RuntimeError, match="did not converge"):
-        get_engine(pg, m_max=2).run([0])
+        get_engine(pg, config=EngineConfig(m_max=2)).run([0])
 
 
 def test_non_convergence_error_reports_steps_and_keeps_partial_result():
@@ -192,7 +179,7 @@ def test_non_convergence_error_reports_steps_and_keeps_partial_result():
     g = erdos_renyi_graph(200, 4.0, seed=21)
     pg = hash_partition(g, 4)
     with pytest.raises(TraversalNotConverged, match=r"n_supersteps=\[2\]") as ei:
-        get_engine(pg, m_max=2).run([0])
+        get_engine(pg, config=EngineConfig(m_max=2)).run([0])
     partial = ei.value.result
     assert np.array_equal(partial.n_supersteps, [2])
     # two supersteps of real progress are retained
@@ -205,7 +192,7 @@ def test_run_window_chaining_matches_single_run():
     and superstep counts exactly, for several window sizes."""
     g = erdos_renyi_graph(300, 5.0, seed=11)
     pg = bfs_grow_partition(g, 4, seed=1)
-    eng = get_engine(pg, m_max=256)
+    eng = get_engine(pg, config=EngineConfig(m_max=256))
     sources = [0, 17, 123]
     full = eng.run(sources)
     for k in (1, 3, 7, 64):
@@ -234,7 +221,7 @@ def test_run_window_reports_next_active_partitions():
     vertices (what the elastic executor's placement decision consumes)."""
     g = erdos_renyi_graph(250, 4.0, seed=3)
     pg = bfs_grow_partition(g, 5, seed=2)
-    eng = get_engine(pg, m_max=256)
+    eng = get_engine(pg, config=EngineConfig(m_max=256))
     state = eng.init_state([0])
     w = eng.run_window(state, 1)
     frontier = np.asarray(w.state.frontier[0])
@@ -273,9 +260,13 @@ def test_dense_engine_backend_parity(name):
     pg = bfs_grow_partition(g, 4)
     srcs = [0, 100]
     ctor = BUILTIN_PROGRAMS[name]
-    rx = get_engine(pg, program=ctor(), m_max=64, backend="xla").run(srcs)
+    rx = get_engine(
+        pg, program=ctor(), config=EngineConfig(m_max=64, backend="xla"),
+    ).run(srcs)
     rk = get_engine(
-        pg, program=ctor(), m_max=64, backend="pallas-interpret"
+        pg,
+        program=ctor(),
+        config=EngineConfig(m_max=64, backend="pallas-interpret"),
     ).run(srcs)
     for f in ("edges_examined", "verts_processed", "msgs_sent",
               "inner_iters", "wire_msgs", "n_supersteps"):
@@ -293,7 +284,7 @@ def test_dense_engine_backend_parity(name):
 def test_engine_rejects_unknown_backend():
     pg = hash_partition(erdos_renyi_graph(50, 3.0, seed=0), 2)
     with pytest.raises(ValueError, match="backend"):
-        get_engine(pg, backend="cuda")
+        get_engine(pg, config=EngineConfig(backend="cuda"))
 
 
 def _graph_with_duplicates_and_isolated(seed: int = 4) -> Graph:
@@ -469,7 +460,9 @@ def test_sssp_on_unit_interval_weights_with_zeros(partitioner):
     sources = [0, 17, 123, 299]
     runs = {
         backend: get_engine(
-            pg, program=SsspProgram(), m_max=256, backend=backend
+            pg,
+            program=SsspProgram(),
+            config=EngineConfig(m_max=256, backend=backend),
         ).run(sources)
         for backend in ("xla", "pallas-interpret")
     }
