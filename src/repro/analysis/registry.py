@@ -44,9 +44,9 @@ TRACED_FUNCTIONS = (
         "_body",
         (
             "dist", "frontier", "nst0",
-            "lsrc", "ldst", "lw", "lpart", "lvalid", "part_of_pos",
-            "rsrc", "rw", "rslot", "rpart", "rvalid", "recv_idx",
-            "msrc", "mw", "mslot", "mpart", "mvalid", "mrecv_idx",
+            "lsrc", "ldst", "lw", "ldeg", "lvalid", "part_of_pos",
+            "rsrc", "rw", "rslot", "rdeg", "rvalid", "recv_idx",
+            "msrc", "mw", "mslot", "mdeg", "mvalid", "mrecv_idx",
         ),
         "shard_map body; keyword-only params are static",
     ),

@@ -29,7 +29,15 @@ unit weights, bit-identical to the pre-algebra program):
     the program's ``identity`` and are no-ops by construction.
   * **counters**: each device accumulates the ``[S, k, P]`` work counters for
     its own partitions only (partitions never span devices), so one ``psum``
-    per window reconstructs the exact global integers.  ``wire_msgs`` counts
+    per window reconstructs the exact global integers.  They come from
+    per-vertex degrees, not from the per-arc frontier: every arc counts
+    toward its source's partition, so the arcs a frontier examines, by
+    partition, are ``part_sum(where(frontier, deg, 0))`` -- one dense masked
+    sum of ``[S, n_pad]`` over the vertex axis against ``part_of_pos``, with
+    no scatter into partition bins.  ``build_window_consts`` derives the
+    ``[D, n_pad]`` degrees (``ldeg``/``rdeg``/``mdeg``: each device's valid
+    local, remote and mirror arcs out of each padded position, 0 on padding)
+    from whatever layout is active.  ``wire_msgs`` counts
     the non-identity slots actually put on the collective per superstep (for
     sum programs: slots fed by at least one active edge) -- the
     post-aggregation message volume the bench compares against the raw
@@ -185,6 +193,14 @@ def plane_shards(pg: PartitionedGraph, program: VertexProgram, ml: MeshEdgeLayou
     return lw, rw, mw
 
 
+def out_degrees(src: np.ndarray, valid: np.ndarray, width: int) -> np.ndarray:
+    """``[D, width]`` int32: each device's valid arcs out of each of its
+    padded vertex positions, duplicate arcs included (0 on padding)."""
+    return np.stack([
+        np.bincount(s[v], minlength=width) for s, v in zip(src, valid)
+    ]).astype(np.int32)
+
+
 def build_window_consts(
     pg: PartitionedGraph,
     program: VertexProgram,
@@ -204,10 +220,20 @@ def build_window_consts(
     so the audited program is the deployed program by construction.
     """
     lw, rw, mw = plane_shards(pg, program, ml)
+    # the work counters read each plane's arcs only as per-vertex degrees
+    # (``_body``'s ``part_sum``), so no per-arc partition id is uploaded; the
+    # degrees are derived here, from the layout as it stands, so a relayout,
+    # an incremental rebuild or a delta merge can never leave them stale.
+    # The unmirrored program never reads ``mdeg``: zero-width, like the
+    # other mirror constants there.
     consts = (
-        ml.lsrc, ml.ldst, lw, ml.lpart, ml.lvalid, ml.part_of_pos,
-        ml.rsrc, rw, ml.rslot, ml.rpart, ml.rvalid, ml.recv_idx,
-        ml.msrc, mw, ml.mslot, ml.mpart, ml.mvalid, ml.mrecv_idx,
+        ml.lsrc, ml.ldst, lw, out_degrees(ml.lsrc, ml.lvalid, ml.n_pad),
+        ml.lvalid, ml.part_of_pos,
+        ml.rsrc, rw, ml.rslot, out_degrees(ml.rsrc, ml.rvalid, ml.n_pad),
+        ml.rvalid, ml.recv_idx,
+        ml.msrc, mw, ml.mslot,
+        out_degrees(ml.msrc, ml.mvalid, ml.n_pad if ml.m_pad else 0),
+        ml.mvalid, ml.mrecv_idx,
     )
     statics = None
     if backend != "xla":
@@ -572,9 +598,9 @@ class MeshTraversalProgram:
     @staticmethod
     def _body(
         dist, frontier, nst0,
-        lsrc, ldst, lw, lpart, lvalid, part_of_pos,
-        rsrc, rw, rslot, rpart, rvalid, recv_idx,
-        msrc, mw, mslot, mpart, mvalid, mrecv_idx,
+        lsrc, ldst, lw, ldeg, lvalid, part_of_pos,
+        rsrc, rw, rslot, rdeg, rvalid, recv_idx,
+        msrc, mw, mslot, mdeg, mvalid, mrecv_idx,
         *blockmaps,
         m_max: int, n_parts: int, n_pad: int, w_pad: int, d_n: int,
         prog: VertexProgram, n_global: int, m_pad: int = 0,
@@ -583,12 +609,18 @@ class MeshTraversalProgram:
         # per-device blocks arrive with a leading length-1 device axis
         with scope("local", "gather"):
             lsrc, ldst, lw = lsrc[0], ldst[0], lw[0]
-            lpart, lvalid, part_of_pos = lpart[0], lvalid[0], part_of_pos[0]
+            lvalid, part_of_pos = lvalid[0], part_of_pos[0]
         with scope("remote", "gather"):
             rsrc, rw, rslot = rsrc[0], rw[0], rslot[0]
-            rpart, rvalid, recv_idx = rpart[0], rvalid[0], recv_idx[0]
+            rvalid, recv_idx = rvalid[0], recv_idx[0]
             msrc, mw, mslot = msrc[0], mw[0], mslot[0]
-            mpart, mvalid, mrecv_idx = mpart[0], mvalid[0], mrecv_idx[0]
+            mvalid, mrecv_idx = mvalid[0], mrecv_idx[0]
+        with scope("local", "counters"):
+            ldeg = ldeg[0]
+        with scope("remote", "counters"):
+            # the remote plane's degree and the mirror plane's count together
+            # toward msgs_sent; the unmirrored program never reads mdeg
+            rdeg = rdeg[0] + mdeg[0] if m_pad > 0 else rdeg[0]
         s_batch, p = dist.shape[0], n_parts
         ident = prog.identity
         # host-static mirror gate: with no mirror slots the traced program is
@@ -684,18 +716,16 @@ class MeshTraversalProgram:
                 v, mslot, num_segments=d_n * m_pad, indices_are_sorted=True
             )
         )
-        seg_sum_lp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, lpart, num_segments=p)
-        )
-        seg_sum_rp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, rpart, num_segments=p)
-        )
-        seg_sum_mp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, mpart, num_segments=p)
-        )
-        seg_sum_vp = jax.vmap(
-            lambda v: jax.ops.segment_sum(v, part_of_pos, num_segments=p)
-        )
+
+        def part_sum(x):
+            # [S, n_pad] int32 -> [S, P]: one dense reduction over the vertex
+            # axis, no scatter.  With x a per-vertex degree under a frontier
+            # it is the per-arc count, since every arc counts toward its
+            # source's partition.
+            in_part = part_of_pos[None, None, :] == jnp.arange(p)[None, :, None]
+            return jnp.sum(
+                jnp.where(in_part, x[:, None, :], 0), axis=-1, dtype=jnp.int32
+            )
 
         def g_any(flags):  # [S] bool per device -> [S] bool, mesh-global
             with collective():
@@ -763,8 +793,8 @@ class MeshTraversalProgram:
             with scope("local", "relax"):
                 acc = relax_l(cand)
             with scope("local", "counters"):
-                we_s = seg_sum_lp(active_le.astype(jnp.int32))
-                wv_s = seg_sum_vp(fr.astype(jnp.int32))
+                we_s = part_sum(jnp.where(fr, ldeg, 0))
+                wv_s = part_sum(fr.astype(jnp.int32))
                 it_s = g_any(fr.any(axis=1)).astype(jnp.int32)
 
             with scope("remote", "gather"):
@@ -777,7 +807,7 @@ class MeshTraversalProgram:
                 else:
                     acc = acc.at[:, recv_flat].add(recv)
             with scope("remote", "counters"):
-                ms_s = seg_sum_rp(active_re.astype(jnp.int32))
+                ms_s = part_sum(jnp.where(fr, rdeg, 0))
 
             if use_mirror:
                 # stateless mirror: combine locally per (owner, hub), sync
@@ -809,7 +839,6 @@ class MeshTraversalProgram:
                         acc = acc.at[:, mrecv_flat].add(mrecv)
                 with scope("remote", "counters"):
                     wire_s = wire_s + wire_m
-                    ms_s = ms_s + seg_sum_mp(active_me.astype(jnp.int32))
 
             with scope("remote", "frontier"):
                 new_d = prog.apply(d, acc, n_global)
@@ -847,8 +876,8 @@ class MeshTraversalProgram:
                     improved = prog.is_active(new_d, d_i)
                     touched = touched | improved
                 with scope("local", "counters"):
-                    we_s = we_s + seg_sum_lp(active_e.astype(jnp.int32))
-                    wv_s = wv_s + seg_sum_vp(f_i.astype(jnp.int32))
+                    we_s = we_s + part_sum(jnp.where(f_i, ldeg, 0))
+                    wv_s = wv_s + part_sum(f_i.astype(jnp.int32))
                     it_s = it_s + g_any(f_i.any(axis=1)).astype(jnp.int32)
                 return new_d, improved, we_s, wv_s, it_s, touched
 
@@ -867,7 +896,7 @@ class MeshTraversalProgram:
             with scope("remote", "relax"):
                 new_d = d2.at[:, recv_flat].min(recv)
             with scope("remote", "counters"):
-                ms_s = seg_sum_rp(active_re.astype(jnp.int32))
+                ms_s = part_sum(jnp.where(touched, rdeg, 0))
 
             if use_cache:
                 # -- mirror sync: combine into the window-local cache, send
@@ -891,7 +920,6 @@ class MeshTraversalProgram:
                     new_d = new_d.at[:, mrecv_flat].min(mrecv)
                 with scope("remote", "counters"):
                     wire_s = wire_s + wire_m
-                    ms_s = ms_s + seg_sum_mp(active_me.astype(jnp.int32))
 
             with scope("remote", "frontier"):
                 next_fr = prog.is_active(new_d, d2)
@@ -940,7 +968,7 @@ class MeshTraversalProgram:
                 wv = jax.lax.psum(wv, PARTS)
                 ms = jax.lax.psum(ms, PARTS)
                 wire = jax.lax.psum(wire, PARTS)
-                pact = jax.lax.psum(seg_sum_vp(fr.astype(jnp.int32)), PARTS) > 0
+                pact = jax.lax.psum(part_sum(fr.astype(jnp.int32)), PARTS) > 0
             done = ~g_any(fr.any(axis=1))
             sg = jnp.zeros((s_batch, m_max, 0), bool)  # mesh: single-device-only
         return d, fr, nst, we, wv, ms, it, sg, wire, pact, done

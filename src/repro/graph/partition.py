@@ -398,14 +398,12 @@ def _build_mesh_layout(
         lsrc = np.zeros((d_n, e_local_pad), dtype=np.int32)
         ldst = np.full((d_n, e_local_pad), n_pad - 1, dtype=np.int32)
         lw = np.zeros((d_n, e_local_pad), dtype=np.float32)
-        lpart = np.zeros((d_n, e_local_pad), dtype=np.int32)
         lvalid = np.zeros((d_n, e_local_pad), dtype=bool)
         l_eid = np.zeros((d_n, e_local_pad), dtype=np.int64)
     else:
         lsrc = base.lsrc.copy()
         ldst = base.ldst.copy()
         lw = base.lw.copy()
-        lpart = base.lpart.copy()
         lvalid = base.lvalid.copy()
         l_eid = base.l_eid.copy()
     for d in np.flatnonzero(vert_aff):
@@ -414,13 +412,11 @@ def _build_mesh_layout(
         lsrc[d] = 0
         ldst[d] = n_pad - 1
         lw[d] = 0.0
-        lpart[d] = 0
         lvalid[d] = False
         l_eid[d] = 0
         lsrc[d, :m] = pos_of_vertex[loc.src[sel]] - d * n_pad
         ldst[d, :m] = pos_of_vertex[loc.dst[sel]] - d * n_pad
         lw[d, :m] = loc.weights[sel]
-        lpart[d, :m] = layout.local_part[sel]
         lvalid[d, :m] = True
         l_eid[d, :m] = sel
         # padding dst rows keep the allocation value n_pad - 1, >= any real
@@ -488,7 +484,6 @@ def _build_mesh_layout(
         rsrc = np.zeros((d_n, e_remote_pad), dtype=np.int32)
         rw = np.zeros((d_n, e_remote_pad), dtype=np.float32)
         rslot = np.full((d_n, e_remote_pad), d_n * w_pad - 1, dtype=np.int32)
-        rpart = np.zeros((d_n, e_remote_pad), dtype=np.int32)
         rvalid = np.zeros((d_n, e_remote_pad), dtype=bool)
         r_eid = np.zeros((d_n, e_remote_pad), dtype=np.int64)
         recv_idx = np.zeros((d_n, d_n, w_pad), dtype=np.int32)
@@ -497,7 +492,6 @@ def _build_mesh_layout(
         mslot = np.full(
             (d_n, e_mirror_pad), max(0, d_n * m_pad - 1), dtype=np.int32
         )
-        mpart = np.zeros((d_n, e_mirror_pad), dtype=np.int32)
         mvalid = np.zeros((d_n, e_mirror_pad), dtype=bool)
         m_eid = np.zeros((d_n, e_mirror_pad), dtype=np.int64)
         mrecv_idx = np.zeros((d_n, d_n, m_pad), dtype=np.int32)
@@ -505,25 +499,21 @@ def _build_mesh_layout(
         rsrc = base.rsrc.copy()
         rw = base.rw.copy()
         rslot = base.rslot.copy()
-        rpart = base.rpart.copy()
         rvalid = base.rvalid.copy()
         r_eid = base.r_eid.copy()
         recv_idx = base.recv_idx.copy()
         msrc = base.msrc.copy()
         mw = base.mw.copy()
         mslot = base.mslot.copy()
-        mpart = base.mpart.copy()
         mvalid = base.mvalid.copy()
         m_eid = base.m_eid.copy()
         mrecv_idx = base.mrecv_idx.copy()
-    part32 = pg.part_of_vertex.astype(np.int32)
     for d in np.flatnonzero(src_aff):
         sel, uniq, inv = per_dev[int(d)]
         m = sel.size
         rsrc[d] = 0
         rw[d] = 0.0
         rslot[d] = d_n * w_pad - 1
-        rpart[d] = 0
         rvalid[d] = False
         r_eid[d] = 0
         recv_idx[:, d, :] = 0
@@ -536,7 +526,6 @@ def _build_mesh_layout(
             rsrc[d, :m] = pos_of_vertex[rem.src[sel]] - d * n_pad
             rw[d, :m] = rem.weights[sel]
             rslot[d, :m] = (u_dd[inv] * w_pad + slot_of_uniq[inv]).astype(np.int32)
-            rpart[d, :m] = part32[rem.src[sel]]
             rvalid[d, :m] = True
             r_eid[d, :m] = sel
             # receive side: block (d -> dd) slot s lands on the dst vertex's
@@ -551,7 +540,6 @@ def _build_mesh_layout(
         msrc[d] = 0
         mw[d] = 0.0
         mslot[d] = max(0, d_n * m_pad - 1)
-        mpart[d] = 0
         mvalid[d] = False
         m_eid[d] = 0
         mrecv_idx[:, d, :] = 0
@@ -563,7 +551,6 @@ def _build_mesh_layout(
             msrc[d, :m] = pos_of_vertex[rem.src[sel]] - d * n_pad
             mw[d, :m] = rem.weights[sel]
             mslot[d, :m] = (u_dd[inv] * m_pad + slot_of_uniq[inv]).astype(np.int32)
-            mpart[d, :m] = part32[rem.src[sel]]
             mvalid[d, :m] = True
             m_eid[d, :m] = sel
             mrecv_idx[u_dd, d, slot_of_uniq] = (
@@ -584,7 +571,6 @@ def _build_mesh_layout(
         lsrc=lsrc,
         ldst=ldst,
         lw=lw,
-        lpart=lpart,
         lvalid=lvalid,
         l_eid=l_eid,
         e_remote_pad=e_remote_pad,
@@ -592,7 +578,6 @@ def _build_mesh_layout(
         rsrc=rsrc,
         rw=rw,
         rslot=rslot,
-        rpart=rpart,
         rvalid=rvalid,
         r_eid=r_eid,
         recv_idx=recv_idx,
@@ -604,7 +589,6 @@ def _build_mesh_layout(
         msrc=msrc,
         mw=mw,
         mslot=mslot,
-        mpart=mpart,
         mvalid=mvalid,
         m_eid=m_eid,
         mrecv_idx=mrecv_idx,

@@ -224,7 +224,6 @@ class MeshEdgeLayout:
     lsrc: np.ndarray  # [D, e_local_pad] int32 device-local src row
     ldst: np.ndarray  # [D, e_local_pad] int32 device-local dst row, ascending
     lw: np.ndarray  # [D, e_local_pad] float32
-    lpart: np.ndarray  # [D, e_local_pad] int32 partition of each edge
     lvalid: np.ndarray  # [D, e_local_pad] bool
     l_eid: np.ndarray  # [D, e_local_pad] int64 row in the dst-sorted local set
     # -- per-device remote out-edges, (dst_device, dst_vertex)-sorted --------
@@ -233,7 +232,6 @@ class MeshEdgeLayout:
     rsrc: np.ndarray  # [D, e_remote_pad] int32 device-local src row
     rw: np.ndarray  # [D, e_remote_pad] float32
     rslot: np.ndarray  # [D, e_remote_pad] int32 in [0, D*w_pad), ascending
-    rpart: np.ndarray  # [D, e_remote_pad] int32 src partition of each edge
     rvalid: np.ndarray  # [D, e_remote_pad] bool
     r_eid: np.ndarray  # [D, e_remote_pad] int64 row in the dst-sorted remote set
     # -- receive side: wire slot -> device-local dst row ---------------------
@@ -250,7 +248,6 @@ class MeshEdgeLayout:
     msrc: np.ndarray | None = None  # [D, e_mirror_pad] int32 device-local src
     mw: np.ndarray | None = None  # [D, e_mirror_pad] float32
     mslot: np.ndarray | None = None  # [D, e_mirror_pad] int32 in [0, D*m_pad)
-    mpart: np.ndarray | None = None  # [D, e_mirror_pad] int32 src partition
     mvalid: np.ndarray | None = None  # [D, e_mirror_pad] bool
     m_eid: np.ndarray | None = None  # [D, e_mirror_pad] int64 remote-set row
     mrecv_idx: np.ndarray | None = None  # [D_recv, D_send, m_pad] int32

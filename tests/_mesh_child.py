@@ -19,6 +19,10 @@ Asserts, under real 8-device execution:
     D in {2, 8} (state AND counters) and match their numpy references;
     stationary PageRank keeps exact counters with state equal to rounding
     (float sums reassociate across shards) and matches its reference,
+  * counters from per-vertex degrees: on a graph with duplicate arcs and
+    arc-less vertices, every builtin program's mesh counters match the dense
+    engine bit for bit across forced relayouts, unmirrored and mirrored
+    (monotone state bit-identical, PageRank's to rounding),
   * per-destination aggregation puts fewer messages on the wire than the
     raw active-remote-edge count -- for every program,
   * windowed chaining on the mesh engine (k in {1, 8}) reproduces the
@@ -477,6 +481,60 @@ for d_n in (2, 8):
         )
     np.testing.assert_array_equal(rk.dist, rx.dist)
 print("mirror backend parity: pallas-interpret==xla for D in (2, 8)")
+
+# -- counters from per-vertex degrees: duplicate arcs, arc-less vertices ------
+# the mesh window counts work from per-vertex out-degrees; on a graph with
+# repeated arcs (each counts) and vertices with no arc at all, every builtin
+# program's counters must match the dense engine bit for bit across forced
+# relayouts, unmirrored and mirrored, with monotone state bit-identical
+rng_dup = np.random.default_rng(4)
+dup_src = rng_dup.integers(0, 140, 700)
+dup_dst = (dup_src + rng_dup.integers(1, 140, 700)) % 140
+again = rng_dup.choice(700, 120, replace=False)
+dup_src = np.concatenate([dup_src, dup_src[again]]).astype(np.int32)
+dup_dst = np.concatenate([dup_dst, dup_dst[again]]).astype(np.int32)
+dup_w = rng_dup.uniform(1.0, 4.0, dup_src.shape[0]).astype(np.float32)
+pg_dup = bfs_grow_partition(Graph(160, dup_src, dup_dst, dup_w), 5, seed=2)
+for prog_name, prog_ctor, d_srcs, state_exact in (
+    ("bfs", BfsProgram, [0, 77, 150], True),
+    ("sssp", SsspProgram, [0, 77, 150], True),
+    ("wcc", WccProgram, [0], True),
+    ("pagerank", lambda: PageRankProgram(num_iters=6), [0], False),
+):
+    dense = get_engine(
+        pg_dup, program=prog_ctor(), config=EngineConfig(m_max=M_MAX)
+    ).run(d_srcs)
+    for d_n in (2, 8):
+        swap_seq = [
+            np.arange(5, dtype=np.int32) % d_n,
+            (np.arange(5, dtype=np.int32)[::-1] % d_n).copy(),
+        ]
+        for mirror_degree in (None, MIRROR_DEGREE):
+            eng, state, we, wv, ms = run_with_swaps(
+                pg_dup, prog_ctor(), d_srcs, d_n, swap_seq,
+                mirror_degree=mirror_degree,
+            )
+            assert (eng._mesh_prog.layout.m_pad > 0) == bool(mirror_degree)
+            m = we.shape[1]
+            tag = f"degrees {prog_name} D={d_n} mirror={mirror_degree}"
+            np.testing.assert_array_equal(
+                we, dense.edges_examined[:, :m], err_msg=tag
+            )
+            np.testing.assert_array_equal(
+                wv, dense.verts_processed[:, :m], err_msg=tag
+            )
+            np.testing.assert_array_equal(
+                ms, dense.msgs_sent[:, :m], err_msg=tag
+            )
+            assert not dense.edges_examined[:, m:].any(), tag
+            np.testing.assert_array_equal(
+                np.asarray(state.n_supersteps), dense.n_supersteps, err_msg=tag
+            )
+            assert_state(
+                eng.gather_global(np.asarray(state.dist)), dense.dist,
+                state_exact, err_msg=tag,
+            )
+    print(f"degree counters {prog_name}: mesh==dense across relayouts")
 
 # -- executor relayout="auto": same results, skips recorded -------------------
 # the cost-aware policy may veto swaps but never changes results or the

@@ -391,8 +391,7 @@ def test_mirror_degenerate_builds_are_byte_identical():
 
     mirror_fields = {
         "mirror_degree", "e_mirror_pad", "m_pad", "msrc", "mw", "mslot",
-        "mpart", "mvalid", "m_eid", "mrecv_idx", "mirror_slots",
-        "mirror_block_edges",
+        "mvalid", "m_eid", "mrecv_idx", "mirror_slots", "mirror_block_edges",
     }
     for f in dataclasses.fields(ml_default):
         if f.name in mirror_fields:
@@ -420,6 +419,117 @@ def test_mirror_degenerate_builds_are_byte_identical():
         mirror_degrees=(None, 2, None, 2),
     )
     assert not findings, [str(f) for f in findings]
+
+
+# -- work counters from per-vertex degrees -----------------------------------
+
+
+def _expected_degrees(src, valid, width):
+    """Per-device ``np.bincount`` of the valid sources, one row a device."""
+    return np.stack([
+        np.bincount(s[v], minlength=width) for s, v in zip(src, valid)
+    ])
+
+
+def _assert_window_degrees(pg: PartitionedGraph, ml) -> None:
+    """``build_window_consts``' degree tables are per-device bincounts of
+    the valid sources, 0 on padding, and put back in vertex order they are
+    the dense engine's local and remote out-degrees."""
+    from repro.graph.mesh_exchange import build_window_consts
+    from repro.graph.program import SsspProgram
+
+    consts, _ = build_window_consts(pg, SsspProgram(), ml)
+    ldeg, rdeg, mdeg = consts[3], consts[9], consts[15]
+    for got in (ldeg, rdeg, mdeg):
+        assert got.dtype == np.int32
+    np.testing.assert_array_equal(
+        ldeg, _expected_degrees(ml.lsrc, ml.lvalid, ml.n_pad)
+    )
+    np.testing.assert_array_equal(
+        rdeg, _expected_degrees(ml.rsrc, ml.rvalid, ml.n_pad)
+    )
+    assert not ldeg[~ml.pos_valid].any() and not rdeg[~ml.pos_valid].any()
+    lay = partitioned_edge_layout(pg)
+    n = pg.graph.n_vertices
+    remote = rdeg
+    if ml.m_pad:
+        np.testing.assert_array_equal(
+            mdeg, _expected_degrees(ml.msrc, ml.mvalid, ml.n_pad)
+        )
+        assert not mdeg[~ml.pos_valid].any()
+        remote = rdeg + mdeg
+    else:
+        assert mdeg.shape == (ml.n_devices, 0)  # never read unmirrored
+    np.testing.assert_array_equal(
+        ldeg.reshape(-1)[ml.pos_of_vertex],
+        np.bincount(lay.local.src, minlength=n),
+    )
+    np.testing.assert_array_equal(
+        remote.reshape(-1)[ml.pos_of_vertex],
+        np.bincount(lay.remote.src, minlength=n),
+    )
+
+
+@pytest.mark.parametrize("mirror_degree", [None, 2])
+@pytest.mark.parametrize("n_dev", [2, 8])
+@pytest.mark.parametrize("partitioner", ["hash", "bfs_grow"])
+def test_window_degree_constants(partitioner, n_dev, mirror_degree):
+    """The mesh window's degree constants on ragged P=5, before and after
+    an incremental rebuild to another ``device_of_part``."""
+    from repro.graph.partition import hash_partition
+
+    g = erdos_renyi_graph(300, 4.0, seed=13)
+    pg = (
+        hash_partition(g, 5, seed=1) if partitioner == "hash"
+        else bfs_grow_partition(g, 5, seed=2)
+    )
+    base = contiguous_device_map(5, n_dev)
+    ml = mesh_edge_layout(pg, base, n_dev, mirror_degree=mirror_degree)
+    assert (ml.m_pad > 0) == (mirror_degree is not None)
+    _assert_window_degrees(pg, ml)
+    # relabelling the devices keeps every pad shape, so the rebuild stays
+    # incremental
+    moved = ((base + 1) % n_dev).astype(np.int32)
+    inc = mesh_edge_layout(pg, moved, n_dev, mirror_degree=mirror_degree, base=ml)
+    assert inc is not ml
+    assert inc.__dict__["_build_info"]["incremental"]
+    _assert_window_degrees(pg, inc)
+
+
+@pytest.mark.parametrize("mirror_degree", [None, "audit"])
+@pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+@pytest.mark.parametrize("name", ["bfs", "pagerank", "sssp", "wcc"])
+def test_mesh_window_has_no_partition_scatter(name, backend, mirror_degree):
+    """No scatter into ``n_parts`` bins is left in the mesh window: every
+    per-partition counter is a dense reduction over the vertex axis.  The
+    xla backend's value reductions into local rows stay scatters."""
+    from repro.analysis.jaxpr_audit import default_audit_graph, iter_eqns
+    from repro.analysis.registry import AUDIT_MESH_WIDTH, AUDIT_MIRROR_DEGREE
+    from repro.graph.mesh_exchange import abstract_window_jaxpr
+    from repro.graph.program import BUILTIN_PROGRAMS
+
+    assert sorted(BUILTIN_PROGRAMS) == ["bfs", "pagerank", "sssp", "wcc"]
+    pg = default_audit_graph()
+    d_n = AUDIT_MESH_WIDTH
+    degree = AUDIT_MIRROR_DEGREE if mirror_degree else None
+    jaxpr = abstract_window_jaxpr(
+        pg, BUILTIN_PROGRAMS[name](), d_n=d_n, s_batch=2, backend=backend,
+        mirror_degree=degree,
+    )
+    ml = mesh_edge_layout(
+        pg, contiguous_device_map(pg.n_parts, d_n), d_n, mirror_degree=degree
+    )
+    assert (ml.m_pad > 0) == bool(mirror_degree)
+    assert pg.n_parts not in (ml.n_pad, d_n * ml.w_pad, d_n * ml.m_pad)
+    shapes = [
+        tuple(v.aval.shape)
+        for eqn, _ in iter_eqns(jaxpr.jaxpr)
+        if "scatter" in eqn.primitive.name
+        for v in eqn.outvars
+    ]
+    assert not [s for s in shapes if s and s[-1] == pg.n_parts], shapes
+    if backend == "xla":
+        assert (2, ml.n_pad) in shapes  # the value reductions remain
 
 
 # -- single-device fallback (runs on the real 1-CPU pytest process) ----------
